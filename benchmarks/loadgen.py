@@ -13,24 +13,22 @@ deployment through ``ShardedServeDispatcher`` (serve/distributed.py):
   throughput saturates and latency is queue depth.
 
 * ``sharded_scaling`` — the subsystem's acceptance record: the same
-  deployment driven to saturation in a FRESH SUBPROCESS per forced
-  host-platform device count (``--xla_force_host_platform_device_count``
-  must be set before jax imports, hence ``--worker`` mode), recording
-  throughput, per-device utilization, and a SHA-1 digest over every
-  output.  On one CPU core the scaling comes from the device-count-
-  aware global buckets (per-shard bucket × mesh size) amortizing the
-  fixed per-batch scheduling cost over more images; the digests assert
-  the sharded results are bitwise-identical to the single-device
-  ``CnnServeEngine`` at every device count.
+  deployment driven to saturation over a serve mesh of every device
+  count from 1 to ``len(jax.devices())``, all in this one process (a
+  chip belongs to one process, so a child could not reach it),
+  recording throughput, per-device utilization, and a SHA-1 digest over
+  every output.  The digests assert the sharded results are
+  bitwise-identical to the single-device ``CnnServeEngine`` at every
+  device count.  On CPU, more devices come from
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set before the
+  process starts; there the scaling comes from the device-count-aware
+  global buckets (per-shard bucket × mesh size) amortizing the fixed
+  per-batch scheduling cost over more images.
 """
 from __future__ import annotations
 
 import argparse
 import hashlib
-import json
-import os
-import subprocess
-import sys
 import time
 from typing import Dict, List, Sequence, Tuple
 
@@ -38,26 +36,22 @@ import numpy as np
 
 from benchmarks.common import csv_row, write_json
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 #: the DIST_SMOKE geometry both records drive
 SCALING_SHAPE: Tuple[int, int, int] = (8, 8, 3)
-#: the device counts the scaling sweep forces
-DEVICE_COUNTS: Tuple[int, ...] = (1, 2, 4)
 
 
 def _images(n: int, seed: int) -> np.ndarray:
     """The deterministic image pool: identical bytes at every device
-    count, so output digests are comparable across workers."""
+    count, so output digests are comparable across device counts."""
     rng = np.random.default_rng(seed)
     return rng.standard_normal((n,) + SCALING_SHAPE).astype(np.float32)
 
 
-def _dispatcher(model, params, buckets):
+def _dispatcher(model, params, buckets, mesh=None):
     from repro.configs.serve import DIST_SMOKE
     from repro.serve import ShardedServeDispatcher
     return ShardedServeDispatcher(
-        model, params, {SCALING_SHAPE: buckets},
+        model, params, {SCALING_SHAPE: buckets}, mesh=mesh,
         process_index=0, process_count=1,
         max_wait_ms=DIST_SMOKE.max_wait_ms,
         default_deadline_ms=DIST_SMOKE.default_deadline_ms,
@@ -65,13 +59,15 @@ def _dispatcher(model, params, buckets):
 
 
 # ---------------------------------------------------------------------------
-# worker: one forced-device-count throughput + digest measurement
+# one device count: throughput + digest measurement
 
-def worker(images: int, seed: int, reps: int = 3) -> Dict:
-    """Saturation throughput of the DIST_SMOKE deployment at THIS
-    process's device count, plus bitwise evidence: a digest over the
-    dispatcher's outputs (request order) and the same digest from the
-    single-device synchronous engine on identical inputs.
+def measure_mesh(n_devices: int, images: int, seed: int,
+                 reps: int = 3) -> Dict:
+    """Saturation throughput of the DIST_SMOKE deployment on a serve
+    mesh of the first ``n_devices`` devices, plus bitwise evidence: a
+    digest over the dispatcher's outputs (request order) and the same
+    digest from the single-device synchronous engine on identical
+    inputs.
 
     Throughput is the DRAIN rate: the backlog is queued first and only
     ``run()`` is timed — the server-side number an open-loop generator
@@ -81,6 +77,7 @@ def worker(images: int, seed: int, reps: int = 3) -> Dict:
     import jax
 
     from repro.configs.serve import DIST_SMOKE
+    from repro.launch.mesh import make_serve_mesh
     from repro.models.cnn import tiny_cnn
     from repro.serve import CnnServeEngine, ImageRequest, ServeRequest
 
@@ -89,7 +86,7 @@ def worker(images: int, seed: int, reps: int = 3) -> Dict:
     params = model.init(jax.random.PRNGKey(0))
     imgs = _images(images, seed)
 
-    disp = _dispatcher(model, params, buckets)
+    disp = _dispatcher(model, params, buckets, make_serve_mesh(n_devices))
     disp.warmup()
     for i in range(8):                       # prime the dispatch path
         disp.submit(ServeRequest(rid=10**9 + i, images=imgs[i:i + 1]))
@@ -141,31 +138,12 @@ def worker(images: int, seed: int, reps: int = 3) -> Dict:
     }
 
 
-def _run_worker(device_count: int, images: int, seed: int) -> Dict:
-    """Fresh interpreter per device count: the forced-host-platform
-    flag only takes effect before jax initialises."""
-    env = dict(os.environ)
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if not f.startswith("--xla_force_host_platform_device_count")]
-    flags.append(f"--xla_force_host_platform_device_count={device_count}")
-    env["XLA_FLAGS"] = " ".join(flags)
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (_ROOT, os.path.join(_ROOT, "src"),
-                    env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.loadgen", "--worker",
-         "--images", str(images), "--seed", str(seed)],
-        env=env, cwd=_ROOT, capture_output=True, text=True, timeout=900)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"scaling worker (devices={device_count}) failed:\n"
-            f"{proc.stderr[-2000:]}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
 def scaling_record(images: int, seed: int = 0) -> Dict:
+    import jax
+
     from repro.configs.serve import DIST_SMOKE
-    runs = [_run_worker(n, images, seed) for n in DEVICE_COUNTS]
+    runs = [measure_mesh(n, images, seed)
+            for n in range(1, len(jax.devices()) + 1)]
     base = runs[0]["img_per_s"]
     digests = ({r["digest"] for r in runs}
                | {r["engine_digest"] for r in runs})
@@ -274,17 +252,8 @@ def run(quick: bool = True) -> List[str]:
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--worker", action="store_true",
-                    help="one forced-device-count measurement; prints "
-                         "a JSON line (internal: scaling_record spawns "
-                         "these with XLA_FLAGS preset)")
-    ap.add_argument("--images", type=int, default=4096)
-    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args(argv)
-    if args.worker:
-        print(json.dumps(worker(args.images, args.seed)))
-        return
     print("name,us_per_call,derived")
     for row in run(quick=not args.full):
         print(row)

@@ -27,6 +27,8 @@ def main(argv=None) -> None:
                     help="comma-separated module subset")
     args = ap.parse_args(argv)
     quick = not args.full
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from benchmarks import (graph_serve, lm_substrate, loadgen,
                             paper_figures, table1_inventory,

@@ -53,17 +53,31 @@ AUTOTUNE_SCHEMA = 2
 _STORE = JsonCache("autotune.json")
 
 #: observable measurement effort — tests assert the replay-from-cache
-#: path performs ZERO re-measurement against these counters
+#: path performs ZERO re-measurement against these counters.
+#: ``failures`` lists every candidate a sweep dropped because it raised
+#: (a kernel the backend's compiler refused, say): one
+#: ``{"spec", "executor", "config", "error"}`` dict each, so a sweep
+#: never loses a candidate silently
 MEASURE_STATS = {"algo_sweeps": 0, "config_sweeps": 0, "fusion_sweeps": 0,
-                 "timed_calls": 0}
+                 "timed_calls": 0, "failures": []}
 
 
 def reset_measure_stats() -> dict:
-    """Zero the measurement counters; returns the discarded counts."""
-    old = dict(MEASURE_STATS)
-    for k in MEASURE_STATS:
-        MEASURE_STATS[k] = 0
+    """Zero the measurement counters and clear ``failures``; returns the
+    discarded values."""
+    old = {k: (list(v) if isinstance(v, list) else v)
+           for k, v in MEASURE_STATS.items()}
+    for k, v in MEASURE_STATS.items():
+        MEASURE_STATS[k] = [] if isinstance(v, list) else 0
     return old
+
+
+def _record_failure(spec: ConvSpec, executor: str, config,
+                    err: Exception) -> None:
+    MEASURE_STATS["failures"].append({
+        "spec": spec.key(), "executor": executor,
+        "config": config.as_dict() if config else {},
+        "error": f"{type(err).__name__}: {err}".splitlines()[0][:500]})
 
 
 def _key(spec: ConvSpec, backend: str) -> str:
@@ -255,12 +269,14 @@ def measure_algorithm(x, w, stride=1, padding="same", repeats=3,
         # time through a ConvPlan so the epilogue runs as deployed;
         # default_config rides inside the guard so one candidate's
         # broken tuning declarations degrade the sweep, not crash it
+        cfg = None
         try:
+            cfg = executors.get(name).default_config(spec)
             p = ConvPlan(spec, name, "candidate", "autotune timing",
-                         backend,
-                         config=executors.get(name).default_config(spec))
+                         backend, config=cfg)
             t = _time_plan(p, x, w, bias, repeats, addend)
-        except Exception:
+        except Exception as e:
+            _record_failure(spec, name, cfg, e)
             continue
         if t < best_t:
             best, best_t = name, t
@@ -335,7 +351,8 @@ def measure_config(x, w, stride=1, padding="same", repeats=3,
                      config_source="candidate")
         try:
             t = _time_plan(p, x, w, bias, repeats, addend)
-        except Exception:
+        except Exception as e:
+            _record_failure(spec, algorithm, cfg, e)
             continue
         if t < best_t:
             best, best_t = cfg, t
@@ -403,7 +420,8 @@ def measure_fusion(spec: ConvSpec, backend: Optional[str] = None,
     try:
         fused_t = _time_plan(fused_plan, x, w, b, repeats, addend)
         unfused_t = _time_plan(unfused, x, w, b, repeats, addend)
-    except Exception:
+    except Exception as e:
+        _record_failure(spec, fused_plan.algorithm, fused_plan.config, e)
         return None              # nothing timed: leave the verdict open
     wins = fused_t <= unfused_t
     entry = _merged_entry(spec, backend)

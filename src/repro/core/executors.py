@@ -123,12 +123,31 @@ class LaunchConfig:
         return ",".join(f"{k}={v}" for k, v in self.dims) or "-"
 
 
-def _dedup_configs(dicts: Iterable[Dict[str, int]]
+# TPU block alignment: a block's last dim must be a multiple of 128
+# lanes and its second-last a multiple of 8 sublanes, unless the block
+# spans the whole array dim.  These tile dims land on a lane axis of
+# some kernel block; the other tiled dims (tp, tt) land on a sublane axis.
+_LANE_DIMS = ("tm", "tc")
+
+
+def _dedup_configs(dicts: Iterable[Dict[str, int]],
+                   extents: Optional[Dict[str, int]] = None
                    ) -> Tuple[LaunchConfig, ...]:
     """Ordered, deduplicated candidate list (clamped candidates often
-    collapse on small paper shapes — e.g. every tp > N*OH*OW)."""
+    collapse on small paper shapes — e.g. every tp > N*OH*OW).
+
+    ``extents`` maps tile dims to the array extent they tile; a
+    candidate whose tile is neither the whole extent nor TPU-aligned
+    (a multiple of 128 for ``_LANE_DIMS``, of 8 for the others)
+    is dropped, so neither the model-chosen default nor a measured
+    sweep picks a block shape the TPU compiler refuses.
+    """
     out, seen = [], set()
     for d in dicts:
+        if extents and any(
+                d[k] < n and d[k] % (128 if k in _LANE_DIMS else 8)
+                for k, n in extents.items()):
+            continue
         c = LaunchConfig.of(d)
         if c.dims not in seen:
             seen.add(c.dims)
@@ -669,17 +688,23 @@ _GEMM_TILES = (
 
 def _gemm_tile_configs(p: int, m: int, c: int) -> Tuple[LaunchConfig, ...]:
     return _dedup_configs(
-        {"tp": min(tp, p), "tm": min(tm, m), "tc": min(tc, c)}
-        for tp, tm, tc in _GEMM_TILES)
+        ({"tp": min(tp, p), "tm": min(tm, m), "tc": min(tc, c)}
+         for tp, tm, tc in _GEMM_TILES),
+        {"tp": p, "tm": m, "tc": c})
 
 
 def _gemm_tile_vmem(config: LaunchConfig, itemsize: int) -> int:
-    """Live-block model of one tiled GEMM step: x/w input blocks double
-    buffered, output block plus its f32 VMEM accumulator."""
+    """Live-block model of one tiled GEMM step at the TPU's tiled layout:
+    x/w input blocks and the (at most 32-bit) output block double
+    buffered, plus the 32-bit VMEM accumulator."""
+    from repro.kernels._compat import tiled_bytes
     tp = config.get("tp", 256)
     tm = config.get("tm", 128)
     tc = config.get("tc", 512)
-    return 2 * itemsize * (tp * tc + tc * tm) + (itemsize + 4) * tp * tm
+    return (2 * (tiled_bytes((tp, tc), itemsize)
+                 + tiled_bytes((tc, tm), itemsize)
+                 + tiled_bytes((tp, tm), 4))
+            + tiled_bytes((tp, tm), 4))
 
 
 def _gemm_tile_steps(p: int, m: int, c: int, config: LaunchConfig) -> float:
@@ -783,10 +808,8 @@ class FusedPallasExecutor(Executor):
     Tuning space: ``tm`` (output-channel tile) x ``rows`` (output rows
     per grid step — the multi-row blocking that lets short-``OW`` paper
     shapes feed the MXU a (rows*OW x C) window instead of one row).
-    ``rows >= 2`` is only geometrically valid when ``KH - 1 <= rows*sh``
-    (the kernel's two-staged-block halo rule) and ``rows <= OH``; both
-    are ``config_supports`` rules, so stale persisted configs from an
-    earlier geometry are re-resolved, never served.
+    ``rows <= OH`` is a ``config_supports`` rule, so stale persisted
+    configs from an earlier geometry are re-resolved, never served.
     """
     name = "cuconv_pallas"
     fuses_epilogue = True
@@ -847,21 +870,18 @@ class FusedPallasExecutor(Executor):
         else:
             rows_cands = (1, 2, 4, 8)
         return _dedup_configs(
-            {"tm": min(tm, m), "rows": min(rows, oh)}
-            for tm in (128, 256, 512)          # candidate 0: tm=128, rows=1
-            for rows in rows_cands)
+            ({"tm": min(tm, m), "rows": min(rows, oh)}
+             for tm in (128, 256, 512)         # candidate 0: tm=128, rows=1
+             for rows in rows_cands),
+            {"tm": m})
 
     def _config_supports(self, spec, config):
+        from repro.kernels.cuconv_fused import STRIDED_LOAD_LANES
         rows = config.get("rows", 1)
-        _, oh, _, _ = spec.out_shape
-        kh = spec.filter_shape[0]
-        sh = spec.stride[0]
+        _, oh, _, m = spec.out_shape
         if rows > oh:
             return False, (f"rows={rows} exceeds OH={oh} for "
                            f"{spec.key()}")
-        if rows > 1 and kh - 1 > rows * sh:
-            return False, (f"multi-row blocking needs KH-1 <= rows*sh; "
-                           f"got KH={kh}, rows={rows}, sh={sh}")
         if spec.fused_pool:
             psh = spec.fused_pool[3]
             if rows % psh:
@@ -870,10 +890,11 @@ class FusedPallasExecutor(Executor):
             if oh % rows:
                 return False, (f"fused pool needs OH % rows == 0; "
                                f"got OH={oh}, rows={rows}")
-            if kh - 1 > rows * sh:
-                return False, (f"fused pool rides the multi-row kernel: "
-                               f"needs KH-1 <= rows*sh; got KH={kh}, "
-                               f"rows={rows}, sh={sh}")
+            tm = min(config.get("tm", 128), m)
+            if tm > STRIDED_LOAD_LANES:
+                return False, (f"fused pool reads its scratch with strided "
+                               f"loads, which Mosaic takes on at most "
+                               f"{STRIDED_LOAD_LANES} lanes; got tm={tm}")
         return True, "config geometry ok"
 
     def config_cost(self, spec, config):
@@ -929,15 +950,16 @@ class FusedPallasExecutor(Executor):
 
 # Winograd-Pallas launch candidates: (tt, tm, tc) tile triples tried
 # under both F(m,3) variants.  Candidate 0 under m=2 is the kernel's
-# shipped default geometry; the smaller triples keep the F(4,3) domain
-# (36 positions vs 16) inside the VMEM budget on big-channel specs.
+# shipped default geometry; the smaller tile counts keep the F(4,3)
+# domain (36 positions vs 16) inside the VMEM budget on big-channel
+# specs, with the channel tiles still 128-lane aligned.
 _WINO_TILES = (
     (128, 128, 128),
     (256, 128, 128),
     (128, 256, 128),
     (128, 128, 256),
-    (128, 128, 64),
-    (64, 128, 64),
+    (64, 128, 128),
+    (32, 128, 128),
     (64, 256, 128),
 )
 
@@ -980,13 +1002,14 @@ class WinogradPallasExecutor(Executor):
         return n * (-(-oh // fm)) * (-(-ow // fm)), m, spec.filter_shape[2]
 
     def configs(self, spec):
-        cands = []
+        cands = ()
         for fm in (2, 4):
             p, m, c = self._tile_counts(spec, fm)
-            for tt, tm, tc in _WINO_TILES:
-                cands.append({"m": fm, "tt": min(tt, p),
-                              "tm": min(tm, m), "tc": min(tc, c)})
-        return _dedup_configs(cands)
+            cands += _dedup_configs(
+                ({"m": fm, "tt": min(tt, p), "tm": min(tm, m),
+                  "tc": min(tc, c)} for tt, tm, tc in _WINO_TILES),
+                {"tt": p, "tm": m, "tc": c})
+        return cands
 
     def _config_supports(self, spec, config):
         fm = config.get("m", 2)
@@ -1097,8 +1120,9 @@ class DirectConvExecutor(Executor):
 
     def configs(self, spec):
         m, c = spec.filter_shape[3], spec.filter_shape[2]
-        return _dedup_configs({"tm": min(tm, m), "tc": min(tc, c)}
-                              for tm, tc in _DIRECT_TILES)
+        return _dedup_configs(({"tm": min(tm, m), "tc": min(tc, c)}
+                               for tm, tc in _DIRECT_TILES),
+                              {"tm": m, "tc": c})
 
     def vmem_bytes(self, spec, config=None):
         from repro.kernels.direct_conv import vmem_bytes
@@ -1198,12 +1222,8 @@ class Int8PallasExecutor(Executor):
         return _gemm_tile_configs(*self._gemm_dims(spec))
 
     def vmem_bytes(self, spec, config=None):
-        # int8 input blocks double buffered; int32 output block + int32
-        # VMEM accumulator
-        cfg = LaunchConfig.of(config)
-        tp, tm = cfg.get("tp", 256), cfg.get("tm", 128)
-        tc = cfg.get("tc", 512)
-        return 2 * (tp * tc + tc * tm) + 8 * tp * tm
+        # int8 input blocks; int32 output block + int32 VMEM accumulator
+        return _gemm_tile_vmem(LaunchConfig.of(config), 1)
 
     def config_cost(self, spec, config):
         return _gemm_tile_steps(*self._gemm_dims(spec), config)

@@ -33,7 +33,7 @@ def _kernel(xs_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tl", "td", "interpret"))
-def conv1d_tap(x, w, b=None, tl=512, td=256, interpret=True):
+def conv1d_tap(x, w, b=None, tl=512, td=256, *, interpret):
     """Causal depthwise conv1d.  x: (B, L, D); w: (K, D); b: (D,) or None."""
     B, Lx, D = x.shape
     K, _ = w.shape

@@ -36,7 +36,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "tm", "tc", "interpret"))
-def conv1x1_gemm(x2d, w, tp=256, tm=128, tc=512, interpret=True):
+def conv1x1_gemm(x2d, w, tp=256, tm=128, tc=512, *, interpret):
     """x2d: (P, C) pixels-major; w: (C, M).  Returns (P, M) in x2d.dtype."""
     P, C = x2d.shape
     _, M = w.shape

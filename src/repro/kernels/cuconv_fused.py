@@ -16,27 +16,27 @@ temporary stream attacks its memory term directly.
 
 Launch configuration (DESIGN.md §9): the kernel geometry is *tunable* —
 ``tm`` is the output-channel tile, ``rows`` the number of output rows
-each grid step produces.
+each grid step produces.  Grid: (N, ceil(OH/rows), M_tiles, KH*KW).
+``rows >= 2`` lets the short-``OW`` paper shapes (7x7, 13x13) feed the
+MXU more than one output row per step.
 
-``rows=1`` (the historical geometry) — grid (N, OH, M_tiles, TAPS).
-Per step: one padded input row (1, 1, Wp, C) is selected by index_map
-*element* offset oh*sh + tap_dy (legal because the H block dim is 1);
-the in-row X window for tap_dx at stride sw is a dynamic_slice of
-length OW*sw reshaped to (OW, sw, C) and column-sampled — a
-slice+reshape that stays TPU-legal (no gather); the (OW x C) window
-hits the MXU against the (C x TM) tap matrix.
+Input layout: the padded input is split into stride phases
+(``_compat.phase_split``), so tap ``(di, dj)`` reads phase
+``(di % sh, dj % sw)`` at offset ``(di // sh, dj // sw)`` with unit
+stride and the kernel needs no strided load.  Each grid step's input
+block is the halo'd band of ``rows + (KH-1)//sh`` phase rows its
+output rows read, addressed by an
+*element* offset (``pl.Element``) in the index_map: it does not depend
+on the tap or the channel tile, so it is fetched once per output-row
+block and stays resident across all KH*KW taps.
 
-``rows>=2`` (multi-row output blocking) — grid (N, ceil(OH/rows),
-M_tiles, TAPS).  The short-``OW`` paper configs (7x7, 13x13) only fill
-a handful of MXU sublanes with a single output row; multi-row blocking
-feeds a (rows*OW x C) window per step instead.  Element-offset
-index_maps need a block dim of 1, so the halo is covered differently
-here: TWO adjacent aligned H-blocks of ``rows*sh`` input rows each are
-staged per step, concatenated in VMEM, and the tap's (rows, OW) window
-is carved out with one dynamic_slice + reshape (strided row/column
-sampling, no gather).  Validity: KH - 1 <= rows*sh, so every tap's
-window lands inside the two staged blocks — ``config_supports`` on the
-executor prunes the rest.
+The tap's ``(rows, OW, C)`` window is a ref slice,
+``x_ref[0, di % sh, pl.ds(di // sh, rows), dj % sw, pl.ds(dj // sw, OW)]``
+— no gather and no value-level dynamic slice (Mosaic lowers neither).
+The row offset is dynamic (a leading dim); the column offset is made
+static by one ``pl.when`` branch per filter column, because Mosaic
+refuses a dynamic unaligned offset on the sublane dim.  The window hits
+the MXU as one ``(rows*OW x C) @ (C x TM)`` matmul.
 
 Epilogue (DESIGN.md §4): on the final tap the still-VMEM-resident
 accumulator takes bias add + activation before the single HBM write —
@@ -50,13 +50,13 @@ activation, so a ResNet shortcut join (``relu(conv(x) + b + shortcut)``)
 also costs no extra HBM round trip.
 
 ``pool`` — a trailing non-overlapping max/avg pool ``(kind, psh, psw)``
-(window == stride, no padding) folded into the multi-row path: the conv
-partials accumulate in an f32 VMEM *scratch* block of ``rows`` output
-rows; on the final tap the epilogue runs and the block is pooled with
-static strided slices (no gather) down to ``(rows/psh, OW/psw)`` before
-the single — now pool-sized — HBM write.  Validity (``config_supports``
-on the executor enforces it): ``rows % psh == 0``, ``OH % rows == 0``,
-``OW % psw == 0`` and the multi-row halo rule ``KH - 1 <= rows*sh``.
+(window == stride, no padding): the conv partials accumulate in an f32
+VMEM *scratch* block of ``rows`` output rows; on the final tap the
+epilogue runs in place and the block is pooled with strided scratch
+reads down to ``(rows/psh, OW/psw)`` before the single — now
+pool-sized — HBM write.  Validity (``config_supports`` on the executor
+enforces it): ``rows % psh == 0``, ``OH % rows == 0``, ``OW % psw == 0``,
+and ``tm <= 128`` (the strided scratch reads).
 """
 from __future__ import annotations
 
@@ -69,60 +69,76 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import _compat
 
+#: widest lane extent Mosaic's strided loads accept (the pool epilogue)
+STRIDED_LOAD_LANES = 128
 
-def _make_kernel(kw: int, ow: int, sw: int, taps: int, activation,
-                 has_bias: bool, has_add: bool = False):
+
+def _make_kernel(kw: int, ow: int, sh: int, sw: int, rows: int, taps: int,
+                 activation, has_bias: bool, has_add: bool, pool=None):
     def _kernel(*refs):
         refs = list(refs)
         x_ref, w_ref = refs.pop(0), refs.pop(0)
         b_ref = refs.pop(0) if has_bias else None
         a_ref = refs.pop(0) if has_add else None
         o_ref = refs.pop(0)
+        acc_ref = refs.pop(0) if pool is not None else o_ref.at[0]
         t = pl.program_id(3)
+        di = t // kw
         dj = jax.lax.rem(t, kw)
-        row = x_ref[0, 0]                                   # (Wp', C)
-        if sw == 1:
-            win = jax.lax.dynamic_slice(
-                row, (dj, 0), (ow, row.shape[1]))           # (OW, C)
-        else:
-            # strided window: contiguous (OW*sw, C) slice, column-sampled
-            # via reshape — the padded input guarantees dj + OW*sw <= Wp'
-            win = jax.lax.dynamic_slice(
-                row, (dj, 0), (ow * sw, row.shape[1]))
-            win = win.reshape(ow, sw, row.shape[1])[:, 0, :]
-        part = jnp.dot(win, w_ref[0, 0],
-                       preferred_element_type=jnp.float32)  # (OW, TM)
 
-        @pl.when(t == 0)
-        def _init():
-            o_ref[0, 0] = part
+        def _tap(j):
+            win = x_ref[0, jax.lax.rem(di, sh), pl.ds(di // sh, rows),
+                        j % sw, pl.ds(j // sw, ow), :]      # (rows, OW, C)
+            win = win.reshape(rows * ow, win.shape[-1])
+            part = jnp.dot(win, w_ref[0, 0],
+                           preferred_element_type=jnp.float32)
+            part = part.reshape(rows, ow, part.shape[-1])   # (rows, OW, TM)
 
-        @pl.when(t > 0)
-        def _acc():
-            o_ref[0, 0] += part
+            # conv partials accumulate in VMEM: the output block
+            # (revisited across all taps) or, under a fused pool, the
+            # f32 scratch; t == 0 implies dj == 0
+            if j == 0:
+                @pl.when(t == 0)
+                def _init():
+                    acc_ref[...] = part
 
-        if has_bias or has_add or activation is not None:
-            @pl.when(t == taps - 1)
-            def _epilogue():
-                acc = o_ref[0, 0]
-                if has_bias:
-                    acc = acc + b_ref[0].astype(jnp.float32)
-                if has_add:
-                    acc = acc + a_ref[0, 0].astype(jnp.float32)
-                if activation == "relu":
-                    acc = jnp.maximum(acc, 0.0)
-                o_ref[0, 0] = acc
+                @pl.when(t > 0)
+                def _acc0():
+                    acc_ref[...] += part
+            else:
+                acc_ref[...] += part
+
+        for j in range(kw):
+            pl.when(dj == j)(functools.partial(_tap, j))
+
+        if not (has_bias or has_add or activation is not None
+                or pool is not None):
+            return
+
+        @pl.when(t == taps - 1)
+        def _epilogue():
+            acc = acc_ref[...]
+            if has_bias:
+                acc = acc + b_ref[0].astype(jnp.float32)
+            if has_add:
+                acc = acc + a_ref[0].astype(jnp.float32)
+            if activation == "relu":
+                acc = jnp.maximum(acc, 0.0)
+            acc_ref[...] = acc
+            if pool is not None:
+                o_ref[0] = _pool_block(acc_ref, rows, ow, *pool)
 
     return _kernel
 
 
-def _pool_block(acc, kind: str, psh: int, psw: int):
-    """Non-overlapping (window == stride) pool of a (rows, OW, TM) VMEM
-    block via static strided slices — no gather, TPU-legal."""
+def _pool_block(acc_ref, rows: int, ow: int, kind: str, psh: int, psw: int):
+    """Non-overlapping (window == stride) pool of the (rows, OW, TM) f32
+    scratch via strided ref reads — no gather, TPU-legal."""
     pooled = None
     for i in range(psh):
         for j in range(psw):
-            piece = acc[i::psh, j::psw, :]
+            piece = acc_ref[pl.ds(i, rows // psh, stride=psh),
+                            pl.ds(j, ow // psw, stride=psw), :]
             if pooled is None:
                 pooled = piece
             elif kind == "max":
@@ -134,80 +150,18 @@ def _pool_block(acc, kind: str, psh: int, psw: int):
     return pooled
 
 
-def _make_multirow_kernel(kw: int, ow: int, sh: int, sw: int, rows: int,
-                          taps: int, activation, has_bias: bool,
-                          has_add: bool = False, pool=None):
-    def _kernel(*refs):
-        refs = list(refs)
-        xa_ref, xb_ref, w_ref = refs.pop(0), refs.pop(0), refs.pop(0)
-        b_ref = refs.pop(0) if has_bias else None
-        a_ref = refs.pop(0) if has_add else None
-        o_ref = refs.pop(0)
-        acc_ref = refs.pop(0) if pool is not None else None
-        t = pl.program_id(3)
-        di = t // kw
-        dj = jax.lax.rem(t, kw)
-        # two adjacent aligned H blocks of rows*sh input rows each; the
-        # tap's window starts at local offset di (<= rows*sh by the
-        # KH - 1 <= rows*sh validity rule), so it always fits the pair
-        big = jnp.concatenate([xa_ref[0], xb_ref[0]], axis=0)
-        C = big.shape[-1]
-        blk = jax.lax.dynamic_slice(
-            big, (di, dj, 0), (rows * sh, ow * sw, C))
-        if sh > 1:
-            blk = blk.reshape(rows, sh, ow * sw, C)[:, 0]   # (rows, OW*sw, C)
-        if sw > 1:
-            blk = blk.reshape(rows, ow, sw, C)[:, :, 0, :]  # (rows, OW, C)
-        win = blk.reshape(rows * ow, C)
-        part = jnp.dot(win, w_ref[0, 0],
-                       preferred_element_type=jnp.float32)  # (rows*OW, TM)
-        part = part.reshape(rows, ow, part.shape[-1])
-
-        if pool is not None:
-            # conv partials accumulate in the f32 VMEM scratch; the
-            # output block only ever sees the pooled final tap
-            kind, psh, psw = pool
-
-            @pl.when(t == 0)
-            def _init():
-                acc_ref[...] = part
-
-            @pl.when(t > 0)
-            def _acc():
-                acc_ref[...] += part
-
-            @pl.when(t == taps - 1)
-            def _epilogue():
-                acc = acc_ref[...]
-                if has_bias:
-                    acc = acc + b_ref[0].astype(jnp.float32)
-                if activation == "relu":
-                    acc = jnp.maximum(acc, 0.0)
-                o_ref[0] = _pool_block(acc, kind, psh, psw)
-
-            return
-
-        @pl.when(t == 0)
-        def _init():
-            o_ref[0] = part
-
-        @pl.when(t > 0)
-        def _acc():
-            o_ref[0] += part
-
-        if has_bias or has_add or activation is not None:
-            @pl.when(t == taps - 1)
-            def _epilogue():
-                acc = o_ref[0]
-                if has_bias:
-                    acc = acc + b_ref[0].astype(jnp.float32)
-                if has_add:
-                    acc = acc + a_ref[0].astype(jnp.float32)
-                if activation == "relu":
-                    acc = jnp.maximum(acc, 0.0)
-                o_ref[0] = acc
-
-    return _kernel
+def _phase_extents(H, W, KH, KW, stride, padding, rows):
+    """``(OH, OW, OHB, band, Hq, Wq)``: output extents, output-row
+    blocks, phase rows per block, and the phase-split input extents."""
+    sh, sw = stride
+    OH = (H + 2 * padding[0] - KH) // sh + 1
+    OW = (W + 2 * padding[1] - KW) // sw + 1
+    OHB = -(-OH // rows)
+    # the last block's band reaches output row OHB*rows - 1: the rows
+    # past OH are zeros and feed only outputs that are sliced away
+    Hq, Wq = _compat.phase_extents(H, W, KH, KW, stride, padding,
+                                   OHB * rows, OW)
+    return OH, OW, OHB, rows + (KH - 1) // sh, Hq, Wq
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "padding",
@@ -215,7 +169,7 @@ def _make_multirow_kernel(kw: int, ow: int, sh: int, sw: int, rows: int,
                                              "tm", "rows", "interpret"))
 def cuconv_fused(x, w, bias=None, stride=(1, 1), padding=(0, 0),
                  activation=None, addend=None, pool=None,
-                 tm=128, rows=1, interpret=True):
+                 tm=128, rows=1, *, interpret):
     """x: (N, H, W, C) NHWC; w: (KH, KW, C, M) HWIO; stride (sh, sw) >= 1.
 
     bias: optional (M,) added on the final tap; activation: None | 'relu',
@@ -226,28 +180,21 @@ def cuconv_fused(x, w, bias=None, stride=(1, 1), padding=(0, 0),
     == stride, no padding) applied to the finished block in VMEM before
     writeback; mutually exclusive with ``addend``.
     ``tm``/``rows`` are the launch configuration (output-channel tile and
-    output rows per grid step); ``rows >= 2`` requires
-    ``KH - 1 <= rows*sh`` (the multi-row halo rule — the planner's
-    ``config_supports`` prunes invalid candidates).  ``pool`` always
-    takes the multi-row path and additionally needs ``rows % psh == 0``,
-    ``OH % rows == 0`` and ``OW % psw == 0``.
+    output rows per grid step).  ``pool`` additionally needs
+    ``rows % psh == 0``, ``OH % rows == 0`` and ``OW % psw == 0``.
+    ``interpret`` is required: callers resolve it per backend
+    (``kernels.ops``).
     Returns (N, OH, OW, M) — pooled to (N, OH/psh, OW/psw, M) under
     ``pool`` — in x.dtype.
     """
     N, H, W, C = x.shape
     KH, KW, _, M = w.shape
     sh, sw = stride
-    ph, pw = padding
-    Hp, Wp = H + 2 * ph, W + 2 * pw
-    OH, OW = (Hp - KH) // sh + 1, (Wp - KW) // sw + 1
-    rows = min(int(rows), OH)
+    rows = min(int(rows), (H + 2 * padding[0] - KH) // sh + 1)
     if rows < 1:
         raise ValueError(f"rows must be >= 1; got {rows}")
-    if (rows > 1 or pool is not None) and KH - 1 > rows * sh:
-        raise ValueError(
-            f"multi-row blocking needs KH - 1 <= rows*sh to cover the tap "
-            f"halo from two aligned input blocks; got KH={KH}, rows={rows}, "
-            f"sh={sh}")
+    OH, OW, OHB, band, Hq, Wq = _phase_extents(H, W, KH, KW, stride,
+                                               padding, rows)
     if pool is not None:
         if addend is not None:
             raise ValueError("pool and addend fusions are mutually "
@@ -264,85 +211,28 @@ def cuconv_fused(x, w, bias=None, stride=(1, 1), padding=(0, 0),
     if addend is not None and addend.shape != (N, OH, OW, M):
         raise ValueError(f"addend shape {addend.shape} != conv output "
                          f"shape {(N, OH, OW, M)}")
-    # widen rows so every tap's strided window slice stays in bounds:
-    # max start KW-1 plus slice length OW*sw (== Wp when sw == 1)
-    Wpad = KW - 1 + OW * sw
     (tm,), (pm,) = _compat.clamp_tiles((M,), (tm,))
+    xq = _compat.phase_split(x, stride, padding, Hq, Wq)
     wp = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, pm)))
-    has_bias = bias is not None
-    has_add = addend is not None
-    kw_common = dict(
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-        name="cuconv_fused",
-    )
-
-    if rows == 1 and pool is None:
-        xp = jnp.pad(x, ((0, 0), (ph, ph),
-                         (pw, pw + max(0, Wpad - Wp)), (0, 0)))
-        Wp = xp.shape[2]
-        grid = (N, OH, (M + pm) // tm, KH * KW)
-        in_specs = [
-            # one padded input row; H-dim block=1 => element-level shift
-            pl.BlockSpec((1, 1, Wp, C),
-                         lambda n, oh, m, t: (n, oh * sh + t // KW, 0, 0)),
-            # the tap matrix F[di, dj] (C x TM), pinned in VMEM
-            pl.BlockSpec((1, 1, C, tm),
-                         lambda n, oh, m, t: (t // KW, jax.lax.rem(t, KW),
-                                              0, m)),
-        ]
-        operands = [xp, wp]
-        if has_bias:
-            bp = jnp.pad(bias.reshape(1, M), ((0, 0), (0, pm)))
-            in_specs.append(pl.BlockSpec((1, tm),
-                                         lambda n, oh, m, t: (0, m)))
-            operands.append(bp)
-        if has_add:
-            # the residual block rides the output's index_map
-            ap = jnp.pad(addend, ((0, 0), (0, 0), (0, 0), (0, pm)))
-            in_specs.append(pl.BlockSpec((1, 1, OW, tm),
-                                         lambda n, oh, m, t: (n, oh, 0, m)))
-            operands.append(ap)
-        out = pl.pallas_call(
-            _make_kernel(KW, OW, sw, KH * KW, activation, has_bias,
-                         has_add),
-            grid=grid,
-            in_specs=in_specs,
-            # output row revisited across all taps (index_map ignores t)
-            out_specs=pl.BlockSpec((1, 1, OW, tm),
-                                   lambda n, oh, m, t: (n, oh, 0, m)),
-            out_shape=jax.ShapeDtypeStruct((N, OH, OW, M + pm), jnp.float32),
-            **kw_common,
-        )(*operands)
-        return out[..., :M].astype(x.dtype)
-
-    # multi-row blocking: rows output rows per step from two adjacent
-    # aligned input blocks of B = rows*sh rows each
-    B = rows * sh
-    OHB = -(-OH // rows)
-    # H must cover block index OHB (the second staged block of the last
-    # step) => (OHB + 1) * B padded rows; extra rows are zeros and the
-    # outputs they feed are sliced away below
-    hpad_extra = max(0, (OHB + 1) * B - Hp)
-    xp = jnp.pad(x, ((0, 0), (ph, ph + hpad_extra),
-                     (pw, pw + max(0, Wpad - Wp)), (0, 0)))
-    Wp = xp.shape[2]
     grid = (N, OHB, (M + pm) // tm, KH * KW)
     in_specs = [
-        pl.BlockSpec((1, B, Wp, C), lambda n, oh, m, t: (n, oh, 0, 0)),
-        pl.BlockSpec((1, B, Wp, C), lambda n, oh, m, t: (n, oh + 1, 0, 0)),
+        # the halo'd band of every phase at element offset oh*rows:
+        # resident across every tap and channel tile of this block
+        # (Mosaic takes element indexing on all dims or none)
+        pl.BlockSpec((pl.Element(1), pl.Element(sh), pl.Element(band),
+                      pl.Element(sw), pl.Element(Wq), pl.Element(C)),
+                     lambda n, oh, m, t: (n, 0, oh * rows, 0, 0, 0)),
+        # the tap matrix F[di, dj] (C x TM)
         pl.BlockSpec((1, 1, C, tm),
                      lambda n, oh, m, t: (t // KW, jax.lax.rem(t, KW),
                                           0, m)),
     ]
-    operands = [xp, xp, wp]
-    if has_bias:
+    operands = [xq, wp]
+    if bias is not None:
         bp = jnp.pad(bias.reshape(1, M), ((0, 0), (0, pm)))
         in_specs.append(pl.BlockSpec((1, tm), lambda n, oh, m, t: (0, m)))
         operands.append(bp)
-    if has_add:
+    if addend is not None:
         # OH padded up to the block grid so the last step's residual
         # block exists; the padded rows feed outputs sliced away below
         ap = jnp.pad(addend, ((0, 0), (0, OHB * rows - OH), (0, 0),
@@ -350,66 +240,63 @@ def cuconv_fused(x, w, bias=None, stride=(1, 1), padding=(0, 0),
         in_specs.append(pl.BlockSpec((1, rows, OW, tm),
                                      lambda n, oh, m, t: (n, oh, 0, m)))
         operands.append(ap)
-    if pool is not None:
-        kind, psh, psw = pool
-        out = pl.pallas_call(
-            _make_multirow_kernel(KW, OW, sh, sw, rows, KH * KW, activation,
-                                  has_bias, has_add, pool=(kind, psh, psw)),
-            grid=grid,
-            in_specs=in_specs,
-            # the output block is the POOLED tile: rows/psh rows per step
-            out_specs=pl.BlockSpec((1, rows // psh, OW // psw, tm),
-                                   lambda n, oh, m, t: (n, oh, 0, m)),
-            out_shape=jax.ShapeDtypeStruct(
-                (N, (OHB * rows) // psh, OW // psw, M + pm), jnp.float32),
-            # conv partials accumulate here, not in the output block
-            scratch_shapes=[pltpu.VMEM((rows, OW, tm), jnp.float32)],
-            **kw_common,
-        )(*operands)
-        return out[:, :OH // psh, :, :M].astype(x.dtype)
+    kernel = _make_kernel(KW, OW, sh, sw, rows, KH * KW, activation,
+                          bias is not None, addend is not None, pool)
+    if pool is None:
+        # the (rows, OW, TM) output block is revisited across all taps
+        out_rows, out_w, scratch = rows, OW, []
+    else:
+        # the output block is the POOLED tile; conv partials accumulate
+        # in the f32 scratch instead
+        out_rows, out_w = rows // psh, OW // psw
+        scratch = [pltpu.VMEM((rows, OW, tm), jnp.float32)]
     out = pl.pallas_call(
-        _make_multirow_kernel(KW, OW, sh, sw, rows, KH * KW, activation,
-                              has_bias, has_add),
+        kernel,
         grid=grid,
         in_specs=in_specs,
-        # (rows, OW, TM) output block revisited across all taps
-        out_specs=pl.BlockSpec((1, rows, OW, tm),
+        out_specs=pl.BlockSpec((1, out_rows, out_w, tm),
                                lambda n, oh, m, t: (n, oh, 0, m)),
-        out_shape=jax.ShapeDtypeStruct((N, OHB * rows, OW, M + pm),
+        out_shape=jax.ShapeDtypeStruct((N, OHB * out_rows, out_w, M + pm),
                                        jnp.float32),
-        **kw_common,
+        scratch_shapes=scratch,
+        compiler_params=_compat.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name="cuconv_fused",
     )(*operands)
-    return out[:, :OH, :, :M].astype(x.dtype)
+    out_h = OH if pool is None else OH // psh
+    return out[:, :out_h, :, :M].astype(x.dtype)
 
 
 def vmem_bytes(x_shape, w_shape, tm=128, rows=1, pad=(0, 0), stride=(1, 1),
                itemsize=4, addend=False, pool=None):
-    """Static VMEM footprint estimate for the fused kernel's live blocks
-    under launch config ``(tm, rows)``.
+    """Static VMEM footprint estimate for the fused kernel under launch
+    config ``(tm, rows)``, at the TPU's tiled layout
+    (``_compat.tiled_bytes``).
 
-    ``addend`` adds the residual input block (it rides the output
-    index_map, double buffered like any input); ``pool`` —
-    ``(kind, psh, psw)`` — adds the f32 scratch accumulator next to the
-    (smaller) pooled output block.
+    Counts the double-buffered input band, tap matrix, output block and
+    ``addend`` block, plus the in-kernel values a step holds: the
+    ``(rows*OW, C)`` window and its ``(rows*OW, TM)`` f32 partial.
+    ``pool`` — ``(kind, psh, psw)`` — adds the f32 scratch accumulator;
+    the output block is then the pooled tile.
     """
-    N, H, W, C = x_shape
+    _, H, W, C = x_shape
     KH, KW, _, M = w_shape
     sh, sw = stride
-    Wp = W + 2 * pad[1]
-    OW = (Wp - KW) // sw + 1
-    OH = (H + 2 * pad[0] - KH) // sh + 1
-    rows = max(1, min(int(rows), OH))
+    rows = max(1, min(int(rows), (H + 2 * pad[0] - KH) // sh + 1))
     tm = min(int(tm), M)
-    wtap = C * tm * itemsize
-    out = rows * OW * tm * 4                     # f32 accumulator
+    _, OW, _, band, _, Wq = _phase_extents(H, W, KH, KW, stride, pad, rows)
+    need = 2 * sh * sw * _compat.tiled_bytes((band, Wq, C), itemsize)
+    need += 2 * _compat.tiled_bytes((C, tm), itemsize)
+    need += (_compat.tiled_bytes((rows * OW, C), itemsize)
+             + _compat.tiled_bytes((rows * OW, tm), 4))
+    out_rows, out_w = rows, OW
     if pool is not None:
         _, psh, psw = pool
-        # scratch accumulator + the pooled output block
-        out = rows * OW * tm * 4 \
-            + (rows // max(1, psh)) * (OW // max(1, psw)) * tm * 4
-    add_blk = 2 * rows * OW * tm * itemsize if addend else 0
-    row_bytes = (KW - 1 + OW * sw) * C * itemsize
-    if rows == 1 and pool is None:
-        return 2 * (row_bytes + wtap) + out + add_blk  # x2: double buffering
-    blk = rows * sh * row_bytes                  # one aligned H block
-    return 2 * (2 * blk + wtap) + out + add_blk  # two staged blocks per step
+        need += _compat.tiled_bytes((rows, OW, tm), 4)
+        out_rows, out_w = rows // max(1, psh), OW // max(1, psw)
+    need += 2 * _compat.tiled_bytes((out_rows, out_w, tm), 4)
+    if addend:
+        need += 2 * _compat.tiled_bytes((rows, OW, tm), itemsize)
+    return need

@@ -39,7 +39,7 @@ def _kernel(xs_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "tm", "tc", "interpret"))
-def stage1_tap_gemm(xs, w, tp=256, tm=128, tc=512, interpret=True):
+def stage1_tap_gemm(xs, w, tp=256, tm=128, tc=512, *, interpret):
     """xs: (T, P, C) stacked shifted views; w: (T, C, M) filter taps.
 
     Returns the stage-1 temporaries (T, P, M), f32.

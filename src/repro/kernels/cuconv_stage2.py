@@ -25,8 +25,8 @@ def _kernel(t_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("tp", "tm", "out_dtype",
                                              "interpret"))
-def stage2_tap_sum(temps, tp=256, tm=256, out_dtype=jnp.float32,
-                   interpret=True):
+def stage2_tap_sum(temps, tp=256, tm=256, out_dtype=jnp.float32, *,
+                   interpret):
     """temps: (T, P, M) stage-1 partials -> (P, M) output plane sums."""
     T, P, M = temps.shape
     (tp, tm), (pp, pm) = _compat.clamp_tiles((P, M), (tp, tm))

@@ -12,8 +12,9 @@ VMEM scratch across contraction grid steps.
 
 Grid: ``(N, M/tm, C/tc)`` with the channel contraction innermost
 ("arbitrary").  Each step stages one image's padded spatial extent for
-a ``tc``-channel slice plus the matching (KH, KW, tc, tm) filter
-block, unrolls the KH*KW taps as strided in-register windows feeding
+a ``tc``-channel slice, split into stride phases
+(``_compat.phase_split``), plus the matching (KH, KW, tc, tm) filter
+block, unrolls the KH*KW taps as unit-stride ref windows feeding
 ``(OH*OW x tc) @ (tc x tm)`` MXU matmuls, and writes the output block
 once on the final channel step.  Because C is tiled, the VMEM working
 set is bounded no matter how many input channels the spec has — the
@@ -38,15 +39,14 @@ from repro.kernels import _compat
 def _make_kernel(KH, KW, OH, OW, sh, sw):
     def kernel(x_ref, w_ref, o_ref, acc_ref):
         c = pl.program_id(2)
-        xb = x_ref[0]                           # (Hp, Wp, tc)
-        wb = w_ref[...]                         # (KH, KW, tc, tm)
         part = None
         for i in range(KH):
             for j in range(KW):
-                win = xb[i:i + (OH - 1) * sh + 1:sh,
-                         j:j + (OW - 1) * sw + 1:sw, :]   # (OH, OW, tc)
-                t = jnp.dot(win.reshape(OH * OW, win.shape[-1]), wb[i, j],
-                            preferred_element_type=jnp.float32)
+                # tap (i, j) reads its stride phase with unit stride
+                win = x_ref[0, i % sh, pl.ds(i // sh, OH),
+                            j % sw, pl.ds(j // sw, OW), :]  # (OH, OW, tc)
+                t = jnp.dot(win.reshape(OH * OW, win.shape[-1]),
+                            w_ref[i, j], preferred_element_type=jnp.float32)
                 part = t if part is None else part + t
 
         @pl.when(c == 0)
@@ -65,46 +65,60 @@ def _make_kernel(KH, KW, OH, OW, sh, sw):
     return kernel
 
 
+def _extents(H, W, KH, KW, stride, pad):
+    """``(OH, OW, Hq, Wq)``: output extents and phase-split input
+    extents (``_compat.phase_split``)."""
+    OH = (H + 2 * pad[0] - KH) // stride[0] + 1
+    OW = (W + 2 * pad[1] - KW) // stride[1] + 1
+    return (OH, OW) + _compat.phase_extents(H, W, KH, KW, stride, pad,
+                                            OH, OW)
+
+
 def vmem_bytes(in_shape, filter_shape, stride=(1, 1), pad=(0, 0),
                tm=128, tc=256, itemsize=4):
-    """Live-block VMEM model of one grid step: the channel-sliced image
-    and filter blocks double buffered, plus the fp32 accumulator and the
-    output block."""
-    _, H, W_, _ = in_shape
-    KH, KW, _, _ = filter_shape
-    Hp, Wp = H + 2 * pad[0], W_ + 2 * pad[1]
-    OH = (Hp - KH) // stride[0] + 1
-    OW = (Wp - KW) // stride[1] + 1
-    return int(2 * (Hp * Wp * tc + KH * KW * tc * tm) * itemsize
-               + OH * OW * tm * (4 + itemsize))
+    """Live-block VMEM model of one grid step at the TPU's tiled layout
+    (``_compat.tiled_bytes``): the channel-sliced image and filter
+    blocks double buffered, the output block double buffered, the fp32
+    accumulator, and the in-kernel tap window and partial sum."""
+    _, H, W_, C = in_shape
+    KH, KW, _, M = filter_shape
+    tm, tc = min(tm, M), min(tc, C)
+    OH, OW, Hq, Wq = _extents(H, W_, KH, KW, stride, pad)
+    tb = _compat.tiled_bytes
+    return int(2 * stride[0] * stride[1] * tb((Hq, Wq, tc), itemsize)
+               + 2 * tb((KH, KW, tc, tm), itemsize)
+               + 2 * tb((OH, OW, tm), itemsize)
+               + tb((OH * OW, tm), 4)                    # accumulator
+               + tb((OH * OW, tc), itemsize)             # tap window
+               + tb((OH * OW, tm), 4))                   # partial sum
 
 
 @functools.partial(jax.jit, static_argnames=(
     "padding", "stride", "tm", "tc", "interpret"))
-def direct_conv(x, w, padding=(0, 0), stride=(1, 1), tm=128, tc=256,
-                interpret=True):
+def direct_conv(x, w, padding=(0, 0), stride=(1, 1), tm=128, tc=256, *,
+                interpret):
     """x: (N, H, W, C) NHWC; w: (KH, KW, C, M) HWIO; any stride.
 
     Bare conv (no epilogue — the direct executor is non-fusing, so
-    bias/activation/fusions apply as XLA ops downstream).  Returns
-    (N, OH, OW, M) in ``x.dtype``.
+    bias/activation/fusions apply as XLA ops downstream).  ``interpret``
+    is required: callers resolve it per backend (``kernels.ops``).
+    Returns (N, OH, OW, M) in ``x.dtype``.
     """
     N, H, W_, C = x.shape
     KH, KW, _, M = w.shape
-    ph, pw = padding
     sh, sw = stride
-    Hp, Wp = H + 2 * ph, W_ + 2 * pw
-    OH = (Hp - KH) // sh + 1
-    OW = (Wp - KW) // sw + 1
+    OH, OW, Hq, Wq = _extents(H, W_, KH, KW, stride, padding)
     (tm, tc), (pm, pc) = _compat.clamp_tiles((M, C), (tm, tc))
-    xp = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, pc)))
+    xq = _compat.phase_split(jnp.pad(x, ((0, 0),) * 3 + ((0, pc),)),
+                             stride, padding, Hq, Wq)
     wp = jnp.pad(w, ((0, 0), (0, 0), (0, pc), (0, pm)))
     grid = (N, (M + pm) // tm, (C + pc) // tc)
     out = pl.pallas_call(
         _make_kernel(KH, KW, OH, OW, sh, sw),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, Hp, Wp, tc), lambda n, mo, c: (n, 0, 0, c)),
+            pl.BlockSpec((1, sh, Hq, sw, Wq, tc),
+                         lambda n, mo, c: (n, 0, 0, 0, 0, c)),
             pl.BlockSpec((KH, KW, tc, tm), lambda n, mo, c: (0, 0, c, mo)),
         ],
         out_specs=pl.BlockSpec((1, OH, OW, tm),
@@ -115,5 +129,5 @@ def direct_conv(x, w, padding=(0, 0), stride=(1, 1), tm=128, tc=256,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="direct_conv",
-    )(xp, wp)
+    )(xq, wp)
     return out[..., :M]

@@ -71,7 +71,7 @@ def _make_kernel(tq: int, tk: int, sk_real: int, causal: bool):
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "tq", "tk", "interpret"))
-def flash_attention(q, k, v, causal=True, tq=256, tk=256, interpret=True):
+def flash_attention(q, k, v, causal=True, tq=256, tk=256, *, interpret):
     """q: (BH, Sq, D); k, v: (BH, Sk, D).  Softmax(QK^T/sqrt(D))V."""
     BH, Sq, D = q.shape
     Sk = k.shape[1]

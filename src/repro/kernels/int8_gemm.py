@@ -43,7 +43,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "tm", "tc", "interpret"))
-def int8_gemm(x2d, w, tp=256, tm=128, tc=512, interpret=True):
+def int8_gemm(x2d, w, tp=256, tm=128, tc=512, *, interpret):
     """x2d: (P, C) int8 pixels-major; w: (C, M) int8.
 
     Returns (P, M) **int32** — the undequantized accumulator.  Zero

@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to auto: Python-interpret mode on CPU (this
-container), compiled Mosaic on a real TPU.
+The one place ``interpret`` is resolved (``_auto_interpret``): ``None``
+means Python-interpret mode off the TPU and compiled Mosaic on it.  The
+raw kernel entries in ``kernels/*.py`` take no default.
 """
 from __future__ import annotations
 

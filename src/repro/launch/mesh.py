@@ -19,17 +19,25 @@ import numpy as np
 SERVE_AXIS = "data"
 
 
+def _auto_mesh(shape, axes):
+    # Auto axis types: sharding follows the in/out_shardings and
+    # with_sharding_constraint annotations (jax.make_mesh's Explicit
+    # default types every array and refuses gathers over sharded axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None, model: int = 2):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = n_devices or len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_serve_mesh(n_devices: int | None = None):

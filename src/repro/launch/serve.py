@@ -81,6 +81,8 @@ def main(argv=None):
     ap.add_argument("--process-count", type=int, default=None,
                     help="override jax.process_count() (cnn-dist)")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     if args.cnn_dist:
         return cnn_dist_main(args)

@@ -178,11 +178,16 @@ class BucketPrograms:
         ``"float32+int8"`` for a QuantPolicy with fp fallback nodes,
         ``"bfloat16"``/``"float32"`` for plain precision policies.
         Builds the bucket's plan on first use (same path as ``fn``)."""
-        if b not in self._plans:
-            self.fn(b)
-        gp = self._plans[b]
+        gp = self.graph_plan(b)
         dtypes = sorted({p.spec.dtype for p in gp.conv_plans.values()})
         return "+".join(dtypes) if dtypes else str(self._input_dtype)
+
+    def graph_plan(self, b: int):
+        """The GraphPlan global bucket ``b``'s program runs (per-shard
+        geometry in sharded mode; built on first use, as ``fn``)."""
+        if b not in self._plans:
+            self.fn(b)
+        return self._plans[b]
 
     def serve_dtypes(self) -> Dict[int, str]:
         """``{global bucket: serving dtype}`` over the configured
@@ -244,14 +249,16 @@ class BucketPrograms:
                 f = jax.jit(lambda params, xb: self.model.apply(
                     params, xb, graph_plan=gp))
             else:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
-                body = shard_map(
+                # check_vma=False: the per-shard body has no collectives,
+                # and pallas_call outputs carry no varying-axes type for
+                # the check to read
+                body = jax.shard_map(
                     lambda params, xb: self.model.apply(
                         params, xb, graph_plan=gp),
                     mesh=self.mesh,
                     in_specs=(P(), P("data", None, None, None)),
-                    out_specs=P("data"))
+                    out_specs=P("data"), check_vma=False)
                 # out sharding names only the leading (batch) dim so
                 # any output rank stays row-sharded
                 f = jax.jit(

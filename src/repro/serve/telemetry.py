@@ -36,7 +36,10 @@ def percentile(xs: Sequence[float], q: float) -> float:
     lo = int(pos)
     hi = min(lo + 1, len(s) - 1)
     frac = pos - lo
-    return s[lo] * (1.0 - frac) + s[hi] * frac
+    # lo + (hi - lo) * frac, capped at hi, is monotone under rounding;
+    # lo * (1 - frac) + hi * frac is not (a tied tail could read
+    # p95 > p99 by one ulp)
+    return min(s[lo] + (s[hi] - s[lo]) * frac, s[hi])
 
 
 def rollup_percentiles(xs: Sequence[float],

@@ -193,7 +193,7 @@ def test_forced_infeasible_config_raises_naming_executor_config_spec():
     msg = str(e.value)
     assert "cuconv_pallas" in msg and "rows" in msg and spec.key() in msg
     # a config whose working set blows the VMEM budget is refused too
-    big = cs.ConvSpec((1, 8, 1200, 1024), (3, 3, 1024, 256),
+    big = cs.ConvSpec((1, 8, 300, 1024), (3, 3, 1024, 256),
                       (1, 1), (1, 1))
     assert ex.get("cuconv_pallas").supports(big)[0]     # default cfg fits
     with pytest.raises(ValueError, match="VMEM"):
@@ -481,6 +481,44 @@ def test_measure_algorithm_degrades_on_broken_tuning_declarations(rng):
         assert best == "lax"
     finally:
         ex.unregister("broken_tuning_plugin")
+
+
+class _RefusedConfigExecutor(ex.Executor):
+    """Registered tunable executor whose kernel fails under one launch
+    config, as a kernel the backend's compiler refuses would."""
+    name = "refused_config_plugin"
+    tunable = ("tm",)
+
+    def configs(self, spec):
+        return (ex.LaunchConfig.of({"tm": 1}), ex.LaunchConfig.of({"tm": 2}))
+
+    def _execute(self, spec, x, w, bias, interpret, config=None):
+        if config["tm"] == 2:
+            raise RuntimeError("kernel refused by the compiler")
+        return cc.conv_lax(x, w, stride=spec.stride, padding=spec.padding)
+
+
+def test_sweeps_record_failed_candidates(rng):
+    """A candidate that raises drops out of the sweep AND is recorded in
+    MEASURE_STATS["failures"] with its executor, config and error."""
+    ex.register(_RefusedConfigExecutor())
+    try:
+        spec = _spec(GEOMS[2])
+        x, w, b = _operands(spec, rng)
+        algo, cfg = autotune.measure_config(
+            x, w, repeats=1, algorithm="refused_config_plugin", bias=b,
+            spec=spec)
+        assert (algo, cfg.as_dict()) == ("refused_config_plugin", {"tm": 1})
+        fails = autotune.MEASURE_STATS["failures"]
+        assert len(fails) == 1
+        assert fails[0]["executor"] == "refused_config_plugin"
+        assert fails[0]["config"] == {"tm": 2}
+        assert fails[0]["spec"] == spec.key()
+        assert "kernel refused by the compiler" in fails[0]["error"]
+        assert autotune.reset_measure_stats()["failures"] == fails
+        assert autotune.MEASURE_STATS["failures"] == []
+    finally:
+        ex.unregister("refused_config_plugin")
 
 
 def test_forced_tune_algo_still_runs_the_executor_sweep():

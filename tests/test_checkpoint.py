@@ -163,13 +163,12 @@ from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.dist import compress as C
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = jax.make_mesh((4,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 64)), jnp.float32)
 err0 = jnp.zeros((4, 64), jnp.float32)
 
-from jax.experimental.shard_map import shard_map
-
-@partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
          out_specs=(P("pod"), P("pod")))
 def f(xs, es):
     out, new_e = C.compressed_psum(xs[0], "pod", es[0])

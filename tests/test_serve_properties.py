@@ -123,6 +123,14 @@ def test_rollup_percentiles_monotone(samples):
     assert min(xs) <= ps["p50"] and ps["p99"] <= max(xs)
 
 
+def test_rollup_percentiles_monotone_on_tied_tail():
+    """A tied tail interpolates to exactly the tied value at every q
+    (the weighted-sum form read p95 one ulp above p99 here)."""
+    xs = [s / 7.0 for s in (0, 0, 0, 0, 0, 8735, 8735)]
+    ps = rollup_percentiles(xs)
+    assert ps["p95"] == ps["p99"] == xs[-1]
+
+
 def test_rollup_percentiles_rejects_empty():
     with pytest.raises(ValueError):
         rollup_percentiles([])

@@ -106,7 +106,8 @@ from repro.optim import adamw_init
 cfg = dataclasses.replace(smoke_variant(get_config("qwen2-1.5b")),
                           d_model=64, num_heads=4, num_kv_heads=2,
                           grad_accum=2)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(8, model=2)
 rules = sh.make_rules("train", multi_pod=False)
 state_shapes = St.state_specs(cfg)
 pspecs = sh.param_specs(state_shapes["params"], rules)
