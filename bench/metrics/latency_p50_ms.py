@@ -1,0 +1,9 @@
+"""Median over every request served of its completion minus its
+scheduled send, on the benchmark's clock (ms)."""
+from bench.stats import percentile
+
+
+def read(win):
+    lat = [(s.t_done - s.t_sched) * 1e3 for s in win.sent
+           if s.status == "served"]
+    return percentile(lat, 50) if lat else None
