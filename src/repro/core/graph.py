@@ -839,39 +839,43 @@ class GraphPlan:
         the calibration collector rides this hook.
         """
         params = self._named_params(params)
-        from repro.kernels import ops
         values = {self.graph.input_name: x}
         for node in self.graph.nodes:
-            ins = [values[e] for e in node.inputs]
-            if isinstance(node, ConvOp):
-                if observe is not None:
-                    observe(node.name, ins[0])
-                p = self._node_params(params, node, node.spec.has_bias)
-                a = ins[1] if node.spec.fused_add != "none" else None
-                y = self._node_fn(node.name)(
-                    ins[0], p["w"], p["b"] if node.spec.has_bias else None, a)
-            elif isinstance(node, PoolOp):
-                y = ops.pool2d(ins[0], node.kind, node.window,
-                               node.stride, node.padding)
-            elif isinstance(node, AddOp):
-                y = ins[0]
-                for other in ins[1:]:
-                    y = y + other
-                if node.activation == "relu":
-                    y = jax.nn.relu(y)
-            elif isinstance(node, ConcatOp):
-                y = jnp.concatenate(ins, axis=-1)
-            elif isinstance(node, GapOp):
-                y = ins[0].mean(axis=(1, 2))
-            elif isinstance(node, DenseOp):
-                p = self._node_params(params, node, node.bias)
-                y = ins[0] @ p["w"]
-                if node.bias:
-                    y = y + p["b"]
-            else:
-                raise TypeError(f"unknown IR node type {type(node)}")
-            values[node.name] = y
+            # every op a node lowers to carries the node's name in its
+            # op_name metadata, so a device trace can attribute it
+            with jax.named_scope(node.name):
+                values[node.name] = self._run_node(
+                    node, [values[e] for e in node.inputs], params, observe)
         return values[self.graph.output]
+
+    def _run_node(self, node: OpSpec, ins: Sequence, params: Mapping,
+                  observe: Optional[Callable]):
+        """One node's output from its input values."""
+        from repro.kernels import ops
+        if isinstance(node, ConvOp):
+            if observe is not None:
+                observe(node.name, ins[0])
+            p = self._node_params(params, node, node.spec.has_bias)
+            a = ins[1] if node.spec.fused_add != "none" else None
+            return self._node_fn(node.name)(
+                ins[0], p["w"], p["b"] if node.spec.has_bias else None, a)
+        if isinstance(node, PoolOp):
+            return ops.pool2d(ins[0], node.kind, node.window,
+                              node.stride, node.padding)
+        if isinstance(node, AddOp):
+            y = ins[0]
+            for other in ins[1:]:
+                y = y + other
+            return jax.nn.relu(y) if node.activation == "relu" else y
+        if isinstance(node, ConcatOp):
+            return jnp.concatenate(ins, axis=-1)
+        if isinstance(node, GapOp):
+            return ins[0].mean(axis=(1, 2))
+        if isinstance(node, DenseOp):
+            p = self._node_params(params, node, node.bias)
+            y = ins[0] @ p["w"]
+            return y + p["b"] if node.bias else y
+        raise TypeError(f"unknown IR node type {type(node)}")
 
     def _attach_quant(self) -> None:
         """Re-attach the quantization payload (calibrated activation

@@ -245,17 +245,21 @@ class BucketPrograms:
         if f is None:
             gp = self._shard_plan(b)
             self._plans[b] = gp
+
+            def program(params, xb):
+                return self.model.apply(params, xb, graph_plan=gp)
+            # a stable per-bucket name: the compiled module (and each
+            # of its runs in a device trace) is jit_serve_b<bucket>
+            program.__name__ = program.__qualname__ = f"serve_b{b}"
             if self.mesh is None:
-                f = jax.jit(lambda params, xb: self.model.apply(
-                    params, xb, graph_plan=gp))
+                f = jax.jit(program)
             else:
                 from jax.sharding import PartitionSpec as P
                 # check_vma=False: the per-shard body has no collectives,
                 # and pallas_call outputs carry no varying-axes type for
                 # the check to read
                 body = jax.shard_map(
-                    lambda params, xb: self.model.apply(
-                        params, xb, graph_plan=gp),
+                    program,
                     mesh=self.mesh,
                     in_specs=(P(), P("data", None, None, None)),
                     out_specs=P("data"), check_vma=False)

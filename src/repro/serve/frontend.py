@@ -49,10 +49,17 @@ scheduler in front of them:
   utilization/imbalance rollups.
 
 * **Telemetry.**  Every request leaves queue/transfer/compute/total
-  latency (serve/telemetry.py); ``stats()`` exposes p50/p95/p99
-  rollups, deadline misses, and overlap counters, and
-  ``benchmarks/graph_serve.py`` writes them into
-  ``BENCH_graph_serve.json``.
+  latency and its ``frontend.close`` span; every batch leaves its
+  sequence id, the ids of the requests it carried, and its host spans
+  ``frontend.pack``/``put``/``launch`` (stamped in ``_dispatch``) and
+  ``frontend.wait``/``fetch``/``scatter`` (stamped in
+  ``_harvest_one``), all on the frontend's clock
+  (serve/telemetry.py).  The stamps are always taken — about eight
+  clock reads per batch.  ``stats()`` exposes p50/p95/p99 rollups per
+  request stage (``latency_ms``) and per batch span (``batch_ms``),
+  deadline misses, and overlap counters; ``telemetry.spans()`` lists
+  the spans for a trace reduction, and ``benchmarks/graph_serve.py``
+  writes the rollups into ``BENCH_graph_serve.json``.
 
 The scheduler is single-threaded and clock-injected (``clock=``): JAX's
 async dispatch provides the device-side concurrency, so behaviour is
@@ -70,7 +77,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve.cnn import BucketPrograms, ImageRequest, scatter_outputs
-from repro.serve.telemetry import BatchTrace, RequestTrace, Telemetry
+from repro.serve.telemetry import (
+    BatchTrace, RequestTrace, Telemetry, union_length)
 
 #: request lifecycle states
 PENDING = "pending"
@@ -110,9 +118,13 @@ class ServeRequest(ImageRequest):
     _submit_t: float = 0.0
     _deadline_t: Optional[float] = None     # absolute, frontend clock
     _seq: int = -1
+    _close_t: Optional[float] = None        # its first batch closed
     _first_dispatch_t: Optional[float] = None
     _transfer_ms: float = 0.0
-    _compute_ms: float = 0.0
+    # (dispatch, harvest) of every batch that carried one of its units
+    _windows: List[Tuple[float, float]] = dataclasses.field(
+        default_factory=list)
+    _batch_ids: List[int] = dataclasses.field(default_factory=list)
     _served_units: int = 0
 
 
@@ -180,6 +192,7 @@ class AsyncServeFrontend:
         self._inflight: collections.deque = collections.deque()
         self._completed: List[ServeRequest] = []
         self._seq = 0
+        self._batch_seq = 0
         self._max_inflight = 0
         self._slo_closes = 0
         self._batch_counts: Dict[str, int] = {}
@@ -282,10 +295,14 @@ class AsyncServeFrontend:
                 self._slo_closes += 1
         b = progs.pick_bucket(len(pend))
         chunk, self._pending[shape] = pend[:b], pend[b:]
+        for r, _ in chunk:
+            if r._close_t is None:
+                r._close_t = now
         return chunk, b
 
     def _dispatch(self, shape, chunk, bucket: int) -> None:
         progs = self.programs[shape]
+        tp = self._clock()
         xb = progs.pack(chunk, bucket)
         # transfer: host blocks only on the COPY — any in-flight batch
         # keeps computing on the device meanwhile (the overlap).  The
@@ -298,15 +315,22 @@ class AsyncServeFrontend:
         t1 = self._clock()
         y = progs.fn(bucket)(progs.params, xd)  # async dispatch: no block
         td = self._clock()
+        batch_id, self._batch_seq = self._batch_seq, self._batch_seq + 1
+        rids: List[int] = []
+        for r, _ in chunk:      # a request's units are contiguous
+            if r._batch_ids and r._batch_ids[-1] == batch_id:
+                continue
+            r._batch_ids.append(batch_id)
+            rids.append(r.rid)
+            if r._first_dispatch_t is None:
+                r._first_dispatch_t = t0
         trace = BatchTrace(
             geometry=_geom(shape), bucket=bucket, units=len(chunk),
             padded=bucket - len(chunk), transfer_t0=t0, transfer_t1=t1,
             dispatch_t=td, overlapped=overlapped,
             shard_units=progs.shard_units(len(chunk), bucket),
-            dtype=progs.serve_dtype(bucket))
-        for r, _ in chunk:
-            if r._first_dispatch_t is None:
-                r._first_dispatch_t = t0
+            dtype=progs.serve_dtype(bucket), batch_id=batch_id,
+            request_ids=tuple(rids), pack_t0=tp)
         self._inflight.append(_InFlight(shape, list(chunk), y, trace))
         self._max_inflight = max(self._max_inflight, len(self._inflight))
         key = f"{_geom(shape)}/b{bucket}"
@@ -314,13 +338,17 @@ class AsyncServeFrontend:
 
     def _harvest_one(self) -> None:
         fl = self._inflight.popleft()
+        tw = self._clock()
+        ready = jax.block_until_ready(fl.result)
+        tf = self._clock()
         # device_get is an EXPLICIT device->host gather (sharded outputs
         # reassemble across the mesh), keeping a warm serve loop clean
         # under jax.transfer_guard("disallow")
-        y = np.asarray(jax.device_get(jax.block_until_ready(fl.result)))
+        y = np.asarray(jax.device_get(ready))
         now = self._clock()
-        fl.trace.harvest_t = now
-        self.telemetry.record_batch(fl.trace)
+        trace = fl.trace
+        trace.wait_t0, trace.wait_t1, trace.harvest_t = tw, tf, now
+        self.telemetry.record_batch(trace)
         scatter_outputs(fl.chunk, y)
         seen: Dict[int, ServeRequest] = {}
         counts: Dict[int, int] = {}
@@ -328,11 +356,12 @@ class AsyncServeFrontend:
             seen[id(r)] = r
             counts[id(r)] = counts.get(id(r), 0) + 1
         for rid_, r in seen.items():
-            r._transfer_ms += fl.trace.transfer_ms
-            r._compute_ms += fl.trace.compute_ms
+            r._transfer_ms += trace.transfer_ms
+            r._windows.append((trace.dispatch_t, trace.harvest_t))
             r._served_units += counts[rid_]
             if r._served_units == r.images.shape[0]:
                 self._complete(r, now)
+        trace.scatter_t1 = self._clock()
 
     def _complete(self, req: ServeRequest, now: float) -> None:
         req.status = SERVED
@@ -344,8 +373,10 @@ class AsyncServeFrontend:
             images=int(req.images.shape[0]), status=SERVED,
             deadline_ms=deadline_ms,
             queue_ms=(req._first_dispatch_t - req._submit_t) * 1e3,
-            transfer_ms=req._transfer_ms, compute_ms=req._compute_ms,
-            total_ms=(now - req._submit_t) * 1e3))
+            transfer_ms=req._transfer_ms,
+            compute_ms=union_length(req._windows) * 1e3,
+            total_ms=(now - req._submit_t) * 1e3, submit_t=req._submit_t,
+            close_t=req._close_t, batch_ids=tuple(req._batch_ids)))
         self._completed.append(req)
 
     # -- serving entry points -------------------------------------------
@@ -398,8 +429,10 @@ class AsyncServeFrontend:
 
     def stats(self) -> Dict:
         """JSON-ready serving summary: request/batch counters, deadline
-        misses, double-buffer overlap counters, and p50/p95/p99 latency
-        rollups per stage (queue/transfer/compute/total)."""
+        misses, double-buffer overlap counters, p50/p95/p99 latency
+        rollups per request stage (``latency_ms``:
+        queue/transfer/compute/total) and per batch span (``batch_ms``:
+        pack/put/launch/wait/fetch/scatter)."""
         st = self.telemetry.rollup()
         served = [t for t in self.telemetry.requests
                   if t.status == SERVED]

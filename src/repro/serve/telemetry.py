@@ -1,14 +1,36 @@
-"""Per-request serving telemetry: latency stages and percentile rollups.
+"""Per-request serving telemetry: latency stages, host spans and
+percentile rollups.
 
 Every request served by the async front end (serve/frontend.py) leaves a
 ``RequestTrace`` — how long it queued, how long its batches spent in
 host→device transfer, how long the device computed, and the wall total —
 and every dispatched batch leaves a ``BatchTrace`` (geometry, bucket,
-padding, the transfer/dispatch/harvest timeline, and whether its
-transfer overlapped an in-flight batch — the double-buffering signal).
+padding, the host timeline of its stages, and whether its transfer
+overlapped an in-flight batch — the double-buffering signal).
+
+The timeline is stamped where the work happens, on the frontend's
+clock.  Each batch carries a sequence id and the ids of the requests it
+carried, and six host spans, one after another (``BATCH_STAGES``):
+
+======================  ================================================
+``frontend.pack``       packing the batch's units into one host array
+``frontend.put``        ``device_put`` + ``block_until_ready`` of it
+``frontend.launch``     the call of the jitted bucket program (async)
+``frontend.wait``       ``block_until_ready`` on the result at harvest
+``frontend.fetch``      ``device_get`` of the result to host numpy
+``frontend.scatter``    scattering the outputs, completing requests
+======================  ================================================
+
+and every admitted request one more, ``frontend.close`` (submit → the
+close of the batch that took its first unit: the admission policy's
+wait).  ``Telemetry.spans()`` lists them as ``(name, start, end,
+batch_id, request_ids)`` tuples, the shape a trace reduction ties to a
+device trace.
+
 ``Telemetry.rollup()`` turns the traces into the machine-readable
 summary ``frontend.stats()`` exposes and ``BENCH_graph_serve.json``
-records: p50/p95/p99 per stage, deadline-miss counts, overlap counters.
+records: p50/p95/p99 per request stage (``latency_ms``) and per batch
+span (``batch_ms``), deadline-miss counts, overlap counters.
 
 The module is deliberately model-free: it never imports jax and knows
 nothing about programs or plans, so any serving layer can record into
@@ -18,10 +40,24 @@ convert to milliseconds.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 #: the latency stages every request is accounted under (ms in rollups)
 STAGES = ("queue", "transfer", "compute", "total")
+
+#: the host spans of every batch, in the order they happen, each with
+#: the ``BatchTrace`` stamps that bound it
+BATCH_STAGES = {
+    "pack": ("pack_t0", "transfer_t0"),
+    "put": ("transfer_t0", "transfer_t1"),
+    "launch": ("transfer_t1", "dispatch_t"),
+    "wait": ("wait_t0", "wait_t1"),
+    "fetch": ("wait_t1", "harvest_t"),
+    "scatter": ("harvest_t", "scatter_t1"),
+}
+
+#: ``(name, start s, end s, batch_id, request_ids)``
+Span = Tuple[str, float, float, int, Tuple[int, ...]]
 
 
 def percentile(xs: Sequence[float], q: float) -> float:
@@ -42,6 +78,18 @@ def percentile(xs: Sequence[float], q: float) -> float:
     return min(s[lo] + (s[hi] - s[lo]) * frac, s[hi])
 
 
+def union_length(intervals: Sequence[Tuple[float, float]]) -> float:
+    """Total length covered by ``(start, end)`` intervals, overlaps
+    counted once."""
+    total, edge = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        a = max(a, edge)
+        if b > a:
+            total += b - a
+            edge = b
+    return total
+
+
 def rollup_percentiles(xs: Sequence[float],
                        qs: Sequence[float] = (50, 95, 99)) -> Dict[str, float]:
     """``{"p50": ..., "p95": ..., "p99": ...}`` for one latency series."""
@@ -52,13 +100,18 @@ def rollup_percentiles(xs: Sequence[float],
 class RequestTrace:
     """One served (or rejected) request's latency accounting.
 
-    ``transfer_ms``/``compute_ms`` sum over every batch that carried one
-    of the request's images — a request larger than the biggest bucket
-    experiences several transfer/compute windows and is charged all of
-    them.  ``compute_ms`` is the in-flight window (dispatch → observed
-    completion): with double buffering it may include time queued behind
-    the previous batch on the device, which is exactly what the request
-    experienced.
+    ``transfer_ms`` sums over every batch that carried one of the
+    request's images (transfers run one after another on the host).
+    ``compute_ms`` is the union of those batches' in-flight windows
+    (dispatch → observed completion): with double buffering a window
+    may include time queued behind the previous batch on the device,
+    which is exactly what the request experienced, and two windows of
+    one request may overlap, which is counted once.
+
+    ``submit_t``/``close_t`` (frontend clock) bound the request's
+    ``frontend.close`` span; ``batch_ids`` lists every batch that
+    carried one of its units, in dispatch order.  A request rejected
+    at admission has no ``close_t`` and no batches.
     """
     rid: int
     geometry: str                       # "HxWxC"
@@ -69,6 +122,16 @@ class RequestTrace:
     transfer_ms: float
     compute_ms: float
     total_ms: float
+    submit_t: float = 0.0
+    close_t: Optional[float] = None
+    batch_ids: Tuple[int, ...] = ()
+
+    @property
+    def close_ms(self) -> Optional[float]:
+        """Submit → the close of its first batch (None if rejected)."""
+        if self.close_t is None:
+            return None
+        return (self.close_t - self.submit_t) * 1e3
 
     def stage_ms(self, stage: str) -> float:
         return getattr(self, f"{stage}_ms")
@@ -87,7 +150,14 @@ class BatchTrace:
     serving dtype of the bucket program that ran the batch (e.g.
     ``"float32"``, ``"bfloat16"``, ``"float32+int8"`` for a quantized
     graph with fp fallback nodes) — stamped by the dispatcher, opaque
-    here."""
+    here.
+
+    ``batch_id`` is the batch's dispatch sequence number and
+    ``request_ids`` the ids of the requests whose units it carried.
+    The stamps bound the ``BATCH_STAGES`` spans back to back:
+    ``pack_t0`` → ``transfer_t0`` → ``transfer_t1`` → ``dispatch_t``
+    on dispatch, ``wait_t0`` → ``wait_t1`` → ``harvest_t`` →
+    ``scatter_t1`` on harvest; a stamp not taken is None."""
     geometry: str
     bucket: int
     units: int                          # real (non-padded) images
@@ -99,14 +169,27 @@ class BatchTrace:
     overlapped: bool = False
     shard_units: Optional[Sequence[int]] = None    # per-device real images
     dtype: Optional[str] = None         # bucket program's serving dtype
+    batch_id: int = -1
+    request_ids: Tuple[int, ...] = ()
+    pack_t0: Optional[float] = None
+    wait_t0: Optional[float] = None
+    wait_t1: Optional[float] = None
+    scatter_t1: Optional[float] = None
 
     @property
     def transfer_ms(self) -> float:
         return (self.transfer_t1 - self.transfer_t0) * 1e3
 
-    @property
-    def compute_ms(self) -> float:
-        return (self.harvest_t - self.dispatch_t) * 1e3
+    def stage_bounds(self, stage: str
+                     ) -> Optional[Tuple[float, float]]:
+        """``(start, end)`` of one ``BATCH_STAGES`` span, or None if a
+        stamp is missing."""
+        a, b = (getattr(self, k) for k in BATCH_STAGES[stage])
+        return None if a is None or b is None else (a, b)
+
+    def stage_ms(self, stage: str) -> Optional[float]:
+        bounds = self.stage_bounds(stage)
+        return None if bounds is None else (bounds[1] - bounds[0]) * 1e3
 
 
 class Telemetry:
@@ -134,6 +217,39 @@ class Telemetry:
         return {stage: rollup_percentiles([t.stage_ms(stage)
                                            for t in served])
                 for stage in STAGES}
+
+    def batch_ms(self) -> Dict[str, Dict[str, float]]:
+        """p50/p95/p99 per batch span (``BATCH_STAGES``) over the
+        batches that carry all of that span's stamps."""
+        out = {}
+        for stage in BATCH_STAGES:
+            ms = [m for m in (b.stage_ms(stage) for b in self.batches)
+                  if m is not None]
+            if ms:
+                out[stage] = rollup_percentiles(ms)
+        return out
+
+    def spans(self, batches: Optional[Sequence[BatchTrace]] = None,
+              requests: Optional[Sequence[RequestTrace]] = None
+              ) -> List[Span]:
+        """The frontend's host spans, ``(name, start, end, batch_id,
+        request_ids)``, of ``batches`` and ``requests`` (default: all
+        recorded): one ``frontend.close`` per admitted request, tagged
+        with its first batch, and one ``frontend.<stage>`` per stamped
+        ``BATCH_STAGES`` span of each batch."""
+        out: List[Span] = []
+        for r in self.requests if requests is None else requests:
+            if r.close_t is not None:
+                out.append(("frontend.close", r.submit_t, r.close_t,
+                            r.batch_ids[0] if r.batch_ids else -1,
+                            (r.rid,)))
+        for b in self.batches if batches is None else batches:
+            for stage in BATCH_STAGES:
+                bounds = b.stage_bounds(stage)
+                if bounds is not None:
+                    out.append((f"frontend.{stage}", *bounds, b.batch_id,
+                                tuple(b.request_ids)))
+        return out
 
     def shard_rollup(self) -> Optional[Dict]:
         """Per-device utilization + imbalance over the sharded batches.
@@ -181,6 +297,7 @@ class Telemetry:
             "overlapped_batches": sum(1 for b in self.batches
                                       if b.overlapped),
             "latency_ms": self.latency_ms(),
+            "batch_ms": self.batch_ms(),
         }
         dtypes = self.dtype_rollup()
         if dtypes:

@@ -14,6 +14,7 @@ from repro.models.cnn import SimpleCNN, resnet_like
 from repro.serve.frontend import (
     DEADLINE_EXCEEDED, SERVED, AsyncServeFrontend, DeadlineExceeded,
     ServeRequest)
+from repro.serve.telemetry import BATCH_STAGES
 
 
 TINY = [(3, 3, 6, 2), (1, 1, 4, 1)]
@@ -398,3 +399,157 @@ def test_acceptance_resnet_two_resolutions_zero_misses(rng):
     assert st["served"] == 6
     assert len(st["batches_by_program"]) >= 2   # both geometries dispatched
     assert st["latency_ms"]["total"]["p99"] >= st["latency_ms"]["total"]["p50"]
+
+
+# ---------------------------------------------------------------------------
+# host spans: the batch timeline, its ids, and the close span
+
+class SteppingClock:
+    """Deterministic clock that moves 1 ms on every reading, so every
+    stamp the frontend takes is distinct and ordered."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self) -> float:
+        self.t += 1e-3
+        return self.t
+
+
+def _stream(rng, fe, sizes, hw=8):
+    for i, n in enumerate(sizes):
+        fe.submit(ServeRequest(rid=i, images=rng.normal(
+            size=(n, hw, hw, 3)).astype(np.float32)))
+
+
+def test_compute_ms_counts_overlapping_batch_windows_once(rng, tiny):
+    """One request split over two batches in flight together: its
+    compute time is the union of their windows (first dispatch → last
+    harvest), not their sum, and never exceeds its total."""
+    model, params = tiny
+    clock = SteppingClock()
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (2,)},
+                            pipeline_depth=2, clock=clock)
+    fe.warmup()
+    _stream(rng, fe, [4])
+    fe.run()
+    a, b = fe.telemetry.batches
+    assert b.dispatch_t < a.harvest_t           # the windows overlap
+    (t,) = fe.telemetry.requests
+    assert t.compute_ms == pytest.approx((b.harvest_t - a.dispatch_t) * 1e3)
+    windows = (a.harvest_t - a.dispatch_t) + (b.harvest_t - b.dispatch_t)
+    assert t.compute_ms < windows * 1e3         # the sum counted twice
+    assert t.total_ms >= t.compute_ms
+
+
+def test_batch_spans_are_ordered_and_inside_poll_and_flush(rng, tiny):
+    model, params = tiny
+    clock = SteppingClock()
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (1, 2)},
+                            max_wait_ms=0.0, pipeline_depth=2, clock=clock)
+    fe.warmup()
+    calls = []
+    for sizes in ([3, 1], [2], [1, 1, 2]):
+        _stream(rng, fe, sizes)
+        for entry in (fe.poll, fe.flush):
+            t0 = clock()
+            entry()
+            calls.append((t0, clock()))
+    assert len(fe.telemetry.batches) >= 5
+    for b in fe.telemetry.batches:
+        bounds = [b.stage_bounds(s) for s in BATCH_STAGES]
+        edge = float("-inf")
+        for a, z in bounds:             # pack ≤ put ≤ … ≤ scatter
+            assert edge <= a <= z
+            edge = z
+        for group in (bounds[:3], bounds[3:]):    # dispatch, harvest
+            assert any(c0 <= group[0][0] and group[-1][1] <= c1
+                       for c0, c1 in calls)
+
+
+def test_batch_and_request_ids_link_requests_to_their_batches(rng, tiny):
+    model, params = tiny
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (1, 2, 4)})
+    fe.warmup()
+    sizes = [3, 1, 6, 2, 1]
+    _stream(rng, fe, sizes)
+    fe.run()
+    batches = fe.telemetry.batches
+    assert sorted(b.batch_id for b in batches) == list(range(len(batches)))
+    by_id = {b.batch_id: b for b in batches}
+    for t in fe.telemetry.requests:
+        carried = [b.batch_id for b in batches if t.rid in b.request_ids]
+        assert list(t.batch_ids) == sorted(carried) and carried
+    assert sum(len(by_id[i].request_ids) for i in by_id) >= len(sizes)
+    # every unit of a split request rode one of its batches
+    assert sum(b.units for b in batches) == sum(sizes)
+    spans = fe.telemetry.spans()
+    names = {s[0] for s in spans}
+    assert names == {"frontend.close"} | {f"frontend.{s}"
+                                          for s in BATCH_STAGES}
+    for name, a, z, bid, rids in spans:
+        assert a <= z
+        if name != "frontend.close":
+            assert rids == by_id[bid].request_ids
+
+
+def test_close_span_ends_before_its_first_batch_packs(rng, tiny):
+    model, params = tiny
+    clock = SteppingClock()
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (2, 4)},
+                            max_wait_ms=5.0, clock=clock)
+    fe.warmup()
+    _stream(rng, fe, [1, 3, 2, 1])
+    fe.poll()                           # closes the full 4-bucket only
+    fe.run()
+    by_id = {b.batch_id: b for b in fe.telemetry.batches}
+    closes = {rids[0]: (a, z, bid) for name, a, z, bid, rids
+              in fe.telemetry.spans() if name == "frontend.close"}
+    assert sorted(closes) == [0, 1, 2, 3]
+    for t in fe.telemetry.requests:
+        a, z, bid = closes[t.rid]
+        assert (a, z, bid) == (t.submit_t, t.close_t, t.batch_ids[0])
+        assert t.submit_t <= t.close_t <= by_id[bid].pack_t0
+        assert t.close_ms == pytest.approx((t.close_t - t.submit_t) * 1e3)
+        assert t.close_ms <= t.queue_ms
+
+
+def test_batch_ms_rollup_is_json_ready_and_monotone(rng, tiny):
+    model, params = tiny
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (1, 2)})
+    fe.warmup()
+    _stream(rng, fe, [2, 1, 3, 2, 1])
+    fe.run()
+    st = json.loads(json.dumps(fe.stats()))
+    assert list(st["batch_ms"]) == list(BATCH_STAGES)
+    for stage, ps in st["batch_ms"].items():
+        assert ps["p50"] <= ps["p95"] <= ps["p99"], stage
+        assert ps["p50"] >= 0.0, stage
+
+
+@pytest.mark.parametrize("intervals,length", [
+    ([], 0.0),
+    ([(0.0, 2.0), (1.0, 3.0)], 3.0),            # overlap counted once
+    ([(5.0, 6.0), (0.0, 1.0)], 2.0),            # disjoint, unsorted
+    ([(0.0, 4.0), (1.0, 2.0)], 4.0),            # nested
+])
+def test_union_length(intervals, length):
+    from repro.serve.telemetry import union_length
+    assert union_length(intervals) == pytest.approx(length)
+
+
+def test_spans_skip_missing_stamps_and_rejected_requests():
+    from repro.serve.telemetry import BatchTrace, RequestTrace, Telemetry
+    t = Telemetry()
+    t.record_batch(BatchTrace(geometry="8x8x3", bucket=2, units=2, padded=0,
+                              transfer_t0=1.0, transfer_t1=2.0,
+                              dispatch_t=3.0, batch_id=0,
+                              request_ids=(7,)))
+    t.record_request(RequestTrace(
+        rid=8, geometry="8x8x3", images=1, status=DEADLINE_EXCEEDED,
+        deadline_ms=1.0, queue_ms=5.0, transfer_ms=0.0, compute_ms=0.0,
+        total_ms=5.0))
+    assert t.spans() == [("frontend.put", 1.0, 2.0, 0, (7,)),
+                         ("frontend.launch", 2.0, 3.0, 0, (7,))]
+    assert list(t.batch_ms()) == ["put", "launch"]
+    assert t.requests[0].close_ms is None
