@@ -126,7 +126,7 @@ class LaunchConfig:
 # TPU block alignment: a block's last dim must be a multiple of 128
 # lanes and its second-last a multiple of 8 sublanes, unless the block
 # spans the whole array dim.  These tile dims land on a lane axis of
-# some kernel block; the other tiled dims (tp, tt) land on a sublane axis.
+# some kernel block; the other tiled dim (tp) lands on a sublane axis.
 _LANE_DIMS = ("tm", "tc")
 
 
@@ -876,7 +876,7 @@ class FusedPallasExecutor(Executor):
             {"tm": m})
 
     def _config_supports(self, spec, config):
-        from repro.kernels.cuconv_fused import STRIDED_LOAD_LANES
+        from repro.kernels._compat import STRIDED_LANES
         rows = config.get("rows", 1)
         _, oh, _, m = spec.out_shape
         if rows > oh:
@@ -891,10 +891,10 @@ class FusedPallasExecutor(Executor):
                 return False, (f"fused pool needs OH % rows == 0; "
                                f"got OH={oh}, rows={rows}")
             tm = min(config.get("tm", 128), m)
-            if tm > STRIDED_LOAD_LANES:
+            if tm > STRIDED_LANES:
                 return False, (f"fused pool reads its scratch with strided "
                                f"loads, which Mosaic takes on at most "
-                               f"{STRIDED_LOAD_LANES} lanes; got tm={tm}")
+                               f"{STRIDED_LANES} lanes; got tm={tm}")
         return True, "config geometry ok"
 
     def config_cost(self, spec, config):
@@ -948,40 +948,56 @@ class FusedPallasExecutor(Executor):
             interpret=interpret)
 
 
-# Winograd-Pallas launch candidates: (tt, tm, tc) tile triples tried
-# under both F(m,3) variants.  Candidate 0 under m=2 is the kernel's
-# shipped default geometry; the smaller tile counts keep the F(4,3)
-# domain (36 positions vs 16) inside the VMEM budget on big-channel
-# specs, with the channel tiles still 128-lane aligned.
+# Winograd-Pallas launch candidates: (tiles, tm, tc) triples tried
+# under both F(m,3) variants, ``tiles`` the Winograd tiles one grid
+# step should hold (the executor turns it into ``rows``, whole tile
+# rows per step, for the spec's tile-row width).  Candidate 0 under
+# m=2 is the kernel's shipped default geometry; the smaller tile counts
+# keep the F(4,3) domain (36 positions vs 16) inside the VMEM budget on
+# big-channel specs, with the channel tiles still 128-lane aligned.
+# ``tm`` stays at the kernel's strided-store limit (128 lanes).
 _WINO_TILES = (
     (128, 128, 128),
     (256, 128, 128),
-    (128, 256, 128),
     (128, 128, 256),
     (64, 128, 128),
     (32, 128, 128),
-    (64, 256, 128),
 )
+
+
+def _wino_rows(th: int, tw: int, tiles: int) -> int:
+    """Tile rows per step nearest ``tiles`` tiles of ``tw`` columns.
+    Below an image's ``th`` tile rows they are spread evenly over the
+    bands (so the last band is as full as the rest: 28 tile rows at 8
+    per step run as 4 bands of 7); from ``th`` up a step takes whole
+    images (``winograd_pallas.geometry``)."""
+    rows = max(1, tiles // tw)
+    if rows >= th:
+        return rows
+    bands = -(-th // rows)
+    return -(-th // bands)
 
 
 class WinogradPallasExecutor(Executor):
     """Tiled Pallas Winograd F(m,3): the whole Winograd domain —
-    B^T d B transform, per-position channel GEMMs, fp32 accumulator,
-    A^T m A inverse, bias/ReLU/residual epilogue — lives in VMEM inside
-    one kernel (kernels/winograd_pallas.py), where the pure-jnp
-    ``winograd`` executor round-trips every domain tensor through HBM.
+    tile gather, B^T d B transform, per-position channel GEMMs, fp32
+    accumulator, A^T m A inverse, bias/ReLU/residual epilogue — lives
+    in VMEM inside one kernel (kernels/winograd_pallas.py), where the
+    pure-jnp ``winograd`` executor round-trips every domain tensor
+    through HBM.
 
     Tuning space: ``m`` (the F(m,3) variant — F(2x2,3x3) with 16 tile
     positions and 2.25x multiply savings, or F(4x4,3x3) with 36
-    positions and 4x savings at looser numerics), ``tt`` (tiles per
-    block), ``tm``/``tc`` (output/input channel tiles).  The variant is
-    a *config dim*, so ``tune="full"`` arbitrates F(2,3) vs F(4,3) per
-    spec and the winner persists like any other launch config.
+    positions and 4x savings at looser numerics), ``rows`` (tile rows
+    per grid step), ``tm``/``tc`` (output/input channel tiles).  The
+    variant is a *config dim*, so ``tune="full"`` arbitrates F(2,3) vs
+    F(4,3) per spec and the winner persists like any other launch
+    config.
     """
     name = "winograd_pallas"
     fuses_epilogue = True
     takes_interpret = True
-    tunable = ("m", "tt", "tm", "tc")
+    tunable = ("m", "rows", "tm", "tc")
 
     def fusions(self, spec):
         # the residual add folds into the in-kernel epilogue (the
@@ -997,44 +1013,69 @@ class WinogradPallasExecutor(Executor):
                            "budget for this spec")
         return True, "3x3 stride-1: tiled Pallas Winograd"
 
-    def _tile_counts(self, spec, fm):
-        n, oh, ow, m = spec.out_shape
-        return n * (-(-oh // fm)) * (-(-ow // fm)), m, spec.filter_shape[2]
+    def _geometry(self, spec, fm, rows):
+        """The kernel's ``geometry`` of ``spec`` under F(fm,3), ``rows``."""
+        from repro.kernels.winograd_pallas import geometry
+        n, h, w, _ = spec.in_shape
+        return geometry(n, h, w, spec.padding, fm, rows,
+                        jnp.dtype(spec.dtype).itemsize)
+
+    def _step_rows(self, spec, fm, tiles):
+        """``rows`` for about ``tiles`` tiles a step, as the kernel runs
+        it (images per step times tile rows of each)."""
+        from repro.kernels.winograd_pallas import tile_grid
+        _, h, w, _ = spec.in_shape
+        th, twp = tile_grid(h, w, spec.padding, fm,
+                            jnp.dtype(spec.dtype).itemsize)
+        _, _, nb, rows, *_ = self._geometry(spec, fm,
+                                            _wino_rows(th, twp, tiles))
+        return nb * rows
 
     def configs(self, spec):
+        _, _, _, m = spec.out_shape
+        c = spec.filter_shape[2]
         cands = ()
         for fm in (2, 4):
-            p, m, c = self._tile_counts(spec, fm)
             cands += _dedup_configs(
-                ({"m": fm, "tt": min(tt, p), "tm": min(tm, m),
-                  "tc": min(tc, c)} for tt, tm, tc in _WINO_TILES),
-                {"tt": p, "tm": m, "tc": c})
+                ({"m": fm, "rows": self._step_rows(spec, fm, tiles),
+                  "tm": min(tm, m), "tc": min(tc, c)}
+                 for tiles, tm, tc in _WINO_TILES),
+                {"tm": m, "tc": c})
         return cands
 
     def _config_supports(self, spec, config):
+        from repro.kernels._compat import STRIDED_LANES
         fm = config.get("m", 2)
         if fm not in (2, 4):
             return False, (f"F(m,3) variant must be m=2 or m=4; "
                            f"got m={fm}")
+        tm = min(config.get("tm", 128), spec.out_shape[3])
+        if tm > STRIDED_LANES:
+            return False, (f"the NHWC output store is a strided store, "
+                           f"which Mosaic takes on at most "
+                           f"{STRIDED_LANES} lanes; got tm={tm}")
         return True, "config geometry ok"
 
     def vmem_bytes(self, spec, config=None):
         from repro.kernels.winograd_pallas import vmem_bytes
         cfg = LaunchConfig.of(config)
         return vmem_bytes(spec.in_shape, spec.filter_shape,
-                          m=cfg.get("m", 2), tt=cfg.get("tt", 128),
+                          m=cfg.get("m", 2), rows=cfg.get("rows", 4),
                           tm=cfg.get("tm", 128), tc=cfg.get("tc", 128),
                           itemsize=jnp.dtype(spec.dtype).itemsize,
                           bias=spec.has_bias,
-                          addend=spec.fused_add != "none")
+                          addend=spec.fused_add != "none",
+                          padding=spec.padding)
 
     def config_cost(self, spec, config):
         fm = config.get("m", 2)
-        p, m, c = self._tile_counts(spec, fm)
-        tt = min(config.get("tt", 128), p)
+        n, _, _, m = spec.out_shape
+        c = spec.filter_shape[2]
+        _, _, nb, _, bands, *_ = self._geometry(spec, fm,
+                                                config.get("rows", 4))
         tm = min(config.get("tm", 128), m)
         tc = min(config.get("tc", 128), c)
-        steps = (-(-p // tt)) * (-(-m // tm)) * (-(-c // tc))
+        steps = n // nb * bands * (-(-m // tm)) * (-(-c // tc))
         # (m+2)^2 per-position GEMMs per step: F(4,3) quarters the tile
         # count but grows the position count 16 -> 36, netting ~0.56x —
         # the model prefers it wherever it stays VMEM-feasible
@@ -1046,16 +1087,16 @@ class WinogradPallasExecutor(Executor):
         return super().flop_cost(spec) / 2.25
 
     def extra_hbm_bytes(self, spec):
-        n, oh, ow, m = spec.out_shape
-        c = spec.filter_shape[2]
+        n, h, w, c = spec.in_shape
+        m = spec.filter_shape[3]
+        ph, pw = spec.padding
         itemsize = jnp.dtype(spec.dtype).itemsize
-        p = n * ((oh + 1) // 2) * ((ow + 1) // 2)
-        # gathered input-tile tensor + output-tile tensor (written, then
-        # re-read by the scatter) at the spec dtype; the transformed
-        # filters (f32) are small and reused — the Winograd-domain
-        # tensors themselves never leave VMEM (the point of the kernel)
-        return (2.0 * p * 16 * c * itemsize + 2.0 * 16 * c * m * 4
-                + 2.0 * p * 4 * m * itemsize)
+        # the padded, phase-split input (written, then read by the
+        # kernel) at the spec dtype and the transformed filters (f32,
+        # small and reused); the tiles, the Winograd-domain tensors and
+        # the output tiles never leave VMEM (the point of the kernel)
+        return (2.0 * n * (h + 2 * ph) * (w + 2 * pw) * c * itemsize
+                + 2.0 * 16 * c * m * 4)
 
     def heuristic_claim(self, spec, backend):
         if backend != "tpu" or spec.has_fusion:
@@ -1076,7 +1117,7 @@ class WinogradPallasExecutor(Executor):
             x, w, spec.padding,
             bias=bias if spec.has_bias else None,
             activation="relu" if relu else None,
-            addend=addend, m=cfg.get("m", 2), tt=cfg.get("tt", 128),
+            addend=addend, m=cfg.get("m", 2), rows=cfg.get("rows", 4),
             tm=cfg.get("tm", 128), tc=cfg.get("tc", 128),
             interpret=interpret)
 

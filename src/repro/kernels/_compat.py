@@ -7,6 +7,8 @@ the kernel package.  `tiled_bytes` is the one home of the TPU's VMEM
 tile padding, which every kernel's `vmem_bytes` model prices blocks at,
 and `phase_split` the one home of the stride-phase input layout that
 lets kernels read strided windows with unit-stride loads.
+``STRIDED_LANES`` is the one home of the lane limit of Mosaic's strided
+loads and stores.
 """
 from typing import Sequence, Tuple
 
@@ -14,6 +16,11 @@ import jax.numpy as jnp
 from jax.experimental.pallas import tpu as _pltpu
 
 CompilerParams = _pltpu.CompilerParams
+
+#: widest lane extent of a block Mosaic's strided loads and stores take
+#: (32-bit data only): the fused pool's scratch reads in cuconv_fused,
+#: the NHWC output interleave in winograd_pallas
+STRIDED_LANES = 128
 
 
 def clamp_tiles(dims: Sequence[int], tiles: Sequence[int]

@@ -69,9 +69,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import _compat
 
-#: widest lane extent Mosaic's strided loads accept (the pool epilogue)
-STRIDED_LOAD_LANES = 128
-
 
 def _make_kernel(kw: int, ow: int, sh: int, sw: int, rows: int, taps: int,
                  activation, has_bias: bool, has_add: bool, pool=None):

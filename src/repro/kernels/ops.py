@@ -98,18 +98,19 @@ def cuconv_fused(x, w, padding=(0, 0), stride=1, bias=None, activation=None,
 
 
 def winograd_fused(x, w, padding=(1, 1), bias=None, activation=None,
-                   addend=None, m=2, tt=128, tm=128, tc=128,
+                   addend=None, m=2, rows=4, tm=128, tc=128,
                    interpret=None):
     """Tiled Pallas Winograd F(m,3) conv (3x3, stride 1) with fused
     bias/activation/residual epilogue.
 
-    Policy-free executor: the F(m,3) variant ``m`` and the ``tt/tm/tc``
-    tiles are the winograd_pallas launch config (core.convspec.plan
+    Policy-free executor: the F(m,3) variant ``m``, the tile rows per
+    step ``rows`` and the ``tm/tc`` channel tiles are the
+    winograd_pallas launch config (core.convspec.plan
     owns which specs take this path; see kernels/winograd_pallas.py).
     """
     return _wg.winograd_fused(x, w, tuple(padding), bias=bias,
                               activation=activation, addend=addend,
-                              m=m, tt=tt, tm=tm, tc=tc,
+                              m=m, rows=rows, tm=tm, tc=tc,
                               interpret=_auto_interpret(interpret))
 
 

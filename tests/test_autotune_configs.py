@@ -103,9 +103,9 @@ def test_candidate_zero_is_the_historical_geometry():
     one = ex.get("conv1x1_pallas").configs(T3_A)[0]
     assert one.as_dict() == {"tp": 49, "tm": 128, "tc": 512}
     # winograd_pallas candidate 0 is the F(2,3) variant at the default
-    # tiles (tt clamped to the spec's tile count: 1 * ceil(7/2)^2 = 16)
+    # tiles (rows clamped to the spec's tile rows: ceil(7/2) = 4)
     wg = ex.get("winograd_pallas").configs(T4_A)[0]
-    assert wg.as_dict() == {"m": 2, "tt": 16, "tm": 128, "tc": 128}
+    assert wg.as_dict() == {"m": 2, "rows": 4, "tm": 128, "tc": 128}
     # direct candidate 0: default (tm, tc) clamped to (M, C)
     dc = ex.get("direct").configs(T4_A)[0]
     assert dc.as_dict() == {"tm": 128, "tc": 192}
@@ -212,13 +212,17 @@ def test_forced_infeasible_config_raises_for_new_executors():
     # F(m,3) variant is a config dim but only m in {2, 4} exists
     with pytest.raises(ValueError) as e:
         cs.plan(T4_A, force="winograd_pallas",
-                config={"m": 3, "tt": 16, "tm": 128, "tc": 128})
+                config={"m": 3, "rows": 4, "tm": 128, "tc": 128})
     msg = str(e.value)
     assert "winograd_pallas" in msg and "m=3" in msg and T4_A.key() in msg
     # oversized tiles blow the (unclamped) VMEM model and are refused
     with pytest.raises(ValueError, match="VMEM"):
         cs.plan(T4_B, force="winograd_pallas",
-                config={"m": 4, "tt": 512, "tm": 512, "tc": 512})
+                config={"m": 4, "rows": 4, "tm": 128, "tc": 1024})
+    # the NHWC output is a strided store: at most 128 output lanes
+    with pytest.raises(ValueError, match="tm=256"):
+        cs.plan(T4_B, force="winograd_pallas",
+                config={"m": 2, "rows": 4, "tm": 256, "tc": 128})
     with pytest.raises(ValueError) as e:
         cs.plan(T4_B, force="direct", config={"tm": 512, "tc": 512})
     msg = str(e.value)
@@ -254,8 +258,8 @@ def test_stale_persisted_config_is_reresolved_not_served():
 
 
 @pytest.mark.parametrize("name,spec,stale", [
-    ("winograd_pallas", T4_A, {"m": 3, "tt": 16, "tm": 128, "tc": 128}),
-    ("winograd_pallas", T4_B, {"m": 4, "tt": 512, "tm": 512, "tc": 512}),
+    ("winograd_pallas", T4_A, {"m": 3, "rows": 4, "tm": 128, "tc": 128}),
+    ("winograd_pallas", T4_B, {"m": 4, "rows": 4, "tm": 128, "tc": 1024}),
     ("direct", T4_B, {"tm": 512, "tc": 512}),
 ])
 def test_stale_persisted_config_self_heals_for_new_executors(name, spec,
@@ -268,6 +272,20 @@ def test_stale_persisted_config_self_heals_for_new_executors(name, spec,
     assert p.algorithm == name
     assert p.config_source == "default"
     ok, why = ex.get(name).config_supports(spec, p.config)
+    assert ok, why
+
+
+def test_persisted_winograd_tt_config_is_reresolved():
+    """A winograd_pallas config persisted before ``rows`` replaced
+    ``tt`` (tiles per block) names a dim the executor no longer tunes:
+    it is dropped at resolve time, not served and not raised on."""
+    stale = {"m": 2, "tt": 128, "tm": 128, "tc": 128}
+    autotune.record_best(T4_B, "cpu", "winograd_pallas", config=stale)
+    p = cs.plan(T4_B, backend="cpu", force="winograd_pallas")
+    assert p.algorithm == "winograd_pallas"
+    assert p.config_source == "default"
+    assert "tt" not in p.config and "rows" in p.config
+    ok, why = ex.get("winograd_pallas").config_supports(T4_B, p.config)
     assert ok, why
 
 

@@ -154,3 +154,53 @@ def test_cuconv_fused_epilogue(rng, KH, KW, stride):
         dimension_numbers=("NHWC", "HWIO", "NHWC")) + b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# winograd_fused: the benchmark's Winograd shapes (ResNet-50 stages 1-4,
+# SqueezeNet 1.0 fires) at batch 1-2, both F(m,3) variants, padding 0
+# and 1, ``rows`` that leave a ragged last band (th % rows != 0) or
+# take whole images per step (rows >= th), and both epilogues: bias +
+# ReLU, and bias + residual addend + ReLU.
+# Channel tiles below C and M run the multi-step contraction and the
+# kept transform across output-channel tiles.
+@pytest.mark.parametrize("N,H,W,C,M,pad,m,rows,tc,addend", [
+    (1, 56, 56, 64, 64, 1, 2, 7, 64, False),
+    (1, 56, 56, 64, 64, 1, 4, 4, 64, True),       # th 14: bands 4,4,4,2
+    (1, 28, 28, 128, 128, 1, 2, 5, 128, True),    # th 14: ragged
+    (2, 28, 28, 128, 128, 0, 4, 3, 128, False),   # th 7: ragged
+    (1, 14, 14, 256, 256, 1, 2, 4, 128, False),   # th 7: ragged
+    (1, 14, 14, 256, 256, 1, 4, 4, 128, True),
+    (2, 7, 7, 512, 512, 1, 2, 3, 256, False),     # th 4: ragged
+    (1, 7, 7, 512, 512, 0, 4, 1, 128, True),
+    (4, 7, 7, 512, 512, 1, 2, 8, 128, True),      # 2 images a step
+    (1, 54, 54, 16, 64, 1, 2, 4, 16, False),      # th 27: ragged
+    (1, 54, 54, 16, 64, 0, 4, 5, 16, True),       # th 13: ragged
+    (3, 27, 27, 32, 128, 1, 2, 28, 32, True),     # 2 images: 3 % 2, so 1
+    (1, 27, 27, 32, 128, 1, 4, 2, 32, False),     # th 7: ragged
+    (2, 13, 13, 64, 256, 1, 2, 14, 64, False),    # 2 images a step
+    (1, 13, 13, 64, 256, 0, 4, 2, 64, True),      # th 3: ragged
+])
+def test_winograd_fused_matches_lax(rng, N, H, W, C, M, pad, m, rows, tc,
+                                    addend):
+    x = _rand(rng, (N, H, W, C), jnp.float32)
+    w = _rand(rng, (3, 3, C, M), jnp.float32) / np.sqrt(9 * C)
+    b = _rand(rng, (M,), jnp.float32)
+    want = jax.lax.conv_general_dilated(
+        x, w, (1, 1), ((pad, pad), (pad, pad)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST) + b
+    ad = None
+    if addend:
+        ad = _rand(rng, want.shape, jnp.float32)
+        want = want + ad
+    want = jnp.maximum(want, 0.0)
+    got = ops.winograd_fused(x, w, (pad, pad), bias=b, activation="relu",
+                             addend=ad, m=m, rows=rows, tm=128, tc=tc,
+                             interpret=True)
+    assert got.shape == want.shape
+    # F(4,3)'s inverse transform (coefficients up to 8) amplifies
+    # rounding an order more than F(2,3)'s
+    tol = 1e-4 if m == 2 else 1e-3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)

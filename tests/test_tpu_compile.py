@@ -10,6 +10,7 @@ compiled program.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -77,22 +78,54 @@ def test_cuconv_fused_residual_add_compiles(one_chip):
         f, one_chip, (xs, F32), (ws, F32), ((128,), F32), (xs, F32))
 
 
+def _first_feasible(spec, m):
+    wino = ex.get("winograd_pallas")
+    return next(c for c in wino.configs(spec)
+                if c["m"] == m and wino.config_supports(spec, c)[0])
+
+
+def _winograd_compiled(sharding, xs, ws, cfg):
+    def f(x, w, b):
+        return ops.winograd_fused(x, w, (1, 1), bias=b, activation="relu",
+                                  m=cfg["m"], rows=cfg["rows"],
+                                  tm=cfg["tm"], tc=cfg["tc"],
+                                  interpret=False)
+    return _compiled_text(f, sharding, (xs, F32), (ws, F32),
+                          ((ws[3],), F32))
+
+
 @pytest.mark.parametrize("m", [2, 4])
 def test_winograd_pallas_compiles(one_chip, m):
     """The first F(m,3) tile candidate the executor's VMEM model admits
     (F(4,3)'s 36-position domain does not fit at 128-wide tiles)."""
     xs, ws = (8, 56, 56, 256), (3, 3, 256, 256)
     spec = cs.ConvSpec(xs, ws, padding=(1, 1), epilogue="bias_relu")
-    wino = ex.get("winograd_pallas")
-    cfg = next(c for c in wino.configs(spec)
-               if c["m"] == m and wino.config_supports(spec, c)[0])
+    txt = _winograd_compiled(one_chip, xs, ws, _first_feasible(spec, m))
+    assert "tpu_custom_call" in txt
 
-    def f(x, w, b):
-        return ops.winograd_fused(x, w, (1, 1), bias=b, activation="relu",
-                                  m=m, tt=cfg["tt"], tm=cfg["tm"],
-                                  tc=cfg["tc"], interpret=False)
-    assert "tpu_custom_call" in _compiled_text(
-        f, one_chip, (xs, F32), (ws, F32), ((256,), F32))
+
+_GATHER_OPS = re.compile(r"\b(gather|dynamic-slice)\(")
+
+
+@pytest.mark.parametrize("label,xs,m_out", [
+    ("resnet50-s1b1c2", (32, 56, 56, 64), 64),
+    ("squeezenet1_0-fire2e3", (32, 54, 54, 16), 64),
+])
+def test_winograd_pallas_gathers_tiles_in_kernel(one_chip, label, xs,
+                                                  m_out):
+    """The benchmark's widest Winograd shapes, at the executor's first
+    feasible config: the tiles are formed in VMEM from the phase-split
+    input, so outside the kernel the compiled program holds no gather
+    and no dynamic-slice (the XLA tile gather this kernel replaced)."""
+    ws = (3, 3, xs[3], m_out)
+    spec = cs.ConvSpec(xs, ws, padding=(1, 1), epilogue="bias_relu")
+    txt = _winograd_compiled(one_chip, xs, ws, _first_feasible(spec, 2))
+    assert "tpu_custom_call" in txt
+    outside = [line for line in txt.splitlines()
+               if "tpu_custom_call" not in line]
+    found = [line.strip()[:160] for line in outside
+             if _GATHER_OPS.search(line)]
+    assert not found, f"{label}: gather outside the kernel: {found}"
 
 
 @pytest.mark.parametrize("stride", [(1, 1), (2, 2)])
