@@ -5,4 +5,4 @@ from repro.core.executors import (  # noqa: F401
     ALGORITHMS, Executor, register, unregister)
 from repro.core.graph import (  # noqa: F401
     AddOp, ConcatOp, ConvGraph, ConvOp, DenseOp, GapOp, Graph,
-    GraphBuilder, GraphPlan, PoolOp, PrecisionPolicy, plan_graph)
+    GraphBuilder, GraphPlan, NormOp, PoolOp, PrecisionPolicy, plan_graph)

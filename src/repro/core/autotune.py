@@ -456,7 +456,7 @@ def tune_spec(spec: ConvSpec, *, tune: str = "algo",
             f"measured tuning must run on the target backend: asked for "
             f"{backend!r} but this process runs {jax.default_backend()!r}")
     x, w, b, _ = _fused_operands(spec)
-    act = "relu" if spec.wants_relu else None
+    act = spec.activation
     kwargs = dict(stride=spec.stride, padding=spec.padding, repeats=repeats,
                   bias=b, activation=act, groups=spec.groups, spec=spec)
     if tune == "algo" or algorithm is None:

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 # the single home of the padding type alias (cuconv re-exports it)
 Pad = Union[int, Tuple[int, int], str]
 
-EPILOGUES = ("none", "bias", "relu", "bias_relu")
+EPILOGUES = ("none", "bias", "relu", "bias_relu", "gelu", "bias_gelu")
 
 # canonical short spellings for ConvSpec.dtype / PrecisionPolicy inputs
 _DTYPE_ALIASES = {"fp32": "float32", "f32": "float32",
@@ -121,7 +121,7 @@ class ConvSpec:
     stride: Tuple[int, int] = (1, 1)          # (sh, sw)
     padding: Tuple[int, int] = (0, 0)         # (ph, pw), pre-normalized
     dtype: str = "float32"
-    epilogue: str = "none"                    # none | bias | relu | bias_relu
+    epilogue: str = "none"                    # one of EPILOGUES
     groups: int = 1                           # feature groups (depthwise: C)
     #: residual-add fusion: a second operand (shape == out_shape) added
     #: after the bias, with 'add_relu' applying ReLU after the sum
@@ -137,7 +137,7 @@ class ConvSpec:
             raise ValueError(f"fused_add {self.fused_add!r} not in "
                              f"{FUSED_ADDS}")
         if self.fused_add != "none":
-            if self.wants_relu:
+            if self.activation is not None:
                 raise ValueError(
                     f"fused_add {self.fused_add!r} needs epilogue 'none' or "
                     f"'bias' (the activation moves AFTER the add); got "
@@ -194,15 +194,14 @@ class ConvSpec:
         Unknown activations are an error, not a silent epilogue "none":
         the planner only knows how to fuse what EPILOGUES names.
         """
-        if activation not in (None, "none", "relu"):
+        if activation not in (None, "none", "relu", "gelu"):
             raise ValueError(
                 f"activation {activation!r} not supported; the planner "
-                f"fuses None or 'relu' (epilogues: {EPILOGUES})")
-        relu = activation == "relu"
+                f"fuses None, 'relu' or 'gelu' (epilogues: {EPILOGUES})")
+        act = None if activation in (None, "none") else activation
         kh, kw = int(w.shape[0]), int(w.shape[1])
-        epi = ("bias_relu" if bias is not None and relu
-               else "bias" if bias is not None
-               else "relu" if relu else "none")
+        epi = "_".join(p for p in ("bias" if bias is not None else None,
+                                   act) if p) or "none"
         return cls(tuple(map(int, x.shape)), tuple(map(int, w.shape)),
                    normalize_stride(stride), normalize_pad(padding, kh, kw),
                    str(x.dtype), epi, int(groups))
@@ -225,11 +224,18 @@ class ConvSpec:
 
     @property
     def has_bias(self) -> bool:
-        return self.epilogue in ("bias", "bias_relu")
+        return self.epilogue.startswith("bias")
+
+    @property
+    def activation(self) -> Optional[str]:
+        """The epilogue's activation: ``"relu"``, ``"gelu"`` (the exact
+        erf form) or None."""
+        act = self.epilogue.rpartition("_")[2]
+        return act if act in ("relu", "gelu") else None
 
     @property
     def wants_relu(self) -> bool:
-        return self.epilogue in ("relu", "bias_relu")
+        return self.activation == "relu"
 
     @property
     def has_fusion(self) -> bool:

@@ -249,9 +249,11 @@ def conv2d(x, w, stride=1, padding: Pad = "same", algorithm="auto",
     """Public conv entry point: a thin wrapper over the ConvSpec planner.
 
     x: (N,H,W,C) NHWC; w: (KH,KW,C/groups,M) HWIO; bias: optional (M,);
-    activation: None | 'relu' (anything else raises — no silent epilogue
-    drop).  groups > 1 requests a grouped/depthwise conv, executed via
-    the library's feature_group_count (plan() routes it there).
+    activation: None | 'relu' | 'gelu' (the exact erf form; anything
+    else raises — no silent epilogue drop).  groups > 1 requests a
+    grouped/depthwise conv, executed via the library's
+    feature_group_count or, for depthwise specs on a TPU, the
+    depthwise_tap kernel (plan() routes it).
     algorithm="auto" lets plan() negotiate over the executor registry
     (measured cache > region claims > cheapest supported); naming a
     registered executor forces it, still subject to its declared

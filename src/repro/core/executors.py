@@ -28,6 +28,9 @@ registered ``Executor`` object declaring:
                    historical hard-coded geometry; ``config_supports``
                    prunes, ``default_config`` model-picks absent
                    measurement, ``autotune.measure_config`` sweeps
+  epilogues        the ``ConvSpec.epilogue`` values it runs (every one
+                   for executors that apply the epilogue as XLA ops;
+                   in-kernel epilogues declare their own)
   execute(...)     run the spec under a launch config, epilogue
                    included (in-kernel when ``fuses_epilogue``, XLA
                    ops otherwise)
@@ -46,7 +49,10 @@ import inspect
 from collections.abc import Mapping as _MappingABC
 from typing import Callable, Dict, Iterable, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+
+from repro.core.convspec import EPILOGUES
 
 # VMEM working-set budget for the fused Pallas kernel (per-core VMEM is
 # ~16 MB; leave headroom for Mosaic's own buffers).  Read at supports()
@@ -196,8 +202,11 @@ class Executor:
     accum: str = "float32"
     #: can execute groups > 1 specs exactly
     supports_groups: bool = False
-    #: the bias/ReLU epilogue runs inside the kernel (no extra HBM trip)
+    #: the bias/activation epilogue runs inside the kernel (no extra
+    #: HBM trip)
     fuses_epilogue: bool = False
+    #: ConvSpec.epilogue values this executor runs
+    epilogues: Tuple[str, ...] = EPILOGUES
     #: forward the planner's interpret flag (Pallas executors)
     takes_interpret: bool = False
     #: names of the launch-config dims this executor can tune; () means
@@ -218,6 +227,9 @@ class Executor:
         if spec.groups != 1 and not self.supports_groups:
             return False, (f"no grouped-conv support (groups={spec.groups}); "
                            f"lax feature_group_count is the executor")
+        if spec.epilogue not in self.epilogues:
+            return False, (f"epilogue {spec.epilogue!r} not in {self.name}'s "
+                           f"declared epilogues {self.epilogues}")
         fusable = self.fusions(spec)
         if spec.fused_add != "none" and "add" not in fusable:
             return False, (f"{self.name} does not fuse a residual add "
@@ -389,19 +401,7 @@ class Executor:
             kwargs["addend"] = addend
         y = self._execute(spec, x, w, bias, interpret, **kwargs)
         if not self.fuses_epilogue:
-            if spec.has_bias:
-                y = y + bias
-            if spec.fused_add != "none":
-                y = y + addend
-                if spec.fused_add == "add_relu":
-                    y = jnp.maximum(y, 0)
-            elif spec.wants_relu:
-                y = jnp.maximum(y, 0)
-            if spec.fused_pool:
-                from repro.kernels import ops
-                kind, pkh, pkw, psh, psw, pph, ppw = spec.fused_pool
-                y = ops.pool2d(y, kind=kind, window=(pkh, pkw),
-                               stride=(psh, psw), padding=(pph, ppw))
+            y = _xla_epilogue(spec, y, bias, addend)
         return y
 
     def _execute(self, spec, x, w, bias, interpret):
@@ -417,6 +417,27 @@ class Executor:
         return (f"<Executor {self.name} dtypes={self.dtypes} "
                 f"accum={self.accum} groups={self.supports_groups} "
                 f"fused_epilogue={self.fuses_epilogue}>")
+
+
+def _xla_epilogue(spec, y, bias, addend):
+    """``spec``'s epilogue as XLA ops after a bare conv: bias, residual
+    addend, activation (after the add under ``add_relu``), pool."""
+    if spec.has_bias:
+        y = y + bias
+    if spec.fused_add != "none":
+        y = y + addend
+        if spec.fused_add == "add_relu":
+            y = jnp.maximum(y, 0)
+    elif spec.activation == "relu":
+        y = jnp.maximum(y, 0)
+    elif spec.activation == "gelu":
+        y = jax.nn.gelu(y, approximate=False)
+    if spec.fused_pool:
+        from repro.kernels import ops
+        kind, pkh, pkw, psh, psw, pph, ppw = spec.fused_pool
+        y = ops.pool2d(y, kind=kind, window=(pkh, pkw),
+                       stride=(psh, psw), padding=(pph, ppw))
+    return y
 
 
 # ---------------------------------------------------------------------------
@@ -566,7 +587,8 @@ def supporting(spec) -> Tuple[str, ...]:
 
 class LaxExecutor(Executor):
     """XLA's native convolution — the cuDNN stand-in of the paper's
-    comparison, and the only executor for grouped/depthwise specs."""
+    comparison, and the executor for grouped specs (depthwise ones may
+    go to ``depthwise_tap``)."""
     name = "lax"
     supports_groups = True
 
@@ -802,8 +824,8 @@ class TwoStagePallasExecutor(Executor):
 
 class FusedPallasExecutor(Executor):
     """The fused Pallas TPU kernel: any stride >= 1, per-tap partials
-    accumulated in VMEM, bias+ReLU epilogue fused before the single HBM
-    write.
+    accumulated in VMEM, bias + ReLU/GELU epilogue fused before the
+    single HBM write.
 
     Tuning space: ``tm`` (output-channel tile) x ``rows`` (output rows
     per grid step — the multi-row blocking that lets short-``OW`` paper
@@ -912,6 +934,11 @@ class FusedPallasExecutor(Executor):
             # resident in VMEM instead of round-tripping HBM
             return 85, "cross-layer fusion resident in VMEM"
         if not spec.unit_stride:
+            if spec.in_shape[3] < 8:
+                # an RGB stem: each tap's GEMM contracts over C rows and
+                # its phase-split input pads C to 128 lanes (ConvNeXt-T's
+                # 4x4/4 stem took 3.2 ms a 32-image batch; PERF.md)
+                return None
             return 80, "strided conv: fused kernel on TPU"
         if spec.is_1x1:
             return 80, "1x1: fused GEMM + epilogue in VMEM"
@@ -935,13 +962,14 @@ class FusedPallasExecutor(Executor):
         from repro.kernels import ops
         cfg = LaunchConfig.of(config)
         if spec.fused_add != "none":
-            relu = spec.fused_add == "add_relu"    # post-add activation
+            # post-add activation
+            act = "relu" if spec.fused_add == "add_relu" else None
         else:
-            relu = spec.wants_relu
+            act = spec.activation
         return ops.cuconv_fused(
             x, w, spec.padding, stride=spec.stride,
             bias=bias if spec.has_bias else None,
-            activation="relu" if relu else None,
+            activation=act,
             addend=addend,
             pool=self._pool3(spec) if spec.fused_pool else None,
             tm=cfg.get("tm", 128), rows=cfg.get("rows", 1),
@@ -998,6 +1026,7 @@ class WinogradPallasExecutor(Executor):
     fuses_epilogue = True
     takes_interpret = True
     tunable = ("m", "rows", "tm", "tc")
+    epilogues = ("none", "bias", "relu", "bias_relu")
 
     def fusions(self, spec):
         # the residual add folds into the in-kernel epilogue (the
@@ -1290,21 +1319,10 @@ class Int8PallasExecutor(Executor):
         # fp32 requantization epilogue: dequantize the int32 accumulator
         # through the outer product of scales, THEN bias/residual/
         # activation/pool at fp32 (base executors' epilogue order)
-        y = acc.astype(f32) * (x_scale * w_scales)
-        if spec.has_bias:
-            y = y + bias.astype(f32)
-        if spec.fused_add != "none":
-            y = y + addend.astype(f32)
-            if spec.fused_add == "add_relu":
-                y = jnp.maximum(y, 0)
-        elif spec.wants_relu:
-            y = jnp.maximum(y, 0)
-        if spec.fused_pool:
-            from repro.kernels import ops
-            kind, pkh, pkw, psh, psw, pph, ppw = spec.fused_pool
-            y = ops.pool2d(y, kind=kind, window=(pkh, pkw),
-                           stride=(psh, psw), padding=(pph, ppw))
-        return y
+        return _xla_epilogue(
+            spec, acc.astype(f32) * (x_scale * w_scales),
+            None if bias is None else bias.astype(f32),
+            None if addend is None else addend.astype(f32))
 
     def _execute(self, spec, x, w, bias, interpret, config=None):
         # bare int8 conv: int8 patch matrix (zero padding is exact under
@@ -1322,6 +1340,96 @@ class Int8PallasExecutor(Executor):
                             interpret=interpret, tp=cfg.get("tp", 256),
                             tm=cfg.get("tm", 128), tc=cfg.get("tc", 512))
         return acc.reshape(n, oh, ow, m)
+
+
+class DepthwisePallasExecutor(Executor):
+    """Depthwise conv one filter tap at a time in VMEM
+    (kernels/depthwise_tap.py): channels on lanes, each tap one
+    broadcast multiply-add of the shifted window into a float32
+    accumulator, the zero padding done in VMEM, bias and ReLU fused.
+    Stride 1, odd K, ``groups == C_in == C_out``.
+
+    Tuning space: ``nb`` (whole images per grid step), ``tc`` (channel
+    tile: C, or a multiple of 128 dividing it) and ``rows`` (output rows
+    one accumulator holds).  The candidates fix ``tc`` and ``rows`` as
+    v5e timings of ConvNeXt-T's depthwise shapes found best (PERF.md
+    §6): 128-lane channel tiles where C is a multiple of 128, and as
+    many output rows as keep the float32 accumulator within 16 vector
+    registers; ``nb`` is left to the cost model.
+    """
+    name = "depthwise_tap"
+    supports_groups = True
+    fuses_epilogue = True
+    takes_interpret = True
+    tunable = ("nb", "tc", "rows")
+    epilogues = ("none", "bias", "relu", "bias_relu")
+
+    def _supports(self, spec):
+        kh, kw, cpg, m = spec.filter_shape
+        if cpg != 1 or spec.groups != m:
+            return False, ("depthwise_tap needs groups == C_in == C_out "
+                           "(one filter per channel)")
+        if not spec.unit_stride or kh % 2 == 0 or kw % 2 == 0:
+            return False, "depthwise_tap needs stride 1 and an odd K"
+        if not any(self.config_supports(spec, c)[0]
+                   for c in self.configs(spec)):
+            return False, "no depthwise block fits the VMEM budget"
+        return True, "depthwise: taps accumulated in VMEM"
+
+    def configs(self, spec):
+        n, _, _, c = spec.in_shape
+        _, oh, ow, _ = spec.out_shape
+        tc = 128 if c % 128 == 0 else c
+        row_vregs = -(-ow // 8) * -(-tc // 128)
+        rows = max((r for r in range(1, oh + 1)
+                    if oh % r == 0 and r * row_vregs <= 16), default=1)
+        return _dedup_configs(
+            ({"nb": nb, "tc": tc, "rows": rows}
+             for nb in (1, 2, 4, 8, 16, 32) if n % nb == 0), {"tc": c})
+
+    def _config_supports(self, spec, config):
+        n, _, _, c = spec.in_shape
+        nb, tc = config.get("nb", 1), min(config.get("tc", 128), c)
+        rows = config.get("rows", 1)
+        if n % nb or c % tc or spec.out_shape[1] % rows:
+            return False, (f"nb={nb} must divide N={n}, tc={tc} C={c} and "
+                           f"rows={rows} OH={spec.out_shape[1]}")
+        return True, "config geometry ok"
+
+    def vmem_bytes(self, spec, config=None):
+        from repro.kernels.depthwise_tap import vmem_bytes
+        cfg = LaunchConfig.of(config)
+        return vmem_bytes(spec.in_shape, spec.filter_shape, spec.padding,
+                          nb=cfg.get("nb", 1), tc=cfg.get("tc", 128),
+                          itemsize=jnp.dtype(spec.dtype).itemsize,
+                          bias=spec.has_bias)
+
+    def config_cost(self, spec, config):
+        # grid steps (about 0.35 us each) plus the first block's read
+        # and the last block's write, which no other step overlaps
+        n, h, w, c = spec.in_shape
+        nb, tc = config.get("nb", 1), min(config.get("tc", 128), c)
+        _, oh, ow, _ = spec.out_shape
+        block = nb * (h * w + oh * ow) * tc * jnp.dtype(spec.dtype).itemsize
+        return (n // nb) * (c // tc) * 0.35e-6 + block / 819e9
+
+    def heuristic_claim(self, spec, backend):
+        if backend != "tpu":
+            return None                    # interpret mode elsewhere
+        # outranks the library conv's grouped claim: inside a served
+        # ConvNeXt-T program on a v5e, XLA's grouped conv took 17.8 ms
+        # a 32-image batch at 28x28x192 against the kernel's 0.26 ms,
+        # though alone it ran within 4% of it (PERF.md §6)
+        return 96, "depthwise: taps accumulated in VMEM"
+
+    def _execute(self, spec, x, w, bias, interpret, config=None):
+        from repro.kernels import ops
+        cfg = LaunchConfig.of(config)
+        return ops.depthwise_conv(x, w, spec.padding, bias=bias,
+                                  relu=spec.wants_relu,
+                                  nb=cfg.get("nb", 1), tc=cfg.get("tc", 128),
+                                  rows=cfg.get("rows", 1),
+                                  interpret=interpret)
 
 
 def _register_builtins() -> None:
@@ -1344,6 +1452,7 @@ def _register_builtins() -> None:
     # no bare-fn surface: the quantize/dequantize epilogue only makes
     # sense through ConvPlan (the registered-executor path)
     register(Int8PallasExecutor())
+    register(DepthwisePallasExecutor())
 
 
 _register_builtins()

@@ -10,8 +10,9 @@ reason; this module is that seam for the repo (DESIGN.md §6):
   OpSpec      typed IR node, one frozen dataclass per operator:
               ConvOp (a ConvSpec — including grouped/depthwise),
               PoolOp (max/avg), AddOp (residual, optional ReLU),
-              ConcatOp (channel axis), GapOp, DenseOp.  Nodes are
-              *named* and name their input edges explicitly.
+              ConcatOp (channel axis), GapOp, DenseOp, NormOp
+              (LayerNorm over channels).  Nodes are *named* and name
+              their input edges explicitly.
   Graph       a DAG of OpSpec nodes in topological order, shape-checked
               at construction (every edge's producer shape must satisfy
               the consumer).  ``signature()`` is its stable identity —
@@ -350,6 +351,35 @@ class DenseOp(OpSpec):
                 f"{self.features[1]}:bias={int(self.bias)}")
 
 
+@dataclasses.dataclass(frozen=True)
+class NormOp(OpSpec):
+    """LayerNorm over the last (channel) axis with a learned scale and
+    shift (params ``{"g", "b"}``), computed in float32; takes NHWC or
+    ``(N, C)`` inputs (a head's norm follows GAP)."""
+    eps: float = 1e-6
+
+    op = "norm"
+
+    def __post_init__(self):
+        super().__post_init__()
+        if len(self.inputs) != 1:
+            raise ValueError(f"norm node {self.name!r} takes exactly one "
+                             f"input; got {self.inputs}")
+        if not self.eps > 0:
+            raise ValueError(f"norm node {self.name!r} needs eps > 0; "
+                             f"got {self.eps!r}")
+
+    def infer_shape(self, in_shapes):
+        (s,) = in_shapes
+        if len(s) not in (2, 4):
+            raise ValueError(f"norm node {self.name!r} needs an NHWC or "
+                             f"(N, C) input; got shape {tuple(s)}")
+        return tuple(s)
+
+    def descriptor(self):
+        return f"{super().descriptor()}:eps={self.eps!r}"
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class Graph:
     """A DAG of named OpSpec nodes over one graph input.
@@ -486,6 +516,9 @@ class GraphBuilder:
               bias: bool = True) -> str:
         c_in = int(self.shapes[src][-1])
         return self._put(DenseOp(name, (src,), (c_in, c_out), bias))
+
+    def norm(self, name: str, src: str, eps: float = 1e-6) -> str:
+        return self._put(NormOp(name, (src,), float(eps)))
 
     def graph(self, output: Optional[str] = None) -> Graph:
         # a precision override that names no CONV node is a typo (or a
@@ -810,13 +843,13 @@ class GraphPlan:
         return named
 
     def _node_params(self, params: Mapping, node: OpSpec,
-                     wants_bias: bool) -> Mapping:
+                     wants_bias: bool, weight: str = "w") -> Mapping:
         """One node's param dict, with errors that name the node instead
         of a bare KeyError from inside the DAG walk."""
         p = params.get(node.name)
-        if p is None or "w" not in p:
+        if p is None or weight not in p:
             raise ValueError(
-                f"params missing {'entry' if p is None else 'weight'} for "
+                f"params missing {'entry' if p is None else weight} for "
                 f"{node.op} node {node.name!r} (param keys: "
                 f"{sorted(params)})")
         if wants_bias and "b" not in p:
@@ -828,7 +861,8 @@ class GraphPlan:
         """Execute the DAG on ``x``.
 
         ``params``: ``{node_name: {"w": ..., "b": ...}}`` for conv and
-        dense nodes (``b`` only where the node wants one), or — for
+        dense nodes (``b`` only where the node wants one) and
+        ``{"g": ..., "b": ...}`` for norm nodes, or — for
         graphs lowered from ``ConvGraph.chain`` — the legacy list of
         one ``(w, bias)`` pair per conv node in graph order.  No plan()
         resolution happens here — the program was resolved up front.
@@ -875,6 +909,13 @@ class GraphPlan:
             p = self._node_params(params, node, node.bias)
             y = ins[0] @ p["w"]
             return y + p["b"] if node.bias else y
+        if isinstance(node, NormOp):
+            p = self._node_params(params, node, True, weight="g")
+            x = ins[0].astype(jnp.float32)
+            mu = x.mean(axis=-1, keepdims=True)
+            var = jnp.square(x - mu).mean(axis=-1, keepdims=True)
+            y = (x - mu) * jax.lax.rsqrt(var + node.eps) * p["g"] + p["b"]
+            return y.astype(ins[0].dtype)
         raise TypeError(f"unknown IR node type {type(node)}")
 
     def _attach_quant(self) -> None:

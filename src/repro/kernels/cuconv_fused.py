@@ -40,7 +40,10 @@ the MXU as one ``(rows*OW x C) @ (C x TM)`` matmul.
 
 Epilogue (DESIGN.md §4): on the final tap the still-VMEM-resident
 accumulator takes bias add + activation before the single HBM write —
-``relu(conv(x, w) + b)`` costs no extra HBM round trip.
+``relu(conv(x, w) + b)`` costs no extra HBM round trip.  The activation
+is ReLU or the exact GELU, ``0.5*y*(1 + erf(y/sqrt(2)))``: Mosaic
+lowers no ``erf``, so ``erf`` here is a float32 rational approximation
+built from multiplies, adds and one divide.
 
 Cross-layer fusion (DESIGN.md §10) extends the same epilogue slot:
 
@@ -68,6 +71,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import _compat
+
+# erf(x) ~ x * P(x^2) / Q(x^2) on [-4, 4], and +-1 in float32 beyond:
+# the rational form XLA long used for float32 erf (coefficients highest
+# degree first); within 6e-7 of lax.erf on [-8, 8]
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04, -1.68282697438203e-03,
+          -7.37332916720468e-03, -1.42647390514189e-02)
+
+
+def _horner(coefs, x):
+    acc = jnp.full_like(x, coefs[0])
+    for c in coefs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def erf(x):
+    """float32 erf from operations Mosaic lowers (no ``lax.erf_p``)."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    return x * _horner(_ERF_P, x2) / _horner(_ERF_Q, x2)
+
+
+def gelu(y):
+    """The exact GELU, ``0.5*y*(1 + erf(y/sqrt(2)))`` (not the tanh form)."""
+    return 0.5 * y * (1.0 + erf(y * 0.7071067811865476))
 
 
 def _make_kernel(kw: int, ow: int, sh: int, sw: int, rows: int, taps: int,
@@ -121,6 +152,8 @@ def _make_kernel(kw: int, ow: int, sh: int, sw: int, rows: int, taps: int,
                 acc = acc + a_ref[0].astype(jnp.float32)
             if activation == "relu":
                 acc = jnp.maximum(acc, 0.0)
+            elif activation == "gelu":
+                acc = gelu(acc)
             acc_ref[...] = acc
             if pool is not None:
                 o_ref[0] = _pool_block(acc_ref, rows, ow, *pool)
@@ -169,8 +202,9 @@ def cuconv_fused(x, w, bias=None, stride=(1, 1), padding=(0, 0),
                  tm=128, rows=1, *, interpret):
     """x: (N, H, W, C) NHWC; w: (KH, KW, C, M) HWIO; stride (sh, sw) >= 1.
 
-    bias: optional (M,) added on the final tap; activation: None | 'relu',
-    applied after bias — both fused in VMEM before the output write.
+    bias: optional (M,) added on the final tap; activation: None | 'relu'
+    | 'gelu' (exact), applied after bias — both fused in VMEM before the
+    output write.
     addend: optional (N, OH, OW, M) residual operand added after the
     bias and before the activation (cross-layer add fusion).  pool:
     optional ``(kind, psh, psw)`` non-overlapping max/avg pool (window
@@ -187,6 +221,9 @@ def cuconv_fused(x, w, bias=None, stride=(1, 1), padding=(0, 0),
     N, H, W, C = x.shape
     KH, KW, _, M = w.shape
     sh, sw = stride
+    if activation not in (None, "relu", "gelu"):
+        raise ValueError(f"activation must be None, 'relu' or 'gelu'; "
+                         f"got {activation!r}")
     rows = min(int(rows), (H + 2 * padding[0] - KH) // sh + 1)
     if rows < 1:
         raise ValueError(f"rows must be >= 1; got {rows}")

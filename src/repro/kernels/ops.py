@@ -14,7 +14,8 @@ import jax.numpy as jnp
 
 from repro.kernels import (conv1x1 as _c1, cuconv_stage1 as _s1,
                            cuconv_stage2 as _s2, cuconv_fused as _cf,
-                           conv1d_tap as _c1d, direct_conv as _dcv,
+                           conv1d_tap as _c1d, depthwise_tap as _dw,
+                           direct_conv as _dcv,
                            flash_attention as _fa, int8_gemm as _i8,
                            winograd_pallas as _wg)
 
@@ -122,6 +123,17 @@ def direct_conv(x, w, padding=(0, 0), stride=(1, 1), tm=128, tc=256,
     return _dcv.direct_conv(x, w, tuple(padding), _norm_stride(stride),
                             tm=tm, tc=tc,
                             interpret=_auto_interpret(interpret))
+
+
+def depthwise_conv(x, w, padding=(0, 0), bias=None, relu=False, nb=1,
+                   tc=128, rows=1, interpret=None):
+    """Depthwise conv (stride 1, one filter per channel) one tap at a
+    time in VMEM, bias and ReLU fused; ``nb``/``tc``/``rows`` are the
+    depthwise_tap executor's launch config (images per step, channel
+    tile, output rows per accumulator)."""
+    return _dw.depthwise_tap(x, w, bias, padding=tuple(padding), relu=relu,
+                             nb=nb, tc=tc, rows=rows,
+                             interpret=_auto_interpret(interpret))
 
 
 def pool2d(x, kind="max", window=(2, 2), stride=(2, 2), padding=(0, 0)):

@@ -27,7 +27,7 @@ import numpy as np
 
 from repro.core import cuconv
 from repro.core.graph import (ConvOp, DenseOp, Graph, GraphBuilder,
-                              GraphPlan, PrecisionPolicy, plan_graph)
+                              GraphPlan, NormOp, PrecisionPolicy, plan_graph)
 
 
 def init_conv(key, kh, kw, c_in, c_out, dtype=jnp.float32):
@@ -64,7 +64,8 @@ class GraphModel:
     for one input geometry (``precision`` is a ``PrecisionPolicy`` —
     ``GraphBuilder`` accepts it wherever a dtype string went); params
     are a name-keyed dict mirroring the IR (``{node_name: {"w": ...,
-    "b": ...}}`` for conv and dense nodes).  Param shapes are
+    "b": ...}}`` for conv and dense nodes, ``{"g": ..., "b": ...}`` for
+    norm nodes).  Param shapes are
     geometry-independent (GAP decouples the head from the spatial
     extent), so ``init`` builds the graph once at the model's canonical
     ``image_shape``.  Master params are always fp32; a bf16 policy casts
@@ -129,9 +130,10 @@ class GraphModel:
 
     # -- params ----------------------------------------------------------
     def init(self, key):
-        """Name-keyed params for every conv/dense node of the graph."""
+        """Name-keyed params for every conv/dense/norm node of the graph."""
         graph = self.graph((1,) + self.image_shape)
-        needy = [n for n in graph.nodes if isinstance(n, (ConvOp, DenseOp))]
+        needy = [n for n in graph.nodes
+                 if isinstance(n, (ConvOp, DenseOp, NormOp))]
         keys = jax.random.split(key, max(len(needy), 1))
         params: Dict[str, Dict] = {}
         for k, node in zip(keys, needy):
@@ -140,6 +142,10 @@ class GraphModel:
                 p = init_conv(k, kh, kw, cpg, m)
                 if not node.spec.has_bias:
                     del p["b"]
+            elif isinstance(node, NormOp):
+                c = graph.shapes[node.name][-1]
+                p = {"g": jnp.ones((c,), jnp.float32),
+                     "b": jnp.zeros((c,), jnp.float32)}
             else:
                 c_in, c_out = node.features
                 p = {"w": jax.random.normal(k, (c_in, c_out), jnp.float32)

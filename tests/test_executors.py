@@ -271,10 +271,11 @@ def test_registry_lookup_and_registration_errors():
         ex.register(_Inert())                        # fails at registration
     assert set(ex.registered()) == set(ex.names())
     # ALGORITHMS is the fn-backed back-compat view: fn-less builtins
-    # (the int8 executor overrides execute() wholesale) are registered
-    # but absent from it
+    # (the int8 executor overrides execute() wholesale; the depthwise
+    # kernel has only its _execute) are registered but absent from it
     assert set(ex.ALGORITHMS) <= set(ex.names())
-    assert set(ex.names()) - set(ex.ALGORITHMS) == {"cuconv_int8"}
+    assert set(ex.names()) - set(ex.ALGORITHMS) == {"cuconv_int8",
+                                                    "depthwise_tap"}
     assert ex.ALGORITHMS["lax"] is cc.conv_lax
     spec = cs.ConvSpec((1, 6, 6, 4), (3, 3, 4, 4), (1, 1), (1, 1))
     assert ex.capable("lax", spec)

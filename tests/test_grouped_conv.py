@@ -108,7 +108,12 @@ def test_grouped_plan_routes_to_library_conv():
     assert "feature_group_count" in p.reason
     for name in ex.names():
         ok, why = cs.supports(name, spec)
-        assert ok == (name == "lax"), name
+        assert ok == (name in ("lax", "depthwise_tap")), name
+    # a grouped spec that is not depthwise has the library conv alone
+    grouped = cs.ConvSpec((1, 8, 8, 8), (3, 3, 2, 8), (1, 1), (1, 1),
+                          groups=4)
+    for name in ex.names():
+        assert cs.supports(name, grouped)[0] == (name == "lax"), name
 
 
 def test_forcing_ungrouped_executor_on_grouped_spec_raises():
@@ -125,23 +130,24 @@ def test_forcing_ungrouped_executor_on_grouped_spec_raises():
     assert "groups" in msg
     with pytest.raises(ValueError, match="winograd"):
         cs.plan(spec, force="winograd")
-    # the one grouped-capable executor still forces cleanly
+    # a grouped-capable executor still forces cleanly
     fp = cs.plan(spec, force="lax")
     assert (fp.algorithm, fp.source) == ("lax", "forced")
 
 
 def test_grouped_measure_and_heuristic_on_tpu_backend(rng):
-    """Measured mode and the TPU heuristic both land on the library conv
-    (the only supported executor) for grouped specs."""
+    """Measured mode times the library conv and the depthwise kernel for
+    a depthwise spec, and records the faster."""
     from repro.core import autotune
     spec = _dw_spec()
-    assert tuple(autotune.default_candidates(spec)) == ("lax",)
-    assert cs.plan(spec, backend="tpu").algorithm == "lax"
+    assert tuple(autotune.default_candidates(spec)) == ("lax",
+                                                        "depthwise_tap")
+    assert cs.plan(spec, backend="tpu").algorithm == "depthwise_tap"
     x = jnp.asarray(rng.normal(size=spec.in_shape), jnp.float32)
     w = jnp.asarray(rng.normal(size=spec.filter_shape), jnp.float32)
     best = autotune.measure_algorithm(x, w, repeats=1, groups=spec.groups)
-    assert best == "lax"
-    assert autotune.cached_best(spec) == "lax"
+    assert best in ("lax", "depthwise_tap")
+    assert autotune.cached_best(spec) == best
 
 
 @pytest.mark.parametrize("hw,k,m,c,groups", [
@@ -164,12 +170,12 @@ def test_real_mobilenet_depthwise_configs_plan_and_run(rng, hw, k, m, c,
 
 
 def test_unknown_activation_raises():
-    """for_conv must not silently drop unknown activations (the old
-    behaviour planned epilogue 'none' for activation='gelu')."""
+    """for_conv must not silently drop unknown activations (an old
+    behaviour planned epilogue 'none' for any unknown one)."""
     x = jnp.zeros((1, 8, 8, 4), jnp.float32)
     w = jnp.zeros((3, 3, 4, 4), jnp.float32)
-    with pytest.raises(ValueError, match="gelu"):
-        cs.ConvSpec.for_conv(x, w, 1, "same", activation="gelu")
+    with pytest.raises(ValueError, match="tanh"):
+        cs.ConvSpec.for_conv(x, w, 1, "same", activation="tanh")
     with pytest.raises(ValueError, match="activation"):
         cc.conv2d(x, w, 1, "same", bias=jnp.zeros((4,)),
                   activation="swish")
@@ -177,3 +183,6 @@ def test_unknown_activation_raises():
     assert cs.ConvSpec.for_conv(x, w, activation="relu").epilogue == "relu"
     assert cs.ConvSpec.for_conv(x, w, activation="none").epilogue == "none"
     assert cs.ConvSpec.for_conv(x, w, activation=None).epilogue == "none"
+    assert cs.ConvSpec.for_conv(x, w, activation="gelu").epilogue == "gelu"
+    assert cs.ConvSpec.for_conv(x, w, bias=jnp.zeros((4,)),
+                                activation="gelu").epilogue == "bias_gelu"

@@ -185,3 +185,31 @@ def test_tpu_planned_default_config_compiles(one_chip, label):
                          ((spec.filter_shape[3],), F32))
     if ex.get(p.algorithm).takes_interpret:
         assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("label", [
+    "convnext-s1dw-b32", "convnext-s2dw-b32", "convnext-s3dw-b32",
+    "convnext-s4dw-b8", "convnext-s1pw1-gelu-b32", "convnext-s3pw1-gelu-b8"])
+def test_tpu_planned_convnext_nodes_compile(one_chip, label):
+    """ConvNeXt-T's depthwise and GELU-epilogue nodes as
+    ``plan(backend="tpu")`` plans them, at published widths."""
+    def dw(n, h, c):
+        return cs.ConvSpec((n, h, h, c), (7, 7, 1, c), padding=(3, 3),
+                           epilogue="bias", groups=c)
+
+    def pw1(n, h, c):
+        return cs.ConvSpec((n, h, h, c), (1, 1, c, 4 * c),
+                           epilogue="bias_gelu")
+    spec = {"convnext-s1dw-b32": dw(32, 56, 96),
+            "convnext-s2dw-b32": dw(32, 28, 192),
+            "convnext-s3dw-b32": dw(32, 14, 384),
+            "convnext-s4dw-b8": dw(8, 7, 768),
+            "convnext-s1pw1-gelu-b32": pw1(32, 56, 96),
+            "convnext-s3pw1-gelu-b8": pw1(8, 14, 384)}[label]
+    p = dataclasses.replace(cs.plan(spec, backend="tpu"), interpret=False)
+    assert p.source != "fallback"
+    txt = _compiled_text(p, one_chip, (spec.in_shape, F32),
+                         (spec.filter_shape, F32),
+                         ((spec.filter_shape[3],), F32))
+    if ex.get(p.algorithm).takes_interpret:
+        assert "tpu_custom_call" in txt
