@@ -31,6 +31,7 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.dist import sharding as _sh
@@ -38,6 +39,9 @@ from repro.dist import sharding as _sh
 
 @dataclasses.dataclass
 class ImageRequest:
+    """Images to classify.  ``images`` must not be mutated until the
+    request is done: a serving layer may read them in place (the
+    frontend's feed path puts a request's block as its own array)."""
     rid: int
     images: np.ndarray                  # (n, H, W, C), or (H, W, C) for one
     out: Optional[np.ndarray] = None    # (n, num_classes) once served
@@ -83,6 +87,16 @@ def pack_units(chunk: Sequence[Tuple[ImageRequest, int]], bucket: int,
     if pad:
         parts.append(np.zeros((pad,) + tuple(image_shape), dtype))
     return np.concatenate(parts, axis=0)
+
+
+def _concatenate(xs):
+    return jnp.concatenate(xs, axis=0)
+
+
+# the feed path's device-side assembly of a batch from its slices: its
+# own small program (module ``jit_assemble``) beside the bucket program
+_concatenate.__name__ = _concatenate.__qualname__ = "assemble"
+_assemble = jax.jit(_concatenate)
 
 
 def scatter_outputs(chunk: Sequence[Tuple[ImageRequest, int]],
@@ -146,6 +160,7 @@ class BucketPrograms:
         self.fuse = fuse
         self._input_dtype = np.dtype(input_dtype or np.float32)
         self._fns: Dict[int, Callable] = {}    # global bucket -> program
+        self._zero_slice = None                 # feed path, made once
         self._plans: Dict[int, object] = {}    # global bucket -> GraphPlan
         # built once: NamedSharding construction is ~0.1ms of pure
         # Python, far too hot to repeat on every packed batch
@@ -277,6 +292,63 @@ class BucketPrograms:
              bucket: int) -> np.ndarray:
         return pack_units(chunk, bucket, self.image_shape,
                           self.input_dtype())
+
+    # -- the feed path: a batch as request-aligned slices -----------------
+    def slice_size(self, b: int) -> int:
+        """Images per slice of global bucket ``b`` on the feed path: the
+        smallest bucket where it divides ``b``, else ``b`` (one slice)."""
+        s = self.buckets[0]
+        return s if b % s == 0 else b
+
+    def zero_slice(self):
+        """The all-padding slice of the smallest bucket, on the device:
+        made once, never transferred again."""
+        if self._zero_slice is None:
+            self._zero_slice = self.put(np.zeros(
+                (self.buckets[0],) + self.image_shape, self.input_dtype()))
+        return self._zero_slice
+
+    def slices(self, chunk: Sequence[Tuple[ImageRequest, int]],
+               bucket: int) -> Tuple[list, int]:
+        """Global bucket ``bucket``'s input as ``bucket / slice_size``
+        slices, and how many of them are views.  A slice that is one
+        contiguous block of one request, already C-contiguous in
+        ``input_dtype()``, is that request's own array (a view, no
+        copy); a slice mixing requests, cast or partly padded is packed
+        alone at slice size; an all-padding slice is ``zero_slice()``.
+        The request's images are read in place until the put is done."""
+        s, dtype = self.slice_size(bucket), self.input_dtype()
+        out: list = []
+        views = 0
+        for k in range(0, bucket, s):
+            part = chunk[k:k + s]
+            if not part:
+                out.append(self.zero_slice())
+                continue
+            blocks = contiguous_blocks(part)
+            if len(part) == s and len(blocks) == 1:
+                r, i0, i1 = blocks[0]
+                view = r.images[i0:i1]
+                if view.dtype == dtype and view.flags.c_contiguous:
+                    out.append(view)
+                    views += 1
+                    continue
+            out.append(pack_units(part, s, self.image_shape, dtype))
+        return out, views
+
+    def assemble(self, xs: Sequence):
+        """One batch from its slices already on the device: a
+        concatenation in a jitted step of its own (one slice is the
+        batch itself)."""
+        return xs[0] if len(xs) == 1 else _assemble(list(xs))
+
+    def warm_feed(self, b: int) -> None:
+        """Compile the feed path's assembly for global bucket ``b`` (and
+        make the zero slice), so the fed batch runs warm programs
+        only."""
+        k = b // self.slice_size(b)
+        if k > 1:
+            jax.block_until_ready(self.assemble([self.zero_slice()] * k))
 
     def warmup(self, *, measure: bool = False,
                tune: Optional[str] = None) -> Dict[int, float]:

@@ -32,6 +32,16 @@ scheduler in front of them:
   N's in-flight compute — every such batch is flagged ``overlapped`` in
   telemetry, the signal the CI smoke test asserts on.
 
+* **The feed path.**  A batch whose packed input is larger than
+  ``FEED_BYTES`` is not packed, and the serve thread does not wait for
+  its transfer: one feeder thread puts it as request-aligned slices
+  (``BucketPrograms.slices``: a slice that is one block of one request
+  is that request's own array), assembles them on the device and
+  launches the program, batch after batch in dispatch order; harvest
+  waits for the feeder's future, then for the result.  Smaller batches,
+  and every batch of a sharded frontend, take the inline path above.
+  A request's ``images`` may be read in place until it is done.
+
 * **SLO-aware bucket choice.**  When the tightest pending deadline has
   less slack than the close policy's remaining wait (plus
   ``slo_close_margin_ms`` headroom), the batch closes immediately into
@@ -63,12 +73,15 @@ scheduler in front of them:
 
 The scheduler is single-threaded and clock-injected (``clock=``): JAX's
 async dispatch provides the device-side concurrency, so behaviour is
-deterministic and testable with a fake clock.
+deterministic and testable with a fake clock.  The feeder thread only
+moves and launches batches the scheduler already formed.
 """
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
+import math
 import time
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -79,6 +92,16 @@ import numpy as np
 from repro.serve.cnn import BucketPrograms, ImageRequest, scatter_outputs
 from repro.serve.telemetry import (
     BatchTrace, RequestTrace, Telemetry, union_length)
+
+#: a batch whose packed input is larger than this many bytes takes the
+#: feed path.  On a TPU v5e (bench/tools/put_timing.py) a packed put of
+#: 224x224x3 float32 images takes 2.2 ms at 8 images (4.8 MB), 3.9 ms
+#: at 16 and 7.1 ms at 32, where a list of four 8-image views takes
+#: 3.5 ms.  A thread that puts while the serve thread polls in a loop
+#: waits a switch interval (5 ms) for the GIL and its put takes 23 ms,
+#: so the batches of a polling, latency-bound loop (8 images or fewer)
+#: keep the serve thread's own put.
+FEED_BYTES = 8 << 20
 
 #: request lifecycle states
 PENDING = "pending"
@@ -133,7 +156,7 @@ class _InFlight:
     """One dispatched, not-yet-harvested batch."""
     shape: Tuple[int, int, int]
     chunk: List[Tuple[ServeRequest, int]]
-    result: object                          # the async device array
+    result: object      # the async device array, or the feeder's future
     trace: BatchTrace
 
 
@@ -196,6 +219,7 @@ class AsyncServeFrontend:
         self._max_inflight = 0
         self._slo_closes = 0
         self._batch_counts: Dict[str, int] = {}
+        self._feeder: Optional[concurrent.futures.ThreadPoolExecutor] = None
 
     # ------------------------------------------------------------------
     @property
@@ -204,10 +228,23 @@ class AsyncServeFrontend:
 
     def warmup(self, *, measure: bool = False, tune: Optional[str] = None
                ) -> Dict[str, Dict[int, float]]:
-        """Compile every geometry's bucket programs; per-bucket compile
-        milliseconds keyed by geometry string."""
-        return {_geom(shape): progs.warmup(measure=measure, tune=tune)
-                for shape, progs in self.programs.items()}
+        """Compile every geometry's bucket programs (and the feed path's
+        assembly where a bucket feeds); per-bucket compile milliseconds
+        keyed by geometry string."""
+        out = {}
+        for shape, progs in self.programs.items():
+            out[_geom(shape)] = progs.warmup(measure=measure, tune=tune)
+            for b in progs.buckets:
+                if self._feeds(progs, b):
+                    progs.warm_feed(b)
+        return out
+
+    def close(self) -> None:
+        """Stop the feeder thread, once the batches handed to it are
+        fed."""
+        if self._feeder is not None:
+            self._feeder.shutdown()
+            self._feeder = None
 
     # -- admission ------------------------------------------------------
     def submit(self, req: ServeRequest) -> None:
@@ -300,21 +337,50 @@ class AsyncServeFrontend:
                 r._close_t = now
         return chunk, b
 
+    @staticmethod
+    def _feeds(progs: BucketPrograms, bucket: int) -> bool:
+        """Whether ``bucket``'s batches take the feed path: unsharded,
+        and more than ``FEED_BYTES`` packed."""
+        return (progs.mesh is None and bucket * progs.input_dtype().itemsize
+                * math.prod(progs.image_shape) > FEED_BYTES)
+
+    def _feed(self, progs: BucketPrograms, chunk, bucket: int):
+        """The feeder thread's part of a fed batch: slice it, put the
+        slices with one ``device_put``, wait for them, assemble and
+        launch.  Returns the async result, the batch's dispatch stamps
+        (pack, put, put done, launched) and its slice counts."""
+        tp = self._clock()
+        xs, views = progs.slices(chunk, bucket)
+        t0 = self._clock()
+        xs = jax.block_until_ready(progs.put(xs))
+        t1 = self._clock()
+        y = progs.fn(bucket)(progs.params, progs.assemble(xs))
+        return y, (tp, t0, t1, self._clock()), len(xs), views
+
     def _dispatch(self, shape, chunk, bucket: int) -> None:
         progs = self.programs[shape]
-        tp = self._clock()
-        xb = progs.pack(chunk, bucket)
-        # transfer: host blocks only on the COPY — any in-flight batch
-        # keeps computing on the device meanwhile (the overlap).  The
-        # put is explicit (and sharded under a mesh); params ride the
-        # program's own once-replicated tree, never re-transferred.
         overlapped = bool(self._inflight)
-        t0 = self._clock()
-        xd = progs.put(xb)
-        jax.block_until_ready(xd)
-        t1 = self._clock()
-        y = progs.fn(bucket)(progs.params, xd)  # async dispatch: no block
-        td = self._clock()
+        if self._feeds(progs, bucket):
+            if self._feeder is None:
+                self._feeder = concurrent.futures.ThreadPoolExecutor(
+                    max_workers=1, thread_name_prefix="serve-feeder")
+            y = self._feeder.submit(self._feed, progs, list(chunk), bucket)
+            # the feeder's own stamps replace these at harvest
+            tp = t0 = t1 = td = self._clock()
+        else:
+            tp = self._clock()
+            xb = progs.pack(chunk, bucket)
+            # transfer: host blocks only on the COPY — any in-flight
+            # batch keeps computing on the device meanwhile (the
+            # overlap).  The put is explicit (and sharded under a mesh);
+            # params ride the program's own once-replicated tree, never
+            # re-transferred.
+            t0 = self._clock()
+            xd = progs.put(xb)
+            jax.block_until_ready(xd)
+            t1 = self._clock()
+            y = progs.fn(bucket)(progs.params, xd)  # async: no block
+            td = self._clock()
         batch_id, self._batch_seq = self._batch_seq, self._batch_seq + 1
         rids: List[int] = []
         for r, _ in chunk:      # a request's units are contiguous
@@ -339,7 +405,18 @@ class AsyncServeFrontend:
     def _harvest_one(self) -> None:
         fl = self._inflight.popleft()
         tw = self._clock()
-        ready = jax.block_until_ready(fl.result)
+        result = fl.result
+        if isinstance(result, concurrent.futures.Future):
+            # a fed batch: the feeder's exception, if any, raises here
+            result, stamps, slices, views = result.result()
+            tr = fl.trace
+            tr.pack_t0, tr.transfer_t0, tr.transfer_t1, tr.dispatch_t = stamps
+            tr.slices, tr.view_slices = slices, views
+            tw = max(tw, tr.dispatch_t)
+            for r, _ in fl.chunk:
+                if r._batch_ids[0] == tr.batch_id:  # its first put
+                    r._first_dispatch_t = tr.transfer_t0
+        ready = jax.block_until_ready(result)
         tf = self._clock()
         # device_get is an EXPLICIT device->host gather (sharded outputs
         # reassemble across the mesh), keeping a warm serve loop clean

@@ -14,12 +14,18 @@ carried, and six host spans, one after another (``BATCH_STAGES``):
 
 ======================  ================================================
 ``frontend.pack``       packing the batch's units into one host array
+                        (a fed batch: building its slices)
 ``frontend.put``        ``device_put`` + ``block_until_ready`` of it
-``frontend.launch``     the call of the jitted bucket program (async)
+``frontend.launch``     the call of the jitted bucket program (async;
+                        a fed batch: its assembly, then the program)
 ``frontend.wait``       ``block_until_ready`` on the result at harvest
 ``frontend.fetch``      ``device_get`` of the result to host numpy
 ``frontend.scatter``    scattering the outputs, completing requests
 ======================  ================================================
+
+A fed batch (serve/frontend.py's feed path) takes its first three on
+the feeder thread, so they may overlap the serve thread's spans of
+other batches.
 
 and every admitted request one more, ``frontend.close`` (submit → the
 close of the batch that took its first unit: the admission policy's
@@ -156,8 +162,14 @@ class BatchTrace:
     ``request_ids`` the ids of the requests whose units it carried.
     The stamps bound the ``BATCH_STAGES`` spans back to back:
     ``pack_t0`` → ``transfer_t0`` → ``transfer_t1`` → ``dispatch_t``
-    on dispatch, ``wait_t0`` → ``wait_t1`` → ``harvest_t`` →
-    ``scatter_t1`` on harvest; a stamp not taken is None."""
+    on dispatch (on the feeder thread for a fed batch), ``wait_t0`` →
+    ``wait_t1`` → ``harvest_t`` → ``scatter_t1`` on harvest; a stamp
+    not taken is None.
+
+    ``slices`` is how many arrays the batch's input was put as (one
+    packed array, or the feed path's request-aligned slices) and
+    ``view_slices`` how many of those were requests' own arrays, put
+    without a host copy."""
     geometry: str
     bucket: int
     units: int                          # real (non-padded) images
@@ -175,6 +187,8 @@ class BatchTrace:
     wait_t0: Optional[float] = None
     wait_t1: Optional[float] = None
     scatter_t1: Optional[float] = None
+    slices: int = 1
+    view_slices: int = 0
 
     @property
     def transfer_ms(self) -> float:
@@ -296,6 +310,9 @@ class Telemetry:
             "padded_slots": sum(b.padded for b in self.batches),
             "overlapped_batches": sum(1 for b in self.batches
                                       if b.overlapped),
+            # arrays put, and those put as requests' own arrays
+            "slices": sum(b.slices for b in self.batches),
+            "view_slices": sum(b.view_slices for b in self.batches),
             "latency_ms": self.latency_ms(),
             "batch_ms": self.batch_ms(),
         }

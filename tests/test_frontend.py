@@ -553,3 +553,240 @@ def test_spans_skip_missing_stamps_and_rejected_requests():
                          ("frontend.launch", 2.0, 3.0, 0, (7,))]
     assert list(t.batch_ms()) == ["put", "launch"]
     assert t.requests[0].close_ms is None
+
+
+# ---------------------------------------------------------------------------
+# the feed path: request-aligned slices put by the feeder thread
+
+@pytest.fixture
+def feed_all(monkeypatch):
+    """Every batch of the tiny geometries takes the feed path."""
+    from repro.serve import frontend
+    monkeypatch.setattr(frontend, "FEED_BYTES", 0)
+
+
+def _serve_both(monkeypatch, model, params, reqs, buckets, **kw):
+    """Serve ``reqs`` (rid -> images) on the inline path, then on the
+    feed path; both frontends and their done lists."""
+    from repro.serve import frontend
+    out = []
+    for feed_bytes in (frontend.FEED_BYTES, 0):
+        monkeypatch.setattr(frontend, "FEED_BYTES", feed_bytes)
+        fe = AsyncServeFrontend(model, params, {(8, 8, 3): buckets}, **kw)
+        fe.warmup()
+        for rid, x in reqs.items():
+            fe.submit(ServeRequest(rid=rid, images=x))
+        done = fe.run()
+        fe.close()
+        out.append((fe, done))
+    return out
+
+
+@pytest.mark.parametrize("sizes,buckets", [
+    ([2, 2, 2, 2, 2, 2], (2, 4)),           # request-aligned full batches
+    ([1, 3, 3, 1, 3, 1, 1], (2, 4)),        # slices that mix requests
+    ([2, 2, 1], (2, 4)),                    # a partly padded slice
+    ([3, 2, 2, 1], (2, 3)),                 # 2 does not divide 3
+])
+def test_feed_path_serves_bitwise_what_the_inline_path_serves(
+        rng, tiny, monkeypatch, sizes, buckets):
+    model, params = tiny
+    reqs = {i: rng.normal(size=(n, 8, 8, 3)).astype(np.float32)
+            for i, n in enumerate(sizes)}
+    (inline, done_i), (fed, done_f) = _serve_both(
+        monkeypatch, model, params, reqs, buckets)
+    assert inline._feeder is None and inline.stats()["view_slices"] == 0
+    assert [r.rid for r in done_f] == [r.rid for r in done_i]
+    for a, b in zip(done_i, done_f):
+        assert a.status == b.status == SERVED
+        np.testing.assert_array_equal(a.out, b.out)
+    for bi, bf in zip(inline.telemetry.batches, fed.telemetry.batches):
+        assert (bi.bucket, bi.units, bi.request_ids) == (
+            bf.bucket, bf.units, bf.request_ids)
+        s = fed.programs[(8, 8, 3)].slice_size(bf.bucket)
+        assert bf.slices == bf.bucket // s
+        assert bi.slices == 1 and bi.view_slices == 0
+    st = fed.stats()
+    assert st["slices"] == sum(b.slices for b in fed.telemetry.batches)
+    if sizes == [2] * 6:                # every slice one request's array
+        assert st["view_slices"] == st["slices"] == 6
+    else:
+        assert st["view_slices"] < st["slices"]
+
+
+def test_slices_are_views_packs_and_the_device_zero_slice(rng, tiny):
+    """The slice rule at the component: a one-request block in the
+    packing dtype is that request's own memory, a mixed, cast or short
+    slice is packed at slice size, an all-padding slice is the one
+    device-resident zero slice; assembled, they are the packed batch."""
+    from repro.serve.cnn import BucketPrograms, ImageRequest
+    model, params = tiny
+    progs = BucketPrograms(model, params, (8, 8, 3), buckets=(2, 8))
+    a = ImageRequest(0, rng.normal(size=(3, 8, 8, 3)).astype(np.float32))
+    b = ImageRequest(1, rng.normal(size=(2, 8, 8, 3)))      # float64
+    chunk = [(a, 0), (a, 1), (a, 2), (b, 0), (b, 1)]
+    xs, views = progs.slices(chunk, 8)
+    assert progs.slice_size(8) == 2 and len(xs) == 4 and views == 1
+    assert np.shares_memory(xs[0], a.images)
+    assert xs[1].shape == xs[2].shape == (2, 8, 8, 3)
+    assert xs[1].dtype == xs[2].dtype == np.float32
+    assert xs[3] is progs.zero_slice()
+    assert isinstance(xs[3], jax.Array)
+    np.testing.assert_array_equal(
+        np.asarray(progs.assemble(progs.put(xs))), progs.pack(chunk, 8))
+    fn = progs.fn(8)
+    np.testing.assert_array_equal(
+        np.asarray(fn(params, progs.assemble(progs.put(xs)))),
+        np.asarray(fn(params, progs.put(progs.pack(chunk, 8)))))
+
+
+def test_feed_path_keeps_the_pipeline_depth_and_one_transfer_at_a_time(
+        rng, tiny, feed_all):
+    """Batches queue at the feeder (each feed is slowed), yet no more
+    than ``pipeline_depth`` are ever in flight, the depth is reached,
+    transfers never overlap, and requests complete in order."""
+    import time
+    model, params = tiny
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (2,)},
+                            pipeline_depth=2)
+    fe.warmup()
+    feed = fe._feed
+    queued = []
+
+    def slow_feed(*a):
+        queued.append(len(fe._inflight))
+        time.sleep(0.02)
+        return feed(*a)
+    fe._feed = slow_feed
+    _stream(rng, fe, [2, 2, 2, 2, 2])
+    done = fe.run()
+    fe.close()
+    assert [r.rid for r in done] == list(range(5))
+    st = fe.stats()
+    assert st["max_inflight"] == 2 and st["inflight"] == 0
+    assert max(queued) <= 2
+    assert st["overlapped_batches"] == 4
+    batches = sorted(fe.telemetry.batches, key=lambda b: b.batch_id)
+    for prev, nxt in zip(batches, batches[1:]):
+        assert prev.transfer_t1 <= nxt.transfer_t0      # FIFO feeder
+    for b in batches:
+        bounds = [b.stage_bounds(s) for s in BATCH_STAGES]
+        assert all(x[0] <= x[1] <= y[0] for x, y in zip(bounds, bounds[1:]))
+    for t in fe.telemetry.requests:
+        assert t.queue_ms >= 0.0 and t.total_ms >= t.compute_ms
+
+
+def test_feeder_exception_surfaces_at_poll_and_flush(rng, tiny, feed_all):
+    model, params = tiny
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (2,)},
+                            pipeline_depth=2)
+    fe.warmup()
+
+    def boom(chunk, bucket):
+        raise RuntimeError("feeder boom")
+    fe.programs[(8, 8, 3)].slices = boom
+    _stream(rng, fe, [2])
+    assert fe.poll() == []              # handed to the feeder, not harvested
+    with pytest.raises(RuntimeError, match="feeder boom"):
+        fe.flush()
+    _stream(rng, fe, [2, 2])
+    with pytest.raises(RuntimeError, match="feeder boom"):
+        fe.poll()                       # the depth forces a harvest
+    fe.close()
+
+
+def test_warmup_compiles_exactly_the_trace_that_serves_on_the_feed_path(
+        rng, tiny, feed_all):
+    """The inline test's requests in other host dtypes, fed: each is
+    cast as its slice is packed, and serving compiles nothing — no
+    bucket program retraces, the assembly step neither."""
+    from repro.serve import cnn
+    model, params = tiny
+    fe = AsyncServeFrontend(model, params, {(8, 8, 3): (1, 2)})
+    fe.warmup()
+    assembled = cnn._assemble._cache_size()
+    fe.submit(ServeRequest(rid=0, images=rng.normal(
+        size=(3, 8, 8, 3))))            # float64 host images
+    fe.submit(ServeRequest(rid=1, images=rng.normal(
+        size=(2, 8, 8, 3)).astype(np.float16)))
+    done = fe.run()
+    fe.close()
+    assert all(r.status == SERVED for r in done)
+    assert fe.stats()["view_slices"] == 0          # every slice was cast
+    assert all(b.slices == b.bucket for b in fe.telemetry.batches)
+    for b, fn in fe.programs[(8, 8, 3)]._fns.items():
+        assert fn._cache_size() == 1, f"bucket {b} retraced while serving"
+    assert cnn._assemble._cache_size() == assembled
+    for r in done:
+        for i in range(r.images.shape[0]):
+            ref = _lax_model_ref(model, params, jnp.asarray(
+                r.images[i:i + 1], jnp.float32))
+            np.testing.assert_allclose(r.out[i], np.asarray(ref)[0],
+                                       rtol=2e-3, atol=2e-3)
+
+
+def test_only_unsharded_batches_over_the_threshold_are_fed(tiny):
+    """The gate reads a batch's packed bytes: under ``FEED_BYTES`` (the
+    tests' tiny geometries, an interactive 8-image 224x224 batch) and
+    on a sharded frontend, batches take the inline path."""
+    import types
+
+    from repro.serve import frontend
+    feeds = AsyncServeFrontend._feeds
+    image = 224 * 224 * 3 * 4
+
+    def progs(shape, mesh=None):
+        return types.SimpleNamespace(
+            mesh=mesh, image_shape=shape,
+            input_dtype=lambda: np.dtype(np.float32))
+    assert not feeds(progs((8, 8, 3)), 32)
+    assert not feeds(progs((224, 224, 3)), 8)
+    assert 8 * image < frontend.FEED_BYTES < 32 * image
+    assert feeds(progs((224, 224, 3)), 32)
+    assert not feeds(progs((224, 224, 3), mesh=object()), 32)
+
+
+def test_feed_path_under_a_short_switch_interval_serves_every_unit_once(
+        rng, tiny, monkeypatch):
+    """Stress: the serve thread submits and polls while the feeder
+    feeds, the interpreter switching threads every 10 us; every request
+    completes once, batches are harvested in dispatch order, and the
+    outputs are the inline path's under the same loop."""
+    import sys
+
+    from repro.serve import frontend
+    model, params = tiny
+    sizes = [int(n) for n in rng.integers(1, 6, size=40)]
+    reqs = {i: rng.normal(size=(n, 8, 8, 3)).astype(np.float32)
+            for i, n in enumerate(sizes)}
+
+    def serve(feed_bytes):
+        monkeypatch.setattr(frontend, "FEED_BYTES", feed_bytes)
+        fe = AsyncServeFrontend(model, params, {(8, 8, 3): (2, 4)},
+                                max_wait_ms=0.0, pipeline_depth=3)
+        fe.warmup()
+        done = []
+        try:
+            for rid, x in reqs.items():
+                fe.submit(ServeRequest(rid=rid, images=x))
+                done += fe.poll()
+            done += fe.run()
+        finally:
+            fe.close()
+        return fe, done
+    _, want = serve(frontend.FEED_BYTES)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        fe, done = serve(0)
+    finally:
+        sys.setswitchinterval(old)
+    assert fe.stats()["slices"] > fe.stats()["batches"]     # it fed
+    assert [r.rid for r in done] == [r.rid for r in want]
+    assert sorted(r.rid for r in done) == list(range(len(sizes)))
+    assert fe.stats()["served"] == len(sizes)
+    assert fe.stats()["max_inflight"] == 3
+    for a, b in zip(done, want):
+        np.testing.assert_array_equal(a.out, b.out)
+    ids = [b.batch_id for b in fe.telemetry.batches]
+    assert ids == sorted(ids)           # harvested in dispatch order
