@@ -1,21 +1,22 @@
 """Quickstart: the cuConv public API in 40 lines.
 
-Runs one convolution through every registered executor (library
-baseline, explicit GEMM, the paper's two-stage cuConv, the fused
-beyond-paper variant, and the Pallas TPU kernel in interpret mode) and
-checks they agree; then uses the cuDNN-style per-layer autotuner — both
-the algorithm sweep and the per-configuration *launch-config* sweep
-(tile geometry per convolution configuration, the paper's own
+Plans one convolution (the paper's headline 1x1 layer) as a TPU and as
+this machine negotiate it, runs it through every registered executor
+that can take it (the Pallas kernels in interpret mode off the TPU) and
+checks they agree with the library conv; then uses the cuDNN-style
+per-layer autotuner — both the algorithm sweep and the launch-config
+sweep (tile geometry per convolution configuration, the paper's own
 config-selection lever).
 
   PYTHONPATH=src python examples/quickstart.py
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import conv2d
 from repro.core import executors
-from repro.core.autotune import select_algorithm, measure_algorithm
+from repro.core.autotune import measure_algorithm
 from repro.core.convspec import ConvSpec, plan
 
 rng = np.random.default_rng(0)
@@ -23,23 +24,26 @@ rng = np.random.default_rng(0)
 # batch 1 (GoogleNet inception 5a) — cuConv's 2.29x region on V100
 x = jnp.asarray(rng.normal(size=(1, 7, 7, 832)), jnp.float32)
 w = jnp.asarray(rng.normal(size=(1, 1, 832, 256)), jnp.float32)
+spec = ConvSpec.for_conv(x, w)
+
+for backend in ("tpu", jax.default_backend()):
+    name, source, reason = executors.negotiate(spec, backend)
+    print(f"negotiated on {backend}: {name} [{source}] {reason}")
 
 ref = conv2d(x, w, algorithm="lax")
-print(f"output shape: {ref.shape}")
-for name in executors.names():      # the registered executor menu
-    out = conv2d(x, w, algorithm=name)
-    err = float(jnp.abs(out - ref).max())
-    print(f"  {name:24s} max_err_vs_library = {err:.2e}")
+out = conv2d(x, w)                  # algorithm="auto": plan() negotiates
+print(f"output shape: {out.shape}   {plan(spec).explain()}")
+for name in executors.supporting(spec):
+    err = float(jnp.abs(conv2d(x, w, algorithm=name) - ref).max())
+    print(f"  {name:16s} max_err_vs_library = {err:.2e}")
 
-heur = select_algorithm(x.shape, w.shape)
-best = measure_algorithm(x, w)
-print(f"autotune heuristic: {heur}   measured best on this machine: {best}")
+print(f"measured best on this machine: {measure_algorithm(x, w)}")
 
-# launch-config tuning: sweep the 1x1 Pallas kernel's VMEM-feasible tile
+# launch-config tuning: sweep the fused kernel's VMEM-feasible tile
 # geometries for THIS configuration and persist the (algorithm, config)
 # winner — a later plan() replays it from cache with zero re-measurement
-tuned = plan(ConvSpec.for_conv(x, w), force="conv1x1_pallas", tune="full")
+tuned = plan(spec, force="cuconv_pallas", tune="full")
 print(f"tuned launch config: {tuned.algorithm} "
       f"cfg[{tuned.config_source}]={tuned.config.key()}")
-replay = plan(ConvSpec.for_conv(x, w), force="conv1x1_pallas")
+replay = plan(spec, force="cuconv_pallas")
 assert replay.config == tuned.config and replay.config_source == "measured"

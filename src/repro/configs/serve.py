@@ -3,18 +3,17 @@
 One frozen dataclass describes an ``AsyncServeFrontend`` deployment —
 which ``(image_shape, buckets)`` programs it owns, how long a short
 batch may wait before dispatching padded, the default latency SLO, and
-the dispatch pipeline depth.  The CI smoke step and
-``benchmarks/graph_serve.py`` both build their frontends from the
-configs here so "the benchmarked deployment" is one named object, not
-numbers scattered across call sites.
+the dispatch pipeline depth.  The CI smoke steps build their frontends
+from the configs here (directly or through ``repro.launch.serve``), so
+each deployment is one named object, not numbers scattered across call
+sites.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Tuple
 
-#: The generous default SLO (milliseconds) used by smoke/benchmark
-#: traffic: wide enough that a CPU-backed interpret-mode run never
+#: The generous default SLO (milliseconds) used by smoke traffic: wide enough that a CPU-backed interpret-mode run never
 #: misses it — CI asserts ZERO deadline misses at this value — while
 #: still exercising the deadline-accounting path for every request.
 DEFAULT_SLO_MS = 60_000.0
@@ -41,8 +40,7 @@ class FrontendConfig:
                 for shape, buckets in self.geometries}
 
 
-#: the deployment the CI async-serve smoke and the benchmark serve:
-#: resnet_like traffic at two image resolutions through ONE frontend
+#: the deployment the CI async-serve smoke serves: resnet_like traffic at two image resolutions through ONE frontend
 SMOKE_FRONTEND = FrontendConfig(
     geometries=(((32, 32, 3), (1, 4)),
                 ((16, 16, 3), (1, 2))),
@@ -52,14 +50,13 @@ SMOKE_FRONTEND = FrontendConfig(
 )
 
 
-#: the multi-device smoke deployment, shared by the CI
-#: multi-device-smoke step, benchmarks/loadgen.py's ``sharded_scaling``
-#: sweep, and tests/test_distributed_serve.py.  The model is the named
-#: ``models.cnn.tiny_cnn``.  Buckets here are PER-SHARD capacities — a
-#: ``ShardedServeDispatcher`` on an N-device mesh serves global buckets
-#: N× these — and each geometry carries a SINGLE bucket so every image
-#: flows through one per-shard batch-shape program, the precondition
-#: for bitwise-identical outputs across device counts.
+#: the multi-device smoke deployment the CI multi-device-smoke step
+#: serves (``python -m repro.launch.serve --cnn-dist``).  The model is
+#: the named ``models.cnn.tiny_cnn``.  Buckets here are PER-SHARD
+#: capacities — a ``ShardedServeDispatcher`` on an N-device mesh serves
+#: global buckets N× these — and each geometry carries a SINGLE bucket
+#: so every image flows through one per-shard batch-shape program, the
+#: precondition for bitwise-identical outputs across device counts.
 DIST_SMOKE = FrontendConfig(
     geometries=(((8, 8, 3), (2,)),
                 ((12, 12, 3), (2,))),

@@ -9,7 +9,6 @@ lever maxDNN showed is worth large factors on its own):
 
   * heuristic mode — the registered executors' region claims
     (``executors.negotiate``, the paper's measured regions);
-    ``select_algorithm`` is the back-compat shape-tuple wrapper.
   * measured mode — ``measure_algorithm`` times every viable candidate
     executor (compiled, synced); ``measure_config`` then sweeps the
     winner's candidate *launch configs* (tile sizes, rows-per-step —
@@ -43,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.convspec import ConvPlan, ConvSpec, heuristic_algorithm
+from repro.core.convspec import ConvPlan, ConvSpec
 from repro.core.plancache import JsonCache
 
 #: persisted-entry schema.  v1 was the bare algorithm string (implicitly
@@ -177,15 +176,6 @@ def clear_cache() -> None:
 
 # ---------------------------------------------------------------------------
 # public API
-
-def select_algorithm(x_shape, w_shape, stride=1) -> str:
-    """Heuristic choice for a configuration (paper regions; see
-    convspec.heuristic_algorithm for the region map)."""
-    spec = ConvSpec(tuple(map(int, x_shape)), tuple(map(int, w_shape)),
-                    (stride, stride) if isinstance(stride, int)
-                    else tuple(stride))
-    return heuristic_algorithm(spec, jax.default_backend())[0]
-
 
 def default_candidates(spec: ConvSpec) -> Sequence[str]:
     """Every registered executor that can execute ``spec`` exactly —

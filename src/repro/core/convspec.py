@@ -19,8 +19,8 @@ module is that seam (DESIGN.md §4):
              returns a stable one-line story (executor provenance +
              dtype/accumulation) for benchmarks/debugging.
 
-Everything downstream (core.cuconv.conv2d, models.cnn, benchmarks,
-serve) routes through plan(); kernels/ops.py stays policy-free, and
+Everything downstream (core.cuconv.conv2d, models.cnn, serve, the
+benchmark in bench/) routes through plan(); kernels/ops.py stays policy-free, and
 capability rules live on the executors themselves (DESIGN.md §8).
 """
 from __future__ import annotations

@@ -35,6 +35,11 @@ registered ``Executor`` object declaring:
                    included (in-kernel when ``fuses_epilogue``, XLA
                    ops otherwise)
 
+The built-in menu: ``lax`` (XLA's conv: grouped specs, the stems, every
+spec off the TPU, and the tests' reference), ``cuconv_pallas`` (the
+fused tap-accumulation kernel), ``winograd_pallas`` (large 3x3 stride-1),
+``cuconv_int8`` (the int8 path) and ``depthwise_tap``.
+
 ``convspec.plan()`` is pure negotiation over these declarations
 (forced > measured cache > heuristic claims > cheapest supported);
 nothing outside this module special-cases an executor name.  Adding a
@@ -44,10 +49,8 @@ call, not a planner edit (README "Registering a third-party executor").
 from __future__ import annotations
 
 import dataclasses
-import functools
-import inspect
 from collections.abc import Mapping as _MappingABC
-from typing import Callable, Dict, Iterable, Optional, Tuple
+from typing import Dict, Iterable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -161,26 +164,6 @@ def _dedup_configs(dicts: Iterable[Dict[str, int]],
     return tuple(out)
 
 
-@functools.lru_cache(maxsize=None)
-def _accepts_kwarg(fn, name: str) -> bool:
-    """Does ``fn`` (an executor method) take a ``name`` kwarg?
-    Pre-config/pre-fusion third-party overrides — 5-argument
-    ``_execute``, ``vmem_bytes(self, spec)`` — keep their old
-    signatures and are called without the newer kwargs."""
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):            # builtins/C callables
-        return False
-    return (name in params
-            or any(p.kind is inspect.Parameter.VAR_KEYWORD
-                   for p in params.values()))
-
-
-def _accepts_config(fn) -> bool:
-    """Back-compat alias for ``_accepts_kwarg(fn, "config")``."""
-    return _accepts_kwarg(fn, "config")
-
-
 class Executor:
     """One registered convolution algorithm: capabilities + execution.
 
@@ -192,10 +175,6 @@ class Executor:
 
     #: registry identity (also the persisted-cache algorithm string)
     name: str = ""
-    #: raw conv callable ``fn(x, w, stride=, padding=, ...)`` — the
-    #: pre-registry ``ALGORITHMS`` surface, still exposed via the
-    #: ``algorithms()`` view for benchmarks that time bare kernels
-    fn: Optional[Callable] = None
     #: ConvSpec.dtype strings this executor accepts
     dtypes: Tuple[str, ...] = ("float32", "bfloat16")
     #: accumulation behavior for the channel contraction
@@ -289,12 +268,7 @@ class Executor:
         ok, why = self._config_supports(spec, config)
         if not ok:
             return False, why
-        # pre-config third-party overrides (vmem_bytes(self, spec)) are
-        # consulted without the config argument
-        if _accepts_config(type(self).vmem_bytes):
-            need = self.vmem_bytes(spec, config)
-        else:
-            need = self.vmem_bytes(spec)
+        need = self.vmem_bytes(spec, config)
         if need is not None and need > FUSED_VMEM_BUDGET:
             return False, (f"config [{config.key()}] working set "
                            f"{need / 2**20:.1f} MB > "
@@ -373,10 +347,8 @@ class Executor:
         bias/ReLU epilogue — and any cross-layer fusion the spec
         carries (residual ``addend``, trailing pool) — as XLA ops after
         the bare conv; ``fuses_epilogue`` executors absorb everything
-        in-kernel.  ``config`` is the plan's resolved launch config;
-        executors whose ``_execute`` predates the config/fusion era
-        (5-argument third-party subclasses) are called without the
-        newer kwargs.  ``quant`` is the quantization payload (calibrated
+        in-kernel.  ``config`` is the plan's resolved launch config.
+        ``quant`` is the quantization payload (calibrated
         activation scale) ConvPlan forwards on int8 plans — ignored
         here; int8-declaring executors override ``execute`` and consume
         it.
@@ -390,28 +362,20 @@ class Executor:
             raise ValueError(f"fused-add spec {spec.key()} needs an addend")
         if addend is not None and addend.dtype != dtype:
             addend = addend.astype(dtype)
-        kwargs = {}
-        if _accepts_kwarg(type(self)._execute, "config"):
-            kwargs["config"] = LaunchConfig.of(config)
-        if addend is not None and self.fuses_epilogue:
-            if not _accepts_kwarg(type(self)._execute, "addend"):
-                raise TypeError(
-                    f"executor {self.name!r} declares the 'add' fusion but "
-                    f"its _execute takes no addend kwarg")
-            kwargs["addend"] = addend
-        y = self._execute(spec, x, w, bias, interpret, **kwargs)
+        config = LaunchConfig.of(config)
         if not self.fuses_epilogue:
-            y = _xla_epilogue(spec, y, bias, addend)
-        return y
+            y = self._execute(spec, x, w, bias, interpret, config=config)
+            return _xla_epilogue(spec, y, bias, addend)
+        # only executors declaring the "add" fusion are handed an addend
+        kwargs = {} if addend is None else {"addend": addend}
+        return self._execute(spec, x, w, bias, interpret, config=config,
+                             **kwargs)
 
-    def _execute(self, spec, x, w, bias, interpret):
-        kwargs = {}
-        if self.takes_interpret:
-            kwargs["interpret"] = interpret
-        if spec.groups != 1:
-            kwargs["groups"] = spec.groups
-        return self.fn(x, w, stride=spec.stride, padding=spec.padding,
-                       **kwargs)
+    def _execute(self, spec, x, w, bias, interpret, config=None):
+        """The conv itself under ``config``; ``fuses_epilogue``
+        executors also take ``addend=`` and apply the whole epilogue.
+        Every executor overrides this (``register`` checks)."""
+        raise NotImplementedError
 
     def __repr__(self):
         return (f"<Executor {self.name} dtypes={self.dtypes} "
@@ -460,11 +424,9 @@ def register(executor: Executor) -> Executor:
     if name in _REGISTRY:
         raise ValueError(f"executor {name!r} already registered; "
                          f"unregister it first to replace it")
-    if executor.fn is None and type(executor)._execute is Executor._execute:
-        # fail at registration, not deep inside a jitted trace when the
-        # default _execute calls a None fn
-        raise ValueError(f"executor {name!r} must set `fn` or override "
-                         f"`_execute`")
+    if type(executor)._execute is Executor._execute:
+        # fail at registration, not deep inside a jitted trace
+        raise ValueError(f"executor {name!r} must override `_execute`")
     _REGISTRY[name] = executor
     return executor
 
@@ -503,39 +465,6 @@ def names() -> Tuple[str, ...]:
 def registered() -> Dict[str, Executor]:
     """Snapshot of the registry (mutating it does not unregister)."""
     return dict(_REGISTRY)
-
-
-class _AlgorithmsView(_MappingABC):
-    """Read-only ``{name: bare conv callable}`` view of the registry —
-    the pre-registry ``cuconv.ALGORITHMS`` surface, kept for callers
-    that time or compose the raw executor functions.  Executors that
-    expose no bare callable (``fn is None`` — legal for third-party
-    entries that only implement ``_execute``) are simply absent from
-    the view, keeping the Mapping contract (iteration never yields a
-    key that ``[]`` would refuse)."""
-
-    def __getitem__(self, name: str) -> Callable:
-        fn = get(name).fn
-        if fn is None:
-            raise KeyError(f"executor {name!r} exposes no bare callable")
-        return fn
-
-    def __iter__(self):
-        return (n for n, e in _REGISTRY.items() if e.fn is not None)
-
-    def __len__(self):
-        return sum(1 for e in _REGISTRY.values() if e.fn is not None)
-
-    def __repr__(self):
-        return f"ALGORITHMS({', '.join(self)})"
-
-
-#: back-compat mapping (``from repro.core import ALGORITHMS``)
-ALGORITHMS = _AlgorithmsView()
-
-
-def algorithms() -> _AlgorithmsView:
-    return ALGORITHMS
 
 
 # ---------------------------------------------------------------------------
@@ -602,100 +531,23 @@ class LaxExecutor(Executor):
         if spec.groups != 1:
             return 95, (f"grouped conv (groups={spec.groups}): library "
                         f"feature_group_count")
+        if backend != "tpu":
+            # the Pallas kernels would run in interpret mode
+            return 40, "off TPU: library conv"
         if not spec.unit_stride:
             # a low claim: any capable kernel claiming the strided
             # region outranks it, so winning here means nothing else did
-            return 40, ("strided conv: library kernel off-TPU"
-                        if backend != "tpu"
-                        else "strided conv: library kernel "
-                        "(no higher-priority claim)")
+            return 40, "strided conv: library kernel (no higher-priority claim)"
         return None
 
-    def _execute(self, spec, x, w, bias, interpret):
+    def _execute(self, spec, x, w, bias, interpret, config=None):
         from repro.core import cuconv
         return cuconv.conv_lax(x, w, stride=spec.stride,
                                padding=spec.padding, groups=spec.groups)
 
 
-class Im2colExecutor(Executor):
-    """Explicit patch matrix + one GEMM (cuDNN "GEMM" variant); pays
-    KH*KW-fold input duplication through HBM."""
-    name = "im2col"
-
-    def extra_hbm_bytes(self, spec):
-        n, oh, ow, _ = spec.out_shape
-        kh, kw, cpg, _ = spec.filter_shape
-        itemsize = jnp.dtype(spec.dtype).itemsize
-        # patch matrix written then re-read by the GEMM
-        return 2.0 * n * oh * ow * kh * kw * cpg * itemsize
-
-
-class WinogradExecutor(Executor):
-    """F(2x2, 3x3) minimal filtering — the paper's strongest competitor
-    in the large-3x3 region."""
-    name = "winograd"
-
-    def _supports(self, spec):
-        if spec.filter_shape[:2] != (3, 3) or not spec.unit_stride:
-            return False, "Winograd F(2x2,3x3) needs 3x3 stride-1"
-        return True, "3x3 stride-1: Winograd region"
-
-    def heuristic_claim(self, spec, backend):
-        if not _is_small(spec):
-            return 70, "large 3x3: Winograd region in the paper"
-        return None
-
-    def flop_cost(self, spec):
-        # 2.25x fewer multiplies than direct (the traffic penalty from
-        # extra_hbm_bytes rides on top, undivided)
-        return super().flop_cost(spec) / 2.25
-
-    def extra_hbm_bytes(self, spec):
-        n, oh, ow, m = spec.out_shape
-        c = spec.in_shape[3]
-        itemsize = jnp.dtype(spec.dtype).itemsize
-        # 16 positions per 2x2 output block: the gathered input tiles /
-        # written output tiles transit at the spec dtype; the Winograd-
-        # domain tensors (V, M) genuinely stay f32 (4 bytes)
-        tiles = n * ((oh + 1) // 2) * ((ow + 1) // 2) * 16
-        return tiles * (c + m) * (itemsize + 4.0)
-
-    def _execute(self, spec, x, w, bias, interpret):
-        from repro.core.winograd import conv_winograd
-        return conv_winograd(x, w, 1, spec.padding)
-
-
-class TwoStageExecutor(Executor):
-    """Faithful paper pipeline (XLA): stage-1 temporaries materialized
-    (KH*KW, N, OH, OW, M), stage-2 sum."""
-    name = "cuconv_two_stage"
-
-    def extra_hbm_bytes(self, spec):
-        n, oh, ow, m = spec.out_shape
-        kh, kw = spec.filter_shape[:2]
-        # f32 temporaries written by stage 1, re-read by stage 2
-        return 2.0 * kh * kw * n * oh * ow * m * 4
-
-
-class CuconvExecutor(Executor):
-    """Beyond-paper fused tap accumulation (XLA, no temporaries) — the
-    paper's "work-fusion" future work realized."""
-    name = "cuconv"
-
-    def heuristic_claim(self, spec, backend):
-        if not spec.unit_stride:
-            return None
-        if spec.is_1x1:
-            return 60, "1x1: single GEMM, no stage 2 (best region)"
-        if _is_small(spec):
-            return 60, "small batch/spatial: cuConv region"
-        if spec.filter_shape[:2] == (3, 3):
-            return None                    # Winograd's region in the paper
-        return 20, "default cuConv region"
-
-
-# Tiled-GEMM launch candidates shared by the 1x1 and two-stage Pallas
-# kernels: (tp, tm, tc) = pixel / out-channel / contraction tiles.
+# Tiled-GEMM launch candidates of the int8 GEMM kernel: (tp, tm, tc) =
+# pixel / out-channel / contraction tiles.
 # Candidate 0 is the historical hard-coded geometry; the rest widen or
 # shrink each axis (clamped per spec, so small paper shapes dedupe).
 _GEMM_TILES = (
@@ -736,90 +588,6 @@ def _gemm_tile_steps(p: int, m: int, c: int, config: LaunchConfig) -> float:
     tm = min(config.get("tm", 128), m)
     tc = min(config.get("tc", 512), c)
     return (-(-p // tp)) * (-(-m // tm)) * (-(-c // tc))
-
-
-class Conv1x1PallasExecutor(Executor):
-    """Dedicated 1x1 GEMM Pallas kernel: all N*H*W pixels MXU-tiled —
-    the paper's best-case region on its natural kernel."""
-    name = "conv1x1_pallas"
-    takes_interpret = True
-    tunable = ("tp", "tm", "tc")
-
-    def _supports(self, spec):
-        if (not spec.is_1x1 or not spec.unit_stride
-                or spec.padding != (0, 0)):
-            return False, "conv1x1 kernel needs 1x1 filter, stride 1, pad 0"
-        return True, "1x1 GEMM kernel (all pixels MXU-tiled)"
-
-    def heuristic_claim(self, spec, backend):
-        if backend == "tpu" and spec.epilogue == "none":
-            # no epilogue to fuse: this kernel tiles all N*H*W pixels
-            # onto the MXU (the fused kernel only fills OW rows per step)
-            return 90, "1x1: dedicated GEMM kernel"
-        return None
-
-    def _gemm_dims(self, spec):
-        n, h, w, c = spec.in_shape
-        return n * h * w, spec.filter_shape[3], c
-
-    def configs(self, spec):
-        return _gemm_tile_configs(*self._gemm_dims(spec))
-
-    def vmem_bytes(self, spec, config=None):
-        return _gemm_tile_vmem(LaunchConfig.of(config),
-                               jnp.dtype(spec.dtype).itemsize)
-
-    def config_cost(self, spec, config):
-        return _gemm_tile_steps(*self._gemm_dims(spec), config)
-
-    def _execute(self, spec, x, w, bias, interpret, config=None):
-        from repro.kernels import ops
-        cfg = LaunchConfig.of(config)
-        return ops.conv1x1(x, w, interpret=interpret,
-                           tp=cfg.get("tp", 256), tm=cfg.get("tm", 128),
-                           tc=cfg.get("tc", 512))
-
-
-class TwoStagePallasExecutor(Executor):
-    """Faithful two-kernel Pallas pipeline (stride 1): HBM temporaries +
-    stage-2 sum — the fused kernel's VMEM-bounded fallback."""
-    name = "cuconv_two_stage_pallas"
-    takes_interpret = True
-    tunable = ("tp", "tm", "tc")
-
-    def _supports(self, spec):
-        if not spec.unit_stride:
-            return False, "two-stage Pallas kernels are stride-1 only"
-        return True, "two-stage Pallas pipeline (bounded VMEM)"
-
-    def extra_hbm_bytes(self, spec):
-        n, oh, ow, m = spec.out_shape
-        kh, kw = spec.filter_shape[:2]
-        return 2.0 * kh * kw * n * oh * ow * m * 4
-
-    def _gemm_dims(self, spec):
-        n, oh, ow, m = spec.out_shape
-        return n * oh * ow, m, spec.filter_shape[2]
-
-    def configs(self, spec):
-        return _gemm_tile_configs(*self._gemm_dims(spec))
-
-    def vmem_bytes(self, spec, config=None):
-        return _gemm_tile_vmem(LaunchConfig.of(config),
-                               jnp.dtype(spec.dtype).itemsize)
-
-    def config_cost(self, spec, config):
-        p, m, c = self._gemm_dims(spec)
-        kh, kw = spec.filter_shape[:2]
-        return kh * kw * _gemm_tile_steps(p, m, c, config)
-
-    def _execute(self, spec, x, w, bias, interpret, config=None):
-        from repro.kernels import ops
-        cfg = LaunchConfig.of(config)
-        return ops.cuconv_two_stage(x, w, spec.padding, interpret=interpret,
-                                    tp=cfg.get("tp", 256),
-                                    tm=cfg.get("tm", 128),
-                                    tc=cfg.get("tc", 512))
 
 
 class FusedPallasExecutor(Executor):
@@ -946,14 +714,6 @@ class FusedPallasExecutor(Executor):
             return 80, "small batch/spatial: cuConv region"
         return None
 
-    def fallback(self, spec):
-        if spec.unit_stride:
-            # the old kernels/ops.py behaviour: oversized rows take the
-            # two-stage Pallas kernels (HBM temporaries, bounded VMEM)
-            return ("cuconv_two_stage_pallas",
-                    "two-stage kernels bound the VMEM working set")
-        return "cuconv", "fused-tap XLA path handles any stride"
-
     def _execute(self, spec, x, w, bias, interpret, config=None,
                  addend=None):
         # epilogue fused into the kernel: the accumulator takes
@@ -1010,9 +770,8 @@ class WinogradPallasExecutor(Executor):
     """Tiled Pallas Winograd F(m,3): the whole Winograd domain —
     tile gather, B^T d B transform, per-position channel GEMMs, fp32
     accumulator, A^T m A inverse, bias/ReLU/residual epilogue — lives
-    in VMEM inside one kernel (kernels/winograd_pallas.py), where the
-    pure-jnp ``winograd`` executor round-trips every domain tensor
-    through HBM.
+    in VMEM inside one kernel (kernels/winograd_pallas.py); the pure-jnp
+    ``core.winograd.conv_winograd`` is the reference for its transforms.
 
     Tuning space: ``m`` (the F(m,3) variant — F(2x2,3x3) with 16 tile
     positions and 2.25x multiply savings, or F(4x4,3x3) with 36
@@ -1151,92 +910,6 @@ class WinogradPallasExecutor(Executor):
             interpret=interpret)
 
 
-# Direct-conv launch candidates: (tm, tc) output/input channel tiles.
-# Candidate 0 is the kernel's shipped default geometry.
-_DIRECT_TILES = (
-    (128, 256),
-    (128, 128),
-    (256, 128),
-    (128, 512),
-    (256, 256),
-    (64, 64),
-    (512, 128),
-)
-
-
-class DirectConvExecutor(Executor):
-    """Im2col-free direct conv (Li et al. 1610.03618): channel-tiled
-    fp32 VMEM accumulation, KH*KW taps unrolled in-kernel, no patch
-    matrix and no per-tap HBM temporaries (kernels/direct_conv.py).
-
-    Because the contraction is grid-tiled by ``tc``, the VMEM working
-    set is bounded for arbitrarily large C — the memory-efficiency
-    lever that makes this the registry's large-C backstop where the
-    patch matrix (im2col) and full-C row staging (fused kernel) both
-    blow up.  ``extra_hbm_bytes`` is near zero by construction: the
-    only re-traffic is re-reading the input once per output-channel
-    tile.
-    """
-    name = "direct"
-    takes_interpret = True
-    tunable = ("tm", "tc")
-
-    def _supports(self, spec):
-        if not any(self.config_supports(spec, c)[0]
-                   for c in self.configs(spec)):
-            return False, ("no channel-tiled candidate fits the VMEM "
-                           "budget (spatial staging too large)")
-        return True, "im2col-free direct conv (channel-tiled VMEM)"
-
-    def configs(self, spec):
-        m, c = spec.filter_shape[3], spec.filter_shape[2]
-        return _dedup_configs(({"tm": min(tm, m), "tc": min(tc, c)}
-                               for tm, tc in _DIRECT_TILES),
-                              {"tm": m, "tc": c})
-
-    def vmem_bytes(self, spec, config=None):
-        from repro.kernels.direct_conv import vmem_bytes
-        cfg = LaunchConfig.of(config)
-        return vmem_bytes(spec.in_shape, spec.filter_shape,
-                          stride=spec.stride, pad=spec.padding,
-                          tm=cfg.get("tm", 128), tc=cfg.get("tc", 256),
-                          itemsize=jnp.dtype(spec.dtype).itemsize)
-
-    def config_cost(self, spec, config):
-        n = spec.in_shape[0]
-        kh, kw, c, m = spec.filter_shape
-        tm = min(config.get("tm", 128), m)
-        tc = min(config.get("tc", 256), c)
-        return n * (-(-m // tm)) * (-(-c // tc)) * kh * kw
-
-    def extra_hbm_bytes(self, spec):
-        n, h, w_, c = spec.in_shape
-        itemsize = jnp.dtype(spec.dtype).itemsize
-        # the input is re-read once per output-channel tile beyond the
-        # first (default tm=128) — the whole im2col-free saving
-        retiles = -(-spec.filter_shape[3] // 128) - 1
-        return float(retiles * n * h * w_ * c * itemsize)
-
-    def heuristic_claim(self, spec, backend):
-        if backend != "tpu" or spec.has_fusion or spec.is_1x1:
-            return None
-        if spec.filter_shape[2] >= 256:
-            # a modest claim: wins the large-C region exactly where no
-            # higher-priority kernel claims (e.g. the fused kernel's
-            # full-C staging refused on VMEM, or large-C strided/5x5
-            # shapes), the memory-bound frontier of Li et al.
-            return 45, "large-C: im2col-free direct path (Li et al.)"
-        return None
-
-    def _execute(self, spec, x, w, bias, interpret, config=None):
-        from repro.kernels import ops
-        cfg = LaunchConfig.of(config)
-        return ops.direct_conv(x, w, spec.padding, stride=spec.stride,
-                               tm=cfg.get("tm", 128),
-                               tc=cfg.get("tc", 256),
-                               interpret=interpret)
-
-
 class Int8PallasExecutor(Executor):
     """Int8 inference executor: symmetric quantization in, int8 x int8
     -> **int32** accumulation on the MXU integer path, fp32
@@ -1258,7 +931,7 @@ class Int8PallasExecutor(Executor):
     at fp32 — identical shapes and operand dtypes to the fp executors,
     so quantized nodes drop into any graph position.
 
-    Tuning space: the shared tiled-GEMM tiles over the im2col dims
+    Tuning space: the tiled-GEMM tiles over the im2col dims
     (N*OH*OW, M, KH*KW*C); int8 tiles are a quarter the bytes of f32,
     so bigger blocks stay VMEM-feasible — the throughput lever the
     ROADMAP's int8 item names.
@@ -1433,26 +1106,12 @@ class DepthwisePallasExecutor(Executor):
 
 
 def _register_builtins() -> None:
-    # registration order == the historical ALGORITHMS order (iteration
-    # order is visible to autotune candidates and the quickstart)
-    from repro.core import cuconv
-    for ex, fn in (
-            (LaxExecutor(), cuconv.conv_lax),
-            (Im2colExecutor(), cuconv.conv_im2col),
-            (WinogradExecutor(), cuconv.conv_winograd_or_fallback),
-            (TwoStageExecutor(), cuconv.conv_cuconv_two_stage),
-            (Conv1x1PallasExecutor(), cuconv.conv_conv1x1_pallas),
-            (TwoStagePallasExecutor(), cuconv.conv_cuconv_two_stage_pallas),
-            (CuconvExecutor(), cuconv.conv_cuconv),
-            (FusedPallasExecutor(), cuconv.conv_cuconv_pallas),
-            (WinogradPallasExecutor(), cuconv.conv_winograd_pallas),
-            (DirectConvExecutor(), cuconv.conv_direct)):
-        ex.fn = fn
+    # registration order breaks negotiation ties (the first-registered
+    # claim or cost wins) and orders the autotuner's candidates
+    for ex in (LaxExecutor(), FusedPallasExecutor(),
+               WinogradPallasExecutor(), Int8PallasExecutor(),
+               DepthwisePallasExecutor()):
         register(ex)
-    # no bare-fn surface: the quantize/dequantize epilogue only makes
-    # sense through ConvPlan (the registered-executor path)
-    register(Int8PallasExecutor())
-    register(DepthwisePallasExecutor())
 
 
 _register_builtins()

@@ -1,10 +1,9 @@
 """Int8 x int8 -> int32 tiled GEMM — the quantized inference fast path.
 
-Same structure as ``conv1x1.py``'s pixels-major GEMM (all three dims
-tiled to VMEM blocks, contraction grid dim innermost, accumulator in
-VMEM scratch across C-revisits), but the operands are int8 and the
-accumulator is **int32**: ``preferred_element_type=jnp.int32`` drives
-the MXU's integer path, which is the "roughly double arithmetic
+A pixels-major GEMM (all three dims tiled to VMEM blocks, contraction
+grid dim innermost, accumulator in VMEM scratch across C-revisits)
+over int8 operands with an **int32** accumulator:
+``preferred_element_type=jnp.int32`` drives the MXU's integer path, which is the "roughly double arithmetic
 throughput" lever the ROADMAP names — int8 tiles are a quarter the
 bytes of f32, so the same VMEM budget holds 4x the tile footprint and
 the MXU runs its 8-bit mode.

@@ -12,16 +12,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import (conv1x1 as _c1, cuconv_stage1 as _s1,
-                           cuconv_stage2 as _s2, cuconv_fused as _cf,
-                           conv1d_tap as _c1d, depthwise_tap as _dw,
-                           direct_conv as _dcv,
-                           flash_attention as _fa, int8_gemm as _i8,
-                           winograd_pallas as _wg)
-
-
+from repro.kernels import (cuconv_fused as _cf, conv1d_tap as _c1d,
+                           depthwise_tap as _dw, flash_attention as _fa,
+                           int8_gemm as _i8, winograd_pallas as _wg)
 from repro.core.convspec import normalize_stride as _norm_stride  # one home
-from repro.kernels._compat import clamp_tiles  # noqa: F401  (re-export)
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
@@ -30,51 +24,11 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def conv1x1(x, w, interpret=None, tp=256, tm=128, tc=512):
-    """x: (N, H, W, C); w: (1, 1, C, M) or (C, M).
-
-    ``tp/tm/tc`` are the GEMM launch tiles (pixels/out-channels/
-    contraction); the defaults are the historical hard-coded geometry.
-    """
-    if w.ndim == 4:
-        w = w[0, 0]
-    N, H, W_, C = x.shape
-    out = _c1.conv1x1_gemm(x.reshape(N * H * W_, C), w, tp=tp, tm=tm, tc=tc,
-                           interpret=_auto_interpret(interpret))
-    return out.reshape(N, H, W_, -1)
-
-
 def int8_gemm(x2d, w, interpret=None, tp=256, tm=128, tc=512):
     """x2d: (P, C) int8; w: (C, M) int8.  Returns (P, M) **int32** — the
     raw accumulator; dequantization is the int8 executor's epilogue."""
     return _i8.int8_gemm(x2d, w, tp=tp, tm=tm, tc=tc,
                          interpret=_auto_interpret(interpret))
-
-
-def cuconv_two_stage(x, w, padding=(0, 0), interpret=None,
-                     tp=256, tm=128, tc=512):
-    """Faithful two-kernel cuConv (stride 1): HBM temporaries + sum.
-
-    Policy-free executor: which inputs take this path (vs the fused or
-    1x1 kernels) is decided by core.convspec.plan, not here.
-    ``tp/tm/tc`` thread the launch tiles into stage 1; stage 2 rides the
-    same pixel tile but keeps its own out-channel tile default (it is a
-    bandwidth-bound reduction — 1-9 % of total time in the paper — and
-    its historical default differs from stage 1's).
-    """
-    from repro.core.cuconv import _tap_views  # shared view builder
-    interp = _auto_interpret(interpret)
-    N, H, W_, C = x.shape
-    KH, KW, _, M = w.shape
-    ph, pw = padding
-    xp = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
-    OH, OW = H + 2 * ph - KH + 1, W_ + 2 * pw - KW + 1
-    views = _tap_views(xp, KH, KW, OH, OW, 1)
-    xs = jnp.stack([v.reshape(N * OH * OW, C) for v in views], 0)
-    temps = _s1.stage1_tap_gemm(xs, w.reshape(KH * KW, C, M),
-                                tp=tp, tm=tm, tc=tc, interpret=interp)
-    out = _s2.stage2_tap_sum(temps, tp=tp, interpret=interp)
-    return out.reshape(N, OH, OW, M).astype(x.dtype)
 
 
 def cuconv_fused(x, w, padding=(0, 0), stride=1, bias=None, activation=None,
@@ -113,16 +67,6 @@ def winograd_fused(x, w, padding=(1, 1), bias=None, activation=None,
                               activation=activation, addend=addend,
                               m=m, rows=rows, tm=tm, tc=tc,
                               interpret=_auto_interpret(interpret))
-
-
-def direct_conv(x, w, padding=(0, 0), stride=(1, 1), tm=128, tc=256,
-                interpret=None):
-    """Im2col-free direct conv (Li et al. 1610.03618): channel-tiled
-    fp32 VMEM accumulation, no patch-matrix materialization.  Any
-    stride; ``tm/tc`` are the direct executor's launch config."""
-    return _dcv.direct_conv(x, w, tuple(padding), _norm_stride(stride),
-                            tm=tm, tc=tc,
-                            interpret=_auto_interpret(interpret))
 
 
 def depthwise_conv(x, w, padding=(0, 0), bias=None, relu=False, nb=1,

@@ -20,20 +20,6 @@ def conv2d_pad_ref(x, w, padding=(0, 0)):
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
-def conv1x1_ref(x2d, w):
-    return (x2d.astype(jnp.float32) @ w.astype(jnp.float32)).astype(x2d.dtype)
-
-
-def stage1_ref(xs, w):
-    """xs: (T, P, C); w: (T, C, M) -> (T, P, M) f32."""
-    return jnp.einsum("tpc,tcm->tpm", xs.astype(jnp.float32),
-                      w.astype(jnp.float32))
-
-
-def stage2_ref(temps):
-    return jnp.sum(temps.astype(jnp.float32), axis=0)
-
-
 def conv1d_ref(x, w, b=None):
     """Causal depthwise conv1d.  x: (B, L, D); w: (K, D)."""
     K = w.shape[0]

@@ -238,10 +238,10 @@ def squeezenet_like():
 def tiny_cnn(num_classes: int = 3):
     """The deliberately tiny two-conv stack the multi-device smoke
     deployment serves (configs/serve.py DIST_SMOKE): per-image compute
-    small enough that CPU-CI scaling runs are dominated by the fixed
-    per-batch scheduling cost the device-count-aware buckets amortize —
-    the same model tests/benchmarks share so the scaling and bitwise
-    records describe one named deployment."""
+    small enough that CPU-CI runs are dominated by the fixed per-batch
+    scheduling cost the device-count-aware buckets amortize — the same
+    model the distributed-serve tests use, so their bitwise checks
+    describe one named deployment."""
     return SimpleCNN([(3, 3, 6, 2), (1, 1, 4, 1)],
                      num_classes=num_classes)
 

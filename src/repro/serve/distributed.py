@@ -39,10 +39,7 @@ and *hosts* without changing what a bucket program is:
 
 On CPU CI the whole subsystem runs under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` forced host
-devices: throughput in BENCH_graph_serve.json scales near-linearly with
-the device count because the global buckets grow with the mesh while
-the per-batch scheduling cost does not (benchmarks/loadgen.py writes
-the ``sharded_scaling`` record).
+devices (tests/test_distributed_serve.py).
 """
 from __future__ import annotations
 

@@ -68,8 +68,7 @@ scheduler in front of them:
   clock reads per batch.  ``stats()`` exposes p50/p95/p99 rollups per
   request stage (``latency_ms``) and per batch span (``batch_ms``),
   deadline misses, and overlap counters; ``telemetry.spans()`` lists
-  the spans for a trace reduction, and ``benchmarks/graph_serve.py``
-  writes the rollups into ``BENCH_graph_serve.json``.
+  the spans for a trace reduction (``bench/attribution.py``).
 
 The scheduler is single-threaded and clock-injected (``clock=``): JAX's
 async dispatch provides the device-side concurrency, so behaviour is

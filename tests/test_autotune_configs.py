@@ -53,14 +53,13 @@ GEOMS = [
 ]
 
 # the paper's profiled configurations each Pallas executor must expose
-# a real tuning space on (table 3 A for the 1x1 kernel, table 4 A/B for
-# the KxK kernels)
+# a real tuning space on (table 3 A for a 1x1 layer, table 4 A/B for
+# the KxK layers)
 T3_A = cs.ConvSpec((1, 7, 7, 832), (1, 1, 832, 256))
 T4_A = cs.ConvSpec((1, 7, 7, 192), (3, 3, 192, 384), (1, 1), (1, 1))
 T4_B = cs.ConvSpec((1, 13, 13, 384), (3, 3, 384, 384), (1, 1), (1, 1))
 
-PALLAS = ("cuconv_pallas", "cuconv_two_stage_pallas", "conv1x1_pallas",
-          "winograd_pallas", "direct")
+PALLAS = ("cuconv_pallas", "winograd_pallas")
 
 
 def _spec(geom, dtype="float32"):
@@ -98,25 +97,19 @@ def test_candidate_zero_is_the_historical_geometry():
     geometry (clamped to the spec), so nothing regresses by default."""
     fused = ex.get("cuconv_pallas").configs(T4_A)[0]
     assert fused.as_dict() == {"tm": 128, "rows": 1}
-    ts = ex.get("cuconv_two_stage_pallas").configs(T4_A)[0]
-    assert ts.as_dict() == {"tp": 49, "tm": 128, "tc": 192}   # tp clamped
-    one = ex.get("conv1x1_pallas").configs(T3_A)[0]
-    assert one.as_dict() == {"tp": 49, "tm": 128, "tc": 512}
+    # the int8 GEMM's candidate 0: the default tiles, tp clamped to
+    # N*OH*OW and tc to KH*KW*C
+    i8 = ex.get("cuconv_int8").configs(T4_A)[0]
+    assert i8.as_dict() == {"tp": 49, "tm": 128, "tc": 512}
     # winograd_pallas candidate 0 is the F(2,3) variant at the default
     # tiles (rows clamped to the spec's tile rows: ceil(7/2) = 4)
     wg = ex.get("winograd_pallas").configs(T4_A)[0]
     assert wg.as_dict() == {"m": 2, "rows": 4, "tm": 128, "tc": 128}
-    # direct candidate 0: default (tm, tc) clamped to (M, C)
-    dc = ex.get("direct").configs(T4_A)[0]
-    assert dc.as_dict() == {"tm": 128, "tc": 192}
 
 
 @pytest.mark.parametrize("name,spec", [
     ("cuconv_pallas", T4_A), ("cuconv_pallas", T4_B),
-    ("cuconv_two_stage_pallas", T4_A), ("cuconv_two_stage_pallas", T4_B),
-    ("conv1x1_pallas", T3_A),
     ("winograd_pallas", T4_A), ("winograd_pallas", T4_B),
-    ("direct", T4_B), ("direct", T3_A),
 ])
 def test_pallas_executors_expose_three_feasible_candidates(name, spec):
     """Acceptance: >= 3 VMEM-feasible candidate configs per Pallas
@@ -131,7 +124,7 @@ def test_pallas_executors_expose_three_feasible_candidates(name, spec):
 
 
 def test_untunable_executors_have_one_empty_config():
-    for name in ("lax", "im2col", "winograd", "cuconv", "cuconv_two_stage"):
+    for name in ("lax",):
         exe = ex.get(name)
         assert exe.tunable == ()
         (only,) = exe.configs(T4_A)
@@ -223,10 +216,6 @@ def test_forced_infeasible_config_raises_for_new_executors():
     with pytest.raises(ValueError, match="tm=256"):
         cs.plan(T4_B, force="winograd_pallas",
                 config={"m": 2, "rows": 4, "tm": 256, "tc": 128})
-    with pytest.raises(ValueError) as e:
-        cs.plan(T4_B, force="direct", config={"tm": 512, "tc": 512})
-    msg = str(e.value)
-    assert "direct" in msg and "VMEM" in msg and T4_B.key() in msg
 
 
 def test_forced_valid_config_rides_the_plan(rng):
@@ -260,7 +249,6 @@ def test_stale_persisted_config_is_reresolved_not_served():
 @pytest.mark.parametrize("name,spec,stale", [
     ("winograd_pallas", T4_A, {"m": 3, "rows": 4, "tm": 128, "tc": 128}),
     ("winograd_pallas", T4_B, {"m": 4, "rows": 4, "tm": 128, "tc": 1024}),
-    ("direct", T4_B, {"tm": 512, "tc": 512}),
 ])
 def test_stale_persisted_config_self_heals_for_new_executors(name, spec,
                                                              stale):
@@ -305,12 +293,12 @@ def test_unversioned_and_foreign_schema_entries_are_dropped():
     are never misdecoded into the (algorithm, config) shape."""
     spec = _spec(GEOMS[0])
     key = autotune._key(spec, "cpu")
-    autotune._STORE.put(key, "cuconv")                  # v1: bare string
+    autotune._STORE.put(key, "lax")                     # v1: bare string
     assert autotune.cached_best(spec, "cpu") is None
     assert autotune.cached_config(spec, "cpu") is None
-    autotune._STORE.put(key, {"schema": 99, "algorithm": "cuconv"})
+    autotune._STORE.put(key, {"schema": 99, "algorithm": "lax"})
     assert autotune.cached_best(spec, "cpu") is None
-    autotune._STORE.put(key, {"algorithm": "cuconv"})   # unversioned dict
+    autotune._STORE.put(key, {"algorithm": "lax"})      # unversioned dict
     assert autotune.cached_best(spec, "cpu") is None
     # plan() falls back to the heuristic tier, not a misdecoded entry
     assert cs.plan(spec, backend="cpu").source in ("heuristic", "cost")
@@ -346,7 +334,7 @@ def test_forced_tune_never_overwrites_the_measured_winner(rng):
     cs.plan(spec, tune="algo")                  # real executor sweep
     winner = autotune.cached_best(spec)
     assert winner is not None
-    forced = "conv1x1_pallas" if winner != "conv1x1_pallas" else "lax"
+    forced = "cuconv_pallas" if winner != "cuconv_pallas" else "lax"
     p = cs.plan(spec, force=forced, tune="full")
     assert p.algorithm == forced
     # the unforced plan still serves the measured winner, not the
@@ -443,38 +431,6 @@ def test_measure_config_short_circuits_on_valid_persisted_config(rng):
     assert autotune.MEASURE_STATS["timed_calls"] > 0
 
 
-class _OldStyleExecutor(ex.Executor):
-    """A PR4-era third-party executor: pre-config signatures everywhere
-    (5-argument _execute, vmem_bytes(self, spec)) and no tuning space."""
-    name = "old_style_plugin"
-
-    def vmem_bytes(self, spec):
-        return 1024
-
-    def _execute(self, spec, x, w, bias, interpret):
-        return cc.conv_lax(x, w, stride=spec.stride, padding=spec.padding)
-
-
-def test_pre_config_executor_signatures_still_work(rng):
-    """Old-signature plugins participate in plans and sweeps untuned —
-    never crash with a TypeError from the config plumbing."""
-    ex.register(_OldStyleExecutor())
-    try:
-        spec = _spec(GEOMS[0])
-        p = cs.plan(spec, force="old_style_plugin")
-        assert p.algorithm == "old_style_plugin"
-        x, w, b = _operands(spec, rng)
-        np.testing.assert_allclose(np.asarray(p(x, w, b), np.float32),
-                                   _f32_ref(spec, x, w, b),
-                                   **TOLS["float32"])
-        best = autotune.measure_algorithm(
-            x, w, stride=spec.stride, padding=spec.padding, repeats=1,
-            candidates=("old_style_plugin", "lax"))
-        assert best in ("old_style_plugin", "lax")
-    finally:
-        ex.unregister("old_style_plugin")
-
-
 class _BrokenTuningExecutor(ex.Executor):
     """Registered executor whose tuning-space declarations raise."""
     name = "broken_tuning_plugin"
@@ -482,7 +438,7 @@ class _BrokenTuningExecutor(ex.Executor):
     def configs(self, spec):
         raise RuntimeError("broken tuning space")
 
-    def _execute(self, spec, x, w, bias, interpret):
+    def _execute(self, spec, x, w, bias, interpret, config=None):
         return cc.conv_lax(x, w, stride=spec.stride, padding=spec.padding)
 
 
@@ -544,8 +500,8 @@ def test_forced_tune_algo_still_runs_the_executor_sweep():
     runs and records the UNFORCED winner for later unforced plans."""
     spec = cs.ConvSpec((1, 6, 6, 8), (1, 1, 8, 4))
     autotune.reset_measure_stats()
-    p = cs.plan(spec, force="conv1x1_pallas", tune="algo")
-    assert p.algorithm == "conv1x1_pallas"      # the pin decides this plan
+    p = cs.plan(spec, force="cuconv_pallas", tune="algo")
+    assert p.algorithm == "cuconv_pallas"       # the pin decides this plan
     assert autotune.MEASURE_STATS["algo_sweeps"] == 1
     assert autotune.cached_best(spec) is not None
 

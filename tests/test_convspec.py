@@ -39,7 +39,7 @@ def _lax_ref(x, w, stride, padding, bias=None, relu=False):
 
 
 # ---------------------------------------------------------------------------
-# dispatch equivalence sweep: every algorithm x stride x padding x dtype x K
+# dispatch equivalence sweep: every executor x stride x padding x dtype x K
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("K", [1, 3, 5])
@@ -51,9 +51,7 @@ def test_all_algorithms_match_lax(rng, stride, padding, K, dtype):
     want = _lax_ref(x, w, stride, padding)
     tols = TOLS[str(jnp.dtype(dtype))]
     spec = cs.ConvSpec.for_conv(x, w, stride, padding)
-    for name in ["im2col", "cuconv_two_stage", "cuconv_two_stage_pallas",
-                 "conv1x1_pallas", "cuconv", "cuconv_pallas", "winograd",
-                 "lax"]:
+    for name in ex.names():
         if not cs.supports(name, spec)[0]:
             continue       # forcing would fall back: lax==lax proves nothing
         got = cc.conv2d(x, w, stride, padding, algorithm=name)
@@ -94,7 +92,10 @@ def test_fused_epilogue_matches_lax(rng, stride, dtype):
     b = _mk(rng, (4,), dtype)
     want = _lax_ref(x, w, stride, "same", bias=b, relu=True)
     tols = TOLS[str(jnp.dtype(dtype))]
-    for name in ["auto", "cuconv", "cuconv_pallas", "lax"]:
+    spec = cs.ConvSpec.for_conv(x, w, stride, "same", bias=b,
+                                activation="relu")
+    for name in ["auto"] + [n for n in ex.names()
+                            if cs.supports(n, spec)[0]]:
         got = cc.conv2d(x, w, stride, "same", algorithm=name,
                         bias=b, activation="relu")
         np.testing.assert_allclose(
@@ -115,21 +116,59 @@ def test_auto_routes_through_plan():
 
 
 def test_plan_respects_vmem_budget_fallback():
-    """Oversized fused working sets take the two-stage path (the guard
-    that used to live in kernels/ops.py — now the fused executor's own
-    capability declaration)."""
+    """Oversized fused working sets take the library conv (the fused
+    executor's own capability declaration refuses them), at any
+    stride."""
     spec = cs.ConvSpec((1, 8, 2100, 1024), (3, 3, 1024, 8),
                        stride=(1, 1), padding=(1, 1))
     assert ex.get("cuconv_pallas").vmem_bytes(spec) > ex.FUSED_VMEM_BUDGET
     p = cs.plan(spec, force="cuconv_pallas")
-    assert p.algorithm == "cuconv_two_stage_pallas"
+    assert p.algorithm == "lax"
     assert p.source == "fallback"
     assert "VMEM" in p.explain()
-    # strided oversized specs cannot take the stride-1 two-stage kernels
     sspec = cs.ConvSpec((1, 8, 4100, 1024), (3, 3, 1024, 8),
                         stride=(2, 2), padding=(1, 1))
     sp = cs.plan(sspec, force="cuconv_pallas")
-    assert sp.algorithm == "cuconv"
+    assert (sp.algorithm, sp.source) == ("lax", "fallback")
+
+
+_POOL = ("max", 2, 2, 2, 2, 0, 0)
+# (in_shape, stride, fused pool) of specs the fused kernel refuses once
+# the VMEM budget sits just under its default geometry's working set
+# (or, for the pool, under every multi-row blocking that covers it)
+_REFUSED = {
+    "stride1-over-vmem": ((1, 8, 8, 8), (1, 1), None),
+    "stride2-over-vmem": ((1, 9, 9, 8), (2, 2), None),
+    "pool-no-blocking": ((1, 8, 8, 8), (1, 1), _POOL),
+}
+
+
+@pytest.mark.parametrize("case", list(_REFUSED))
+def test_forced_fused_kernel_falls_back_to_lax(rng, monkeypatch, case):
+    """Forced onto a spec it refuses, cuconv_pallas takes the base
+    executor's declared fallback, the library conv, and the plan still
+    computes relu(conv + b) (then the pool)."""
+    import dataclasses
+    in_shape, stride, pool = _REFUSED[case]
+    spec = cs.ConvSpec(in_shape, (3, 3, 8, 4), stride, (1, 1),
+                       epilogue="bias_relu")
+    spec = dataclasses.replace(spec, fused_pool=pool)
+    fused = ex.get("cuconv_pallas")
+    monkeypatch.setattr(ex, "FUSED_VMEM_BUDGET",
+                        fused.vmem_bytes(spec) - (pool is None))
+    ok, why = fused.supports(spec)
+    assert not ok, spec.key()
+    p = cs.plan(spec, force="cuconv_pallas")
+    assert (p.algorithm, p.source) == ("lax", "fallback"), p.explain()
+    x = _mk(rng, in_shape, jnp.float32)
+    w = _mk(rng, spec.filter_shape, jnp.float32)
+    b = _mk(rng, (4,), jnp.float32)
+    want = _lax_ref(x, w, stride, (1, 1), bias=b, relu=True)
+    if pool is not None:
+        want = jax.lax.reduce_window(want, -jnp.inf, jax.lax.max,
+                                     (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    np.testing.assert_allclose(np.asarray(p(x, w, b)), np.asarray(want),
+                               **TOLS["float32"])
 
 
 def test_plan_fallback_is_numerically_correct(rng):
@@ -160,12 +199,13 @@ def test_forced_unsupported_lands_on_documented_fallbacks():
     """Every forced-but-unsupported algorithm takes _fallback_for's
     documented stand-in."""
     spec3 = cs.ConvSpec((1, 8, 8, 4), (3, 3, 4, 4), (1, 1), (1, 1))
-    p = cs.plan(spec3, force="conv1x1_pallas")        # needs 1x1
+    p = cs.plan(spec3, force="depthwise_tap")         # needs depthwise
     assert (p.source, p.algorithm) == ("fallback", "lax")
     strided = cs.ConvSpec((1, 8, 8, 4), (3, 3, 4, 4), (2, 2), (1, 1))
-    p = cs.plan(strided, force="cuconv_two_stage_pallas")  # stride-1 only
+    p = cs.plan(strided, force="winograd_pallas")     # 3x3 stride-1 only
     assert (p.source, p.algorithm) == ("fallback", "lax")
-    p = cs.plan(strided, force="winograd")            # 3x3 stride-1 only
+    one = cs.ConvSpec((1, 8, 8, 4), (1, 1, 4, 4))
+    p = cs.plan(one, force="winograd_pallas")         # 3x3 only
     assert (p.source, p.algorithm) == ("fallback", "lax")
 
 
@@ -207,21 +247,28 @@ def test_spec_key_stable_and_epilogue_sensitive():
 
 
 def test_heuristic_regions_via_plan():
-    """The paper's regions, now owned by plan() (CPU backend)."""
-    mk = lambda xs, ws, s: cs.plan(cs.ConvSpec(xs, ws, (s, s))).algorithm
-    assert mk((1, 7, 7, 832), (1, 1, 832, 256), 1) == "cuconv"
-    assert mk((64, 56, 56, 128), (3, 3, 128, 128), 1) == "winograd"
-    assert mk((1, 7, 7, 64), (3, 3, 64, 64), 2) == "lax"
+    """The paper's regions, owned by plan(): on a TPU the 1x1 / small
+    region takes the fused kernel, large 3x3 stride-1 the Winograd
+    kernel; off the TPU every spec takes the library conv."""
+    def mk(xs, ws, s, backend):
+        return cs.plan(cs.ConvSpec(xs, ws, (s, s), (ws[0] // 2,) * 2),
+                       backend=backend).algorithm
+    regions = {((1, 7, 7, 832), (1, 1, 832, 256), 1): "cuconv_pallas",
+               ((64, 56, 56, 128), (3, 3, 128, 128), 1): "winograd_pallas",
+               ((1, 7, 7, 64), (3, 3, 64, 64), 2): "cuconv_pallas"}
+    for (xs, ws, s), want in regions.items():
+        assert mk(xs, ws, s, "tpu") == want
+        assert mk(xs, ws, s, "cpu") == "lax"
 
 
 def test_tpu_backend_prefers_fused_kernel():
     spec = cs.ConvSpec((1, 7, 7, 192), (3, 3, 192, 384), (2, 2), (1, 1))
     p = cs.plan(spec, backend="tpu")
     assert p.algorithm == "cuconv_pallas"
-    # bare 1x1 takes the dedicated GEMM kernel; with an epilogue the
-    # fused kernel wins (epilogue applied in VMEM, no extra round trip)
+    # 1x1, bare or with an epilogue (applied in VMEM, no extra round
+    # trip), takes the fused kernel too
     one = cs.ConvSpec((1, 7, 7, 832), (1, 1, 832, 256))
-    assert cs.plan(one, backend="tpu").algorithm == "conv1x1_pallas"
+    assert cs.plan(one, backend="tpu").algorithm == "cuconv_pallas"
     one_epi = cs.ConvSpec((1, 7, 7, 832), (1, 1, 832, 256),
                           epilogue="bias_relu")
     assert cs.plan(one_epi, backend="tpu").algorithm == "cuconv_pallas"
@@ -237,8 +284,8 @@ def test_measured_cache_persists_across_reload(rng, tmp_path, monkeypatch):
     x = _mk(rng, (1, 6, 6, 8), jnp.float32)
     w = _mk(rng, (1, 1, 8, 4), jnp.float32)
     best = autotune.measure_algorithm(x, w, repeats=1,
-                                      candidates=("lax", "cuconv"))
-    assert best in ("lax", "cuconv")
+                                      candidates=("lax", "cuconv_pallas"))
+    assert best in ("lax", "cuconv_pallas")
     assert (tmp_path / "autotune.json").exists()
     # a fresh process (simulated by dropping the in-memory mirror) reads
     # the measured winner back and plan() serves it
@@ -259,7 +306,7 @@ def test_measured_winner_serves_epilogue_specs(rng, tmp_path, monkeypatch):
     x = _mk(rng, (1, 6, 6, 8), jnp.float32)
     w = _mk(rng, (3, 3, 8, 4), jnp.float32)
     best = autotune.measure_algorithm(x, w, repeats=1,
-                                      candidates=("lax", "cuconv"))
+                                      candidates=("lax", "cuconv_pallas"))
     spec = cs.ConvSpec.for_conv(x, w, 1, "same", bias=jnp.zeros((4,)),
                                 activation="relu")
     p = cs.plan(spec)
@@ -285,11 +332,12 @@ def test_measure_default_candidates_include_pallas(rng, tmp_path, monkeypatch):
     autotune.clear_cache()
     spec = cs.ConvSpec((1, 4, 4, 4), (1, 1, 4, 3))
     cands = set(autotune.default_candidates(spec))
-    assert {"cuconv_pallas", "conv1x1_pallas",
-            "cuconv_two_stage_pallas"} <= cands
+    assert cands == {"lax", "cuconv_pallas"}
     strided = cs.ConvSpec((1, 8, 8, 4), (3, 3, 4, 3), (2, 2), (1, 1))
-    assert "cuconv_two_stage_pallas" not in set(
+    assert "winograd_pallas" not in set(
         autotune.default_candidates(strided))
+    assert "winograd_pallas" in set(autotune.default_candidates(
+        cs.ConvSpec((1, 8, 8, 4), (3, 3, 4, 3), (1, 1), (1, 1))))
     # the full default sweep runs (Pallas in interpret mode here) and
     # times the fused-epilogue deployment, not the bare conv
     x = _mk(rng, (1, 4, 4, 4), jnp.float32)

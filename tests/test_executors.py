@@ -166,6 +166,20 @@ def test_plan_never_selects_incapable_executor(backend, dtype):
                     f"{spec.key()} but it declares: {why}")
 
 
+@pytest.mark.parametrize("geom", SWEEP, ids=lambda g: _spec(g, "float32").key())
+def test_off_tpu_negotiation_plans_lax(geom):
+    """Off the TPU every float spec negotiates to the library conv: the
+    Pallas executors would run in interpret mode, so none claims there,
+    and the cost tier (where winograd_pallas' divided flop cost would
+    win every 3x3 spec) is never reached."""
+    for dtype in DTYPES:
+        spec = _spec(geom, dtype)
+        for backend in ("cpu", "gpu"):
+            p = cs.plan(spec, backend=backend)
+            assert (p.algorithm, p.source) == ("lax", "heuristic"), \
+                p.explain()
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_forced_plans_resolve_or_refuse_loudly(dtype):
     """Forcing any registered executor either lands on a capable
@@ -192,8 +206,8 @@ def test_stale_measured_winner_remeasures_instead_of_short_circuiting(rng):
     spec = cs.ConvSpec.for_conv(x, w, 1, "same")
     autotune.record_best(spec, jax.default_backend(), "gone_executor")
     best = autotune.measure_algorithm(x, w, repeats=1,
-                                      candidates=("lax", "cuconv"))
-    assert best in ("lax", "cuconv")
+                                      candidates=("lax", "cuconv_pallas"))
+    assert best in ("lax", "cuconv_pallas")
     assert autotune.cached_best(spec) == best    # stale entry overwritten
 
 
@@ -211,9 +225,9 @@ def test_stale_measured_winner_never_misplans():
     """A persisted measured entry naming an executor that cannot run the
     spec (or is no longer registered) is ignored, not served."""
     spec = _spec(SWEEP[1], "float32")               # strided
-    autotune.record_best(spec, "cpu", "cuconv_two_stage_pallas")  # stride-1 only
+    autotune.record_best(spec, "cpu", "winograd_pallas")   # stride-1 only
     p = cs.plan(spec, backend="cpu")
-    assert p.algorithm != "cuconv_two_stage_pallas"
+    assert p.algorithm != "winograd_pallas"
     assert ex.get(p.algorithm).supports(spec)[0]
     autotune.record_best(spec, "cpu", "gone_executor")
     p = cs.plan(spec, backend="cpu")
@@ -265,45 +279,17 @@ def test_registry_lookup_and_registration_errors():
     with pytest.raises(ValueError, match="name"):
         ex.register(ex.Executor())                   # no name
 
-    class _Inert(ex.Executor):                       # no fn, no _execute
+    class _Inert(ex.Executor):                       # no _execute
         name = "inert"
     with pytest.raises(ValueError, match="_execute"):
         ex.register(_Inert())                        # fails at registration
     assert set(ex.registered()) == set(ex.names())
-    # ALGORITHMS is the fn-backed back-compat view: fn-less builtins
-    # (the int8 executor overrides execute() wholesale; the depthwise
-    # kernel has only its _execute) are registered but absent from it
-    assert set(ex.ALGORITHMS) <= set(ex.names())
-    assert set(ex.names()) - set(ex.ALGORITHMS) == {"cuconv_int8",
-                                                    "depthwise_tap"}
-    assert ex.ALGORITHMS["lax"] is cc.conv_lax
+    assert ex.names() == ("lax", "cuconv_pallas", "winograd_pallas",
+                          "cuconv_int8", "depthwise_tap")
     spec = cs.ConvSpec((1, 6, 6, 4), (3, 3, 4, 4), (1, 1), (1, 1))
     assert ex.capable("lax", spec)
     assert not ex.capable("conv9000", spec)       # unknown: False, no raise
-    assert not ex.capable("conv1x1_pallas", spec)  # registered, incapable
-
-
-def test_fn_less_executor_absent_from_algorithms_view():
-    """A third-party executor that only implements _execute (fn=None)
-    must not break the back-compat mapping view's iterate-then-index
-    contract — it is simply absent from the view."""
-    class _NoFn(ex.Executor):
-        name = "no_fn_fp16"
-        dtypes = ("float16",)
-
-        def _execute(self, spec, x, w, bias, interpret):
-            return cc.conv_lax(x, w, stride=spec.stride,
-                               padding=spec.padding)
-
-    ex.register(_NoFn())
-    try:
-        assert "no_fn_fp16" in ex.names()
-        assert "no_fn_fp16" not in list(ex.ALGORITHMS)
-        assert dict(ex.ALGORITHMS)                 # iterate+index never raises
-        with pytest.raises(KeyError):
-            ex.ALGORITHMS["no_fn_fp16"]
-    finally:
-        ex.unregister("no_fn_fp16")
+    assert not ex.capable("depthwise_tap", spec)  # registered, incapable
 
 
 class _ToyExecutor(ex.Executor):
@@ -315,7 +301,7 @@ class _ToyExecutor(ex.Executor):
     def heuristic_claim(self, spec, backend):
         return 1000, "toy region"
 
-    def _execute(self, spec, x, w, bias, interpret):
+    def _execute(self, spec, x, w, bias, interpret, config=None):
         return cc.conv_lax(x, w, stride=spec.stride, padding=spec.padding,
                            groups=spec.groups)
 
@@ -353,7 +339,7 @@ class _QuietExecutor(ex.Executor):
     name = "quiet_fp16"
     dtypes = ("float16",)
 
-    def _execute(self, spec, x, w, bias, interpret):
+    def _execute(self, spec, x, w, bias, interpret, config=None):
         return cc.conv_lax(x, w, stride=spec.stride, padding=spec.padding)
 
 

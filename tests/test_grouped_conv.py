@@ -128,8 +128,8 @@ def test_forcing_ungrouped_executor_on_grouped_spec_raises():
     assert "cuconv_pallas" in msg             # names the executor
     assert spec.key() in msg                  # names the spec
     assert "groups" in msg
-    with pytest.raises(ValueError, match="winograd"):
-        cs.plan(spec, force="winograd")
+    with pytest.raises(ValueError, match="winograd_pallas"):
+        cs.plan(spec, force="winograd_pallas")
     # a grouped-capable executor still forces cleanly
     fp = cs.plan(spec, force="lax")
     assert (fp.algorithm, fp.source) == ("lax", "forced")

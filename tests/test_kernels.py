@@ -5,8 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels import conv1x1 as k1, cuconv_stage1 as ks1, \
-    cuconv_stage2 as ks2, cuconv_fused as kf, conv1d_tap as kc, \
+from repro.kernels import cuconv_fused as kf, conv1d_tap as kc, \
     flash_attention as kfa
 
 TOLS = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
@@ -15,39 +14,6 @@ TOLS = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
 
 def _rand(rng, shape, dtype):
     return jnp.asarray(rng.normal(size=shape), jnp.float32).astype(dtype)
-
-
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("P,C,M", [(64, 32, 16), (300, 130, 70),
-                                   (17, 257, 129), (1024, 64, 256)])
-def test_conv1x1_gemm(rng, P, C, M, dtype):
-    x = _rand(rng, (P, C), dtype)
-    w = _rand(rng, (C, M), dtype)
-    got = k1.conv1x1_gemm(x, w, interpret=True)
-    want = ref.conv1x1_ref(x, w)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **TOLS[dtype])
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("T,P,C,M", [(9, 50, 16, 8), (25, 128, 48, 32),
-                                     (4, 33, 7, 5)])
-def test_stage1(rng, T, P, C, M, dtype):
-    xs = _rand(rng, (T, P, C), dtype)
-    w = _rand(rng, (T, C, M), dtype)
-    got = ks1.stage1_tap_gemm(xs, w, interpret=True)
-    want = ref.stage1_ref(xs, w)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               **TOLS[dtype])
-
-
-@pytest.mark.parametrize("T,P,M", [(9, 64, 32), (25, 100, 20), (1, 7, 3)])
-def test_stage2(rng, T, P, M):
-    temps = _rand(rng, (T, P, M), jnp.float32)
-    got = ks2.stage2_tap_sum(temps, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref.stage2_ref(
-        temps)), rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -66,19 +32,6 @@ def test_cuconv_fused_kernel(rng, N, H, W, C, KH, KW, M, pad, dtype):
                               (pad, pad))
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), **TOLS[dtype])
-
-
-@pytest.mark.parametrize("N,H,W,C,KH,KW,M,pad", [
-    (1, 7, 7, 16, 3, 3, 8, 1),
-    (2, 9, 9, 8, 5, 5, 4, 2),
-])
-def test_cuconv_two_stage_kernels(rng, N, H, W, C, KH, KW, M, pad):
-    x = _rand(rng, (N, H, W, C), jnp.float32)
-    w = _rand(rng, (KH, KW, C, M), jnp.float32)
-    got = ops.cuconv_two_stage(x, w, (pad, pad), interpret=True)
-    want = ref.conv2d_pad_ref(x, w, (pad, pad))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

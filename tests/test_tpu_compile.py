@@ -128,32 +128,6 @@ def test_winograd_pallas_gathers_tiles_in_kernel(one_chip, label, xs,
     assert not found, f"{label}: gather outside the kernel: {found}"
 
 
-@pytest.mark.parametrize("stride", [(1, 1), (2, 2)])
-def test_direct_compiles(one_chip, stride):
-    xs, ws = (8, 28, 28, 256), (3, 3, 256, 256)
-
-    def f(x, w):
-        return ops.direct_conv(x, w, (1, 1), stride=stride, tm=128, tc=128,
-                               interpret=False)
-    assert "tpu_custom_call" in _compiled_text(
-        f, one_chip, (xs, F32), (ws, F32))
-
-
-def test_two_stage_kernels_compile(one_chip):
-    def f(x, w):
-        return ops.cuconv_two_stage(x, w, (1, 1), interpret=False)
-    txt = _compiled_text(f, one_chip, ((1, 13, 13, 384), F32),
-                         ((3, 3, 384, 384), F32))
-    assert txt.count("tpu_custom_call") >= 2          # stage 1 + stage 2
-
-
-def test_conv1x1_compiles(one_chip):
-    def f(x, w):
-        return ops.conv1x1(x, w, interpret=False)
-    assert "tpu_custom_call" in _compiled_text(
-        f, one_chip, ((8, 14, 14, 1024), F32), ((1, 1, 1024, 256), F32))
-
-
 def test_int8_gemm_compiles(one_chip):
     def f(x, w):
         return ops.int8_gemm(x, w, interpret=False)
