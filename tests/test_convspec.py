@@ -1,4 +1,6 @@
 """ConvSpec plan layer: dispatch, epilogues, strides, fallbacks, cache."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -137,8 +139,8 @@ _POOL = ("max", 2, 2, 2, 2, 0, 0)
 # the VMEM budget sits just under its default geometry's working set
 # (or, for the pool, under every multi-row blocking that covers it)
 _REFUSED = {
-    "stride1-over-vmem": ((1, 8, 8, 8), (1, 1), None),
-    "stride2-over-vmem": ((1, 9, 9, 8), (2, 2), None),
+    "stride1-over-vmem": ((1, 8, 8, 8), (1, 1), ()),
+    "stride2-over-vmem": ((1, 9, 9, 8), (2, 2), ()),
     "pool-no-blocking": ((1, 8, 8, 8), (1, 1), _POOL),
 }
 
@@ -148,14 +150,14 @@ def test_forced_fused_kernel_falls_back_to_lax(rng, monkeypatch, case):
     """Forced onto a spec it refuses, cuconv_pallas takes the base
     executor's declared fallback, the library conv, and the plan still
     computes relu(conv + b) (then the pool)."""
-    import dataclasses
     in_shape, stride, pool = _REFUSED[case]
     spec = cs.ConvSpec(in_shape, (3, 3, 8, 4), stride, (1, 1),
                        epilogue="bias_relu")
     spec = dataclasses.replace(spec, fused_pool=pool)
     fused = ex.get("cuconv_pallas")
+    need = fused.vmem_bytes(spec)          # the default geometry's
     monkeypatch.setattr(ex, "FUSED_VMEM_BUDGET",
-                        fused.vmem_bytes(spec) - (pool is None))
+                        need if pool else need - 1)
     ok, why = fused.supports(spec)
     assert not ok, spec.key()
     p = cs.plan(spec, force="cuconv_pallas")
@@ -164,7 +166,7 @@ def test_forced_fused_kernel_falls_back_to_lax(rng, monkeypatch, case):
     w = _mk(rng, spec.filter_shape, jnp.float32)
     b = _mk(rng, (4,), jnp.float32)
     want = _lax_ref(x, w, stride, (1, 1), bias=b, relu=True)
-    if pool is not None:
+    if pool:
         want = jax.lax.reduce_window(want, -jnp.inf, jax.lax.max,
                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
     np.testing.assert_allclose(np.asarray(p(x, w, b)), np.asarray(want),
