@@ -281,9 +281,17 @@ class Executor:
     def config_cost(self, spec, config) -> float:
         """Abstract cost of running ``spec`` under ``config`` — only has
         to *rank* candidates (``default_config`` minimizes it; ties keep
-        the earliest candidate).  Tunable executors model grid-step
-        count (bigger feasible blocks = fewer steps = fuller MXU)."""
+        the earliest candidate), so a number or a tuple compared in
+        order.  Tunable executors model grid-step count (bigger feasible
+        blocks = fewer steps = fuller MXU)."""
         return 0.0
+
+    def padded_share(self, spec, config) -> Optional[float]:
+        """Share of the output elements the kernel computes under
+        ``config`` that lie beyond the real output (blocks rounded up
+        past ``OH`` rows or ``M`` lanes; 0 when the blocks tile it
+        exactly), or None when the executor has no block model."""
+        return None
 
     def default_config(self, spec) -> LaunchConfig:
         """Model-chosen launch config absent measurement: the cheapest
@@ -590,6 +598,27 @@ def _gemm_tile_steps(p: int, m: int, c: int, config: LaunchConfig) -> float:
     return (-(-p // tp)) * (-(-m // tm)) * (-(-c // tc))
 
 
+def _exact_rows(oh: int, target: int) -> int:
+    """The divisor of ``oh`` nearest ``target`` output rows a step (ties
+    to the larger), so the row blocks tile the output exactly; the
+    padded ``min(target, oh)`` where that divisor is below
+    ``target / 2`` (a prime ``OH`` keeps its padded block rather than
+    falling to one row a step)."""
+    d = min((d for d in range(1, oh + 1) if oh % d == 0),
+            key=lambda d: (abs(d - target), -d))
+    return d if 2 * d >= target else min(target, oh)
+
+
+def _exact_tm(m: int, target: int) -> int:
+    """The largest multiple of 128 lanes that divides ``m`` and is at
+    most ``target``; ``m`` itself (one whole-extent tile) where none
+    does."""
+    for tm in range(target - target % 128, 0, -128):
+        if m % tm == 0:
+            return tm
+    return m
+
+
 class FusedPallasExecutor(Executor):
     """The fused Pallas TPU kernel: any stride >= 1, per-tap partials
     accumulated in VMEM, bias + ReLU/GELU epilogue fused before the
@@ -600,6 +629,10 @@ class FusedPallasExecutor(Executor):
     shapes feed the MXU a (rows*OW x C) window instead of one row).
     ``rows <= OH`` is a ``config_supports`` rule, so stale persisted
     configs from an earlier geometry are re-resolved, never served.
+    Candidates tile the output exactly (``rows | OH``, ``tm | M``)
+    wherever the geometry allows: a padded block computes dead rows or
+    lanes, and XLA pads its inputs and slices its output around the
+    kernel.
     """
     name = "cuconv_pallas"
     fuses_epilogue = True
@@ -658,10 +691,17 @@ class FusedPallasExecutor(Executor):
             rows_cands = tuple(r for r in (psh, 2 * psh, 4 * psh, 8 * psh)
                                if r <= oh) or (psh,)
         else:
-            rows_cands = (1, 2, 4, 8)
+            # each row target's exact tiling of OH, then the target
+            # itself (a measured sweep may still time a padded block;
+            # config_cost ranks it below every exact one)
+            rows_cands = tuple(r for target in (1, 2, 4, 8)
+                               for r in (_exact_rows(oh, target), target))
+        # each lane target, clamped to M, then its exact tiling of M
+        tms = [t for target in (128, 256, 512)
+               for t in (min(target, m), _exact_tm(m, target))]
         return _dedup_configs(
-            ({"tm": min(tm, m), "rows": min(rows, oh)}
-             for tm in (128, 256, 512)         # candidate 0: tm=128, rows=1
+            ({"tm": tm, "rows": min(rows, oh)}
+             for tm in tms                     # candidate 0: tm=128, rows=1
              for rows in rows_cands),
             {"tm": m})
 
@@ -688,11 +728,22 @@ class FusedPallasExecutor(Executor):
         return True, "config geometry ok"
 
     def config_cost(self, spec, config):
+        """``(computed, steps)``: the output rows x lanes the kernel
+        computes over all taps (``OHB*rows`` by ``ceil(M/tm)*tm``, so a
+        padded block pays for its dead rows and lanes and never ties an
+        exact one), then the grid steps (bigger exact blocks win)."""
         n, oh, _, m = spec.out_shape
         kh, kw = spec.filter_shape[:2]
         tm = min(config.get("tm", 128), m)
         rows = max(1, min(config.get("rows", 1), oh))
-        return n * (-(-oh // rows)) * (-(-m // tm)) * kh * kw
+        steps = n * (-(-oh // rows)) * (-(-m // tm)) * kh * kw
+        return steps * rows * tm, steps
+
+    def padded_share(self, spec, config):
+        n, oh, _, m = spec.out_shape
+        kh, kw = spec.filter_shape[:2]
+        computed, _ = self.config_cost(spec, LaunchConfig.of(config))
+        return 1.0 - n * oh * m * kh * kw / computed
 
     def heuristic_claim(self, spec, backend):
         if backend != "tpu":

@@ -794,7 +794,10 @@ class GraphPlan:
     def explain(self) -> str:
         """One aligned table for the whole network (every IR node):
         geometry, dtype, and executor provenance (which registry entry
-        won and why — forced / measured / heuristic / cost)."""
+        won and why — forced / measured / heuristic / cost), with the
+        padded share of the launch config's blocks where the executor
+        models it (``Executor.padded_share``; 0 when they tile the
+        output exactly)."""
         lines = [f"GraphPlan[{self.source}] backend={self.backend} "
                  f"sig={self.graph.signature()} nodes={len(self.graph)}"]
         for node in self.graph.nodes:
@@ -806,6 +809,9 @@ class GraphPlan:
                 grp = f" g{s.groups}" if s.groups != 1 else ""
                 cfg = (f" cfg[{p.config_source}]={p.config.key()}"
                        if p.config else "")
+                share = p.executor.padded_share(s, p.config)
+                if share is not None:
+                    cfg += f" padded={share:.3g}"
                 fz = ""
                 prov = self.fused.get(node.name)
                 if prov:

@@ -151,6 +151,73 @@ def test_default_config_is_vmem_feasible_and_model_ranked():
 
 
 # ---------------------------------------------------------------------------
+# cuconv_pallas: launch configs that tile the output exactly
+
+# the output rows and channels the benchmark's configurations serve
+# (ResNet-50, SqueezeNet 1.0, ConvNeXt-T at 224x224)
+SERVED_OH = (56, 28, 14, 7, 54, 27, 13)
+SERVED_M = (16, 48, 64, 128, 192, 256, 384, 512, 768, 1000, 1024, 1536,
+            2048, 3072)
+# (OH, M, C) with no feasible exact config: M = 1000 has no 128-lane
+# divisor, and its one whole-extent tile holds a (C x 1000) weight
+# block that busts the VMEM budget at C = 4096
+NO_EXACT_CONFIG = {(oh, 1000, 4096) for oh in SERVED_OH}
+
+
+@pytest.mark.parametrize("target,oh,rows", [
+    (8, 56, 8), (8, 28, 7), (8, 14, 7), (8, 7, 7), (8, 54, 9), (8, 27, 9),
+    (8, 13, 13), (4, 54, 3), (2, 27, 3), (4, 13, 4), (8, 53, 8)])
+def test_row_target_takes_the_nearest_divisor_of_oh(target, oh, rows):
+    """Ties go to the larger divisor; a target whose nearest divisor is
+    below half of it stays padded."""
+    assert ex._exact_rows(oh, target) == rows
+
+
+@pytest.mark.parametrize("target,m,tm", [
+    (512, 768, 384), (512, 1000, 1000), (256, 768, 256), (512, 1536, 512),
+    (128, 64, 64), (512, 192, 192), (512, 640, 128)])
+def test_lane_target_takes_the_largest_128_multiple_dividing_m(target, m,
+                                                               tm):
+    assert ex._exact_tm(m, target) == tm
+
+
+@pytest.mark.parametrize("m", SERVED_M)
+@pytest.mark.parametrize("oh", SERVED_OH)
+def test_default_config_tiles_the_output_exactly(oh, m):
+    """Wherever a feasible config has ``rows | OH`` and ``tm | M``, the
+    model-chosen default has too; elsewhere the case is a named
+    exception and the default is still feasible."""
+    exe = ex.get("cuconv_pallas")
+    for c in (256, 4096):
+        spec = cs.ConvSpec((32, oh, oh, c), (1, 1, c, m))
+        feasible = [cfg for cfg in exe.configs(spec)
+                    if exe.config_supports(spec, cfg)[0]]
+        exact = [cfg for cfg in feasible
+                 if exe.padded_share(spec, cfg) == 0]
+        assert bool(exact) == ((oh, m, c) not in NO_EXACT_CONFIG)
+        cfg = exe.default_config(spec)
+        assert cfg in feasible, cfg.key()
+        if exact:
+            assert oh % cfg["rows"] == 0 and m % min(cfg["tm"], m) == 0
+            assert exe.padded_share(spec, cfg) == 0, cfg.key()
+        else:
+            assert exe.padded_share(spec, cfg) > 0
+
+
+def test_padded_config_never_ties_an_exact_one():
+    """At OH 28 and M 768 the padded rows=8 and tm=512 blocks take as
+    many grid steps as rows=7 and tm=384, and cost more."""
+    exe = ex.get("cuconv_pallas")
+    spec = cs.ConvSpec((32, 28, 28, 192), (1, 1, 192, 768))
+    cost = lambda **cfg: exe.config_cost(spec, ex.LaunchConfig.of(cfg))
+    assert cost(rows=7, tm=384) < cost(rows=8, tm=384)
+    assert cost(rows=7, tm=384) < cost(rows=7, tm=512)
+    assert exe.default_config(spec).as_dict() == {"rows": 7, "tm": 384}
+    assert exe.padded_share(spec, {"rows": 8, "tm": 512}) == pytest.approx(
+        1 - 28 * 768 / (32 * 1024))
+
+
+# ---------------------------------------------------------------------------
 # numerics: every candidate config executes exactly
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

@@ -63,11 +63,17 @@ def _model(config: str) -> GraphModel:
     return GraphModel(builder, (size, size, c), name=config)
 
 
+def served_graph_plan(config: str, bucket: int, policy: str):
+    """The ``GraphPlan`` of one bucket program, as a chip negotiates it."""
+    model = _model(config)
+    return model.graph_plan((bucket,) + model.image_shape, backend="tpu",
+                            precision=None if policy == "float32"
+                            else policy)
+
+
 def served_plans(config: str, bucket: int, policy: str) -> dict:
     """``{node: "<algorithm> <config key>"}`` of one bucket program."""
-    model = _model(config)
-    gp = model.graph_plan((bucket,) + model.image_shape, backend="tpu",
-                          precision=None if policy == "float32" else policy)
+    gp = served_graph_plan(config, bucket, policy)
     return {n: f"{p.algorithm} {p.config.key() if p.config else '-'}"
             for n, p in gp.conv_plans.items()}
 
@@ -94,6 +100,26 @@ def test_cell_plan_is_unchanged(config, bucket, policy, empty_cache):
     moved = {n: (want.get(n), got.get(n)) for n in set(want) | set(got)
              if want.get(n) != got.get(n)}
     assert not moved, f"served plans moved (recorded, now): {moved}"
+
+
+@pytest.mark.parametrize("config,bucket,policy", CASES,
+                         ids=[_case_id(*c) for c in CASES])
+def test_served_cuconv_nodes_tile_exactly(config, bucket, policy,
+                                          empty_cache):
+    """Every ``cuconv_pallas`` node's blocks tile its output (``rows |
+    OH``, ``tm | M``): no dead rows or lanes computed, and no XLA pad
+    and slice around the kernel.  No node is excepted: every OH and M
+    the cells serve has a feasible exact tiling.  ``explain()`` shows
+    the share."""
+    gp = served_graph_plan(config, bucket, policy)
+    shares = {n: p.executor.padded_share(p.spec, p.config)
+              for n, p in gp.conv_plans.items()
+              if p.algorithm == "cuconv_pallas"}
+    assert shares
+    assert not {n: s for n, s in shares.items() if s}
+    table = {line.split()[0]: line for line in gp.explain().splitlines()[1:]}
+    for n, share in shares.items():
+        assert f" padded={share:.3g} " in table[n], table[n]
 
 
 _STEM_POOL = ("max", 3, 3, 2, 2, 1, 1)       # ResNet-50's 3x3/2 pad 1
@@ -131,7 +157,25 @@ NODE_CLASSES = {
                          "bias_relu", "none", _FIRE_POOL, 1),
     "stem4x4s4-bias": ("lax", (2, 16, 16, 3), (4, 8), 4, 0,
                        "bias", "none", (), 1),
+    # exact tilings of the output: rows a divisor of OH that is no
+    # power of two, rows = OH, tm a multiple of 128 that is none
+    "1x1-rows7": ("cuconv_pallas", (1, 28, 28, 8), (1, 16), 1, 0,
+                  "bias_relu", "none", (), 1),
+    "1x1-add_relu-rows7": ("cuconv_pallas", (1, 28, 28, 8), (1, 16), 1, 0,
+                           "bias", "add_relu", (), 1),
+    "3x3s2-rows7": ("cuconv_pallas", (1, 28, 28, 8), (3, 16), 2, 1,
+                    "bias_relu", "none", (), 1),
+    "1x1-rows9": ("cuconv_pallas", (1, 27, 27, 8), (1, 16), 1, 0,
+                  "bias_relu", "none", (), 1),
+    "1x1-rows13": ("cuconv_pallas", (2, 13, 13, 8), (1, 16), 1, 0,
+                   "bias_relu", "none", (), 1),
+    "1x1-tm384": ("cuconv_pallas", (2, 4, 4, 8), (1, 768), 1, 0,
+                  "bias_gelu", "none", (), 1),
 }
+# the launch config each exact-tiling class must plan
+CLASS_CONFIGS = {"1x1-rows7": {"rows": 7}, "1x1-add_relu-rows7": {"rows": 7},
+                 "3x3s2-rows7": {"rows": 7}, "1x1-rows9": {"rows": 9},
+                 "1x1-rows13": {"rows": 13}, "1x1-tm384": {"tm": 384}}
 TOLS = {"float32": dict(rtol=3e-4, atol=3e-4),
         "bfloat16": dict(rtol=3e-2, atol=3e-2)}
 
@@ -174,6 +218,8 @@ def test_served_node_class_matches_lax(cls, dtype, empty_cache):
     spec = _class_spec(cls, dtype)
     p = cs.plan(spec, backend="tpu")
     assert p.algorithm == NODE_CLASSES[cls][0], p.explain()
+    for dim, want in CLASS_CONFIGS.get(cls, {}).items():
+        assert p.config[dim] == want, p.explain()
     rng = np.random.default_rng(len(cls))
     kh, kw, cpg, m = spec.filter_shape
     mk = lambda shape, scale=1.0: jnp.asarray(

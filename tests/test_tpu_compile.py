@@ -34,6 +34,21 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def v5e_1x1():
+    """One chip of a one-chip v5e host, the machine the cells run on."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            topology_name="v5e:1x1", platform="tpu",
+            chips_per_host_bounds=(1, 1, 1))
+    except Exception as e:     # no TPU compiler / libtpu here
+        pytest.skip(f"no v5e:1x1 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
 def _compiled_text(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
             for shape, dtype in shapes]
@@ -76,6 +91,59 @@ def test_cuconv_fused_residual_add_compiles(one_chip):
                                 addend=a, tm=128, rows=4, interpret=False)
     assert "tpu_custom_call" in _compiled_text(
         f, one_chip, (xs, F32), (ws, F32), ((128,), F32), (xs, F32))
+
+
+def _served_cuconv_geometries(config):
+    """``{geometry: (node, spec, config)}``: one ``cuconv_pallas`` node
+    of each distinct kernel geometry (the spec but its batch, and the
+    launch config) the configuration's float32 bucket programs plan."""
+    from test_served_plans import BUCKETS, served_graph_plan
+    out = {}
+    for bucket in BUCKETS[config]:
+        gp = served_graph_plan(config, bucket, "float32")
+        for node, p in gp.conv_plans.items():
+            s = p.spec
+            if p.algorithm == "cuconv_pallas":
+                out.setdefault((s.in_shape[1:], s.filter_shape, s.stride,
+                                s.padding, s.epilogue, s.fused_add,
+                                s.fused_pool, p.config.key()),
+                               (node, s, p.config))
+    return out
+
+
+_SLICE = re.compile(r"\bslice\(")
+
+
+@pytest.mark.parametrize("config", ["resnet50", "squeezenet1_0",
+                                    "convnext_tiny"])
+def test_served_cuconv_geometries_compile_without_slices(v5e_1x1, config):
+    """Every kernel geometry a cell serves on ``cuconv_pallas``, at its
+    launch config and a 32-image batch, compiles for a one-chip v5e; the
+    blocks tile the output, so the program slices nothing off it and
+    pads only for the conv's own padding or stride phases."""
+    for node, s, cfg in _served_cuconv_geometries(config).values():
+        xs = (32,) + s.in_shape[1:]
+        out = (32,) + s.out_shape[1:]
+        add = s.fused_add != "none"
+        shapes = [(xs, F32), (s.filter_shape, F32),
+                  ((s.filter_shape[3],), F32)] + [(out, F32)] * add
+        act = ("relu" if s.fused_add == "add_relu" else None) if add \
+            else s.activation
+        pool = (s.fused_pool[0],) + s.fused_pool[3:5] if s.fused_pool \
+            else None
+
+        def f(x, w, b, a=None):
+            return ops.cuconv_fused(x, w, s.padding, stride=s.stride, bias=b,
+                                    activation=act, addend=a, pool=pool,
+                                    tm=cfg["tm"], rows=cfg["rows"],
+                                    interpret=False)
+        txt = _compiled_text(f, v5e_1x1, *shapes)
+        assert "tpu_custom_call" in txt, node
+        outside = [line.strip()[:160] for line in txt.splitlines()
+                   if "tpu_custom_call" not in line]
+        assert not [line for line in outside if _SLICE.search(line)], node
+        if s.padding == (0, 0) and s.unit_stride:
+            assert not [line for line in outside if " pad(" in line], node
 
 
 def _first_feasible(spec, m):
